@@ -32,8 +32,8 @@ from deeplearning4j_tpu.nn.conf.neural_net import (  # noqa: F401
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork  # noqa: F401
 from deeplearning4j_tpu.nn.graph import ComputationGraph  # noqa: F401
 
-# Wire jax's persistent compilation cache at import (opt-out via
-# DL4J_TPU_COMPILE_CACHE=0): init-time helper ops compile before the first
+# Turn on jax's persistent compilation cache at import (opt-out via
+# DL4J_TPU_COMPILE_CACHE=off; placed by JAX_COMPILATION_CACHE_DIR): init-time helper ops compile before the first
 # _get_jit would lazily configure it, and a warm process should replay
 # those from disk too, not just the big training programs.
 from deeplearning4j_tpu.compilation import (  # noqa: F401,E402

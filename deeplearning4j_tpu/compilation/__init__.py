@@ -2,9 +2,11 @@
 
 Three layers (PERF.md §14):
 
-1. `cache.py`    — wires jax's persistent compilation cache to a per-user
-                   directory (``DL4J_TPU_COMPILE_CACHE``, opt-out): the
-                   backend compile of a seen program becomes a disk read.
+1. `cache.py`    — turns on jax's persistent compilation cache where
+                   ``JAX_COMPILATION_CACHE_DIR`` says, else at the fixed
+                   ``<checkout>/.dl4j_compile_cache``
+                   (``DL4J_TPU_COMPILE_CACHE=off`` opts out): the backend
+                   compile of a seen program becomes a disk read.
 2. `store.py` /
    `program.py`  — the framework-level AOT executable store: whole
                    compiled executables serialized under a fingerprint of
@@ -23,7 +25,7 @@ histogram, all labeled ``source=trace|persistent|aot``.
 """
 
 from deeplearning4j_tpu.compilation.cache import (
-    ENV_KNOB, cache_root, configure_persistent_cache, default_cache_dir)
+    ENV_KNOB, cache_root, checkout_cache_dir, configure_persistent_cache)
 from deeplearning4j_tpu.compilation.program import (
     CachedProgram, get_store, wrap_program)
 from deeplearning4j_tpu.compilation.store import (
@@ -32,8 +34,8 @@ from deeplearning4j_tpu.compilation.warmup import (
     infer_feature_shape, synthetic_dataset, warmup_net)
 
 __all__ = [
-    "ENV_KNOB", "cache_root", "configure_persistent_cache",
-    "default_cache_dir", "CachedProgram", "get_store", "wrap_program",
+    "ENV_KNOB", "cache_root", "checkout_cache_dir",
+    "configure_persistent_cache", "CachedProgram", "get_store", "wrap_program",
     "AOTStore", "build_fingerprint_doc", "fingerprint", "tree_signature",
     "infer_feature_shape", "synthetic_dataset", "warmup_net", "reset",
 ]
@@ -42,7 +44,7 @@ __all__ = [
 def reset() -> None:
     """Test hook: drop the latched cache configuration, the store
     singleton, and jax's in-memory persistent-cache handle so the next use
-    re-reads ``DL4J_TPU_COMPILE_CACHE``."""
+    re-reads the environment."""
     from deeplearning4j_tpu.compilation import program as _program
 
     _program.reset_for_tests()

@@ -14,8 +14,12 @@ call of each signature:
    jit call would have paid), writes the artifact back, and uses the
    compiled executable from then on.
 
-Any failure in the store path degrades to the plain jitted callable with a
-warning. `warm(*args)` does step 1-3 *without executing* the program —
+Only the store may degrade: an artifact that cannot be read is a miss and
+one that cannot be written stays in memory, each with a warning. The
+compile itself never does — what the compiler refuses (a Pallas kernel the
+chip rejects, a program that does not fit) raises out of the first call and
+out of `warm`, so a warmed server is a server whose programs compiled.
+`warm(*args)` does step 1-3 *without executing* the program —
 donation-safe pre-compilation for the warmup API. `lower(*args)` delegates
 to the underlying jit fn (the profiler's cost-analysis probe relies on
 it).
@@ -36,16 +40,35 @@ _store_lock = threading.Lock()
 _store_singleton: Optional[_store.AOTStore] = None
 _store_root: Optional[str] = None
 
+# Persistent-cache hits seen on this thread (jax fires the event inside
+# `compile()`, on the compiling thread). An executable that jax loaded from
+# its persistent cache must not be written to the AOT store: under jaxlib
+# 0.9.0 the CPU backend serializes a deserialized executable without its
+# compiled functions, and the artifact then loads but dies at its first
+# call ("Function ... not found").
+_persistent_hits = threading.local()
+_hit_listener_installed = False
+
+
+def _on_jax_event(event: str, **_kw) -> None:
+    if event == "/jax/compilation_cache/cache_hits":
+        _persistent_hits.n = getattr(_persistent_hits, "n", 0) + 1
+
 
 def get_store() -> Optional[_store.AOTStore]:
     """Process-wide `AOTStore` under the configured cache root (configures
     the persistent XLA cache as a side effect of first use). None when
     caching is disabled."""
-    global _store_singleton, _store_root
+    global _store_singleton, _store_root, _hit_listener_installed
     root = _cache.configure_persistent_cache()
     if root is None:
         return None
     with _store_lock:
+        if not _hit_listener_installed:
+            from jax import monitoring
+
+            monitoring.register_event_listener(_on_jax_event)
+            _hit_listener_installed = True
         if _store_singleton is None or _store_root != root:
             _store_singleton = _store.AOTStore(root)
             _store_root = root
@@ -113,40 +136,43 @@ class CachedProgram:
         with self._lock:
             entry = self._entries.get(sig)
             if entry is None:
-                entry = self._acquire(args)
+                entry, _ = self._acquire(args)
                 self._entries[sig] = entry
             return entry
 
     def _acquire(self, args):
+        """`(callable, origin)` for one argument signature; origin is
+        'aot' (store hit), 'compiled' (live compile + write-back) or 'jit'
+        (no store: the program traces on its first call)."""
         store = get_store()
         if store is None:
-            return self._fn
+            return self._fn, "jit"
         try:
             doc = _store.build_fingerprint_doc(self._net, self.kind,
                                                self.static, args)
             fp = _store.fingerprint(doc)
         except Exception as e:
             self._warn_fallback("fingerprinting failed", e)
-            return self._fn
+            return self._fn, "jit"
         loaded = store.load(fp)
         if loaded is not None:
             _store._M_HITS_AOT.inc()
             self._record_memory(loaded)
-            return loaded
+            return loaded, "aot"
         _store._M_MISSES_AOT.inc()
-        try:
-            t0 = time.perf_counter()
-            compiled = self._fn.lower(*args).compile()
-            # dl4j_compile_seconds{source=trace|persistent} for the backend
-            # part is observed by the jax.monitoring hook; this histogram
-            # entry is intentionally NOT duplicated here.
-            dt = time.perf_counter() - t0
-        except Exception as e:
-            self._warn_fallback("AOT compilation failed", e)
-            return self._fn
-        store.save(fp, compiled, dict(doc, compile_seconds=dt))
+        hits0 = getattr(_persistent_hits, "n", 0)
+        t0 = time.perf_counter()
+        # A compile error propagates: the plain jit path would only compile
+        # the same program again, and fail again at the first request.
+        compiled = self._fn.lower(*args).compile()
+        # dl4j_compile_seconds{source=trace|persistent} for the backend
+        # part is observed by the jax.monitoring hook; this histogram
+        # entry is intentionally NOT duplicated here.
+        dt = time.perf_counter() - t0
+        if getattr(_persistent_hits, "n", 0) == hits0:
+            store.save(fp, compiled, dict(doc, compile_seconds=dt))
         self._record_memory(compiled)
-        return compiled
+        return compiled, "compiled"
 
     def _record_memory(self, compiled) -> None:
         """Static HBM accounting: every executable that materializes here
@@ -171,41 +197,23 @@ class CachedProgram:
         running it (safe with donated buffers). Returns where it came
         from: 'ready' (already warm), 'aot' (store hit), 'compiled'
         (live compile + write-back), or 'jit' (store unavailable — the
-        program will trace on first call)."""
+        program will trace on first call). Raises what the compiler
+        raises."""
         sig = self._signature(args)
         with self._lock:
             if sig in self._entries:
                 return "ready"
-            store = get_store()
-            if store is None:
-                return "jit"
-            try:
-                doc = _store.build_fingerprint_doc(self._net, self.kind,
-                                                  self.static, args)
-                fp = _store.fingerprint(doc)
-            except Exception as e:
-                self._warn_fallback("fingerprinting failed", e)
-                self._entries[sig] = self._fn
-                return "jit"
-            loaded = store.load(fp)
-            if loaded is not None:
-                _store._M_HITS_AOT.inc()
-                self._entries[sig] = loaded
-                return "aot"
-            _store._M_MISSES_AOT.inc()
-            try:
-                t0 = time.perf_counter()
-                compiled = self._fn.lower(*args).compile()
-                dt = time.perf_counter() - t0
-            except Exception as e:
-                self._warn_fallback("AOT compilation failed", e)
-                self._entries[sig] = self._fn
-                return "jit"
-            store.save(fp, compiled, dict(doc, compile_seconds=dt))
-            self._entries[sig] = compiled
-            return "compiled"
+            self._entries[sig], origin = self._acquire(args)
+            return origin
 
     # ----------------------------------------------------------- plumbing
+
+    def executables(self):
+        """The executable held for each argument signature seen so far
+        (`jax.stages.Compiled`; the plain jit callable where a fingerprint
+        failed) — for inspection: `as_text()`, `memory_analysis()`."""
+        with self._lock:
+            return list(self._entries.values())
 
     def lower(self, *args, **kwargs):
         """Delegate to the underlying jit fn (cost-analysis probes)."""

@@ -17,9 +17,13 @@ everything that determines the program:
 
 Any field changing changes the hash -> a miss -> live compile + write-back.
 A hit deserializes the executable directly: **zero tracing, zero XLA**.
-Loads that fail for any reason (corrupt file, incompatible jaxlib, device
-mismatch) warn once and fall back to live compilation — the store can only
-ever cost a disk read, never correctness.
+An artifact records the ids of the devices its program was compiled for
+and is loaded for exactly those (JAX would otherwise load it for every
+device of the backend, and a one-device program then dies at call time on
+a several-device host). Loads that fail for any reason (corrupt file,
+incompatible jaxlib, a recorded device this process does not have) warn
+once and are a miss, decided at load time — the store can only ever cost
+a disk read, never correctness.
 
 Writes go through tmp-file + ``os.replace`` so concurrent processes
 populating the same directory never expose half-written artifacts.
@@ -39,7 +43,7 @@ from typing import Any, Dict, Optional, Tuple
 
 from deeplearning4j_tpu import observability as _obs
 
-FORMAT_VERSION = 1
+FORMAT_VERSION = 2  # 2: artifacts record their device ids
 
 _M_HITS = _obs.metrics.counter(
     "dl4j_compile_cache_hits_total",
@@ -65,11 +69,11 @@ def _leaf_desc(leaf) -> Tuple:
     import jax
 
     try:
-        aval = jax.core.get_aval(leaf)
+        aval = jax.typeof(leaf)
         shape = tuple(int(d) for d in aval.shape)
         dtype = str(aval.dtype)
         weak = bool(getattr(aval, "weak_type", False))
-    except Exception:
+    except TypeError:  # not an array-like leaf: its type is its identity
         shape, dtype, weak = (), str(type(leaf).__name__), False
     sharding = getattr(leaf, "sharding", None)
     return (shape, dtype, weak, None if sharding is None else str(sharding))
@@ -155,14 +159,14 @@ def fingerprint(doc: Dict[str, Any]) -> str:
 
 class AOTStore:
     """Directory of serialized executables: ``<root>/aot/<fp>.jaxec``
-    (pickled ``{format, fingerprint, jax, jaxlib, payload}``) with a
-    ``<fp>.json`` metadata sidecar holding the fingerprint document."""
+    (pickled ``{format, fingerprint, jax, jaxlib, device_ids, payload}``)
+    with a ``<fp>.json`` metadata sidecar holding the fingerprint
+    document."""
 
     def __init__(self, root: str):
         self.root = os.path.join(root, "aot")
         self._lock = threading.Lock()
         self._warned: set = set()
-        self._save_warned = False
 
     def _path(self, fp: str) -> str:
         return os.path.join(self.root, fp + ".jaxec")
@@ -175,9 +179,10 @@ class AOTStore:
         warnings.warn(message)
 
     def load(self, fp: str):
-        """Deserialize + load the executable for `fp`, or None on miss OR
-        any failure (corruption, version/device mismatch — the fallback is
-        always a live compile)."""
+        """Deserialize + load the executable for `fp` onto the devices it
+        was compiled for, or None on miss OR any failure (corruption,
+        version/device mismatch — the fallback is always a live
+        compile)."""
         path = self._path(fp)
         try:
             with open(path, "rb") as f:
@@ -199,9 +204,17 @@ class AOTStore:
             from jax.experimental.serialize_executable import (
                 deserialize_and_load)
 
+            by_id = {d.id: d for d in jax.devices()}
+            missing = [i for i in blob["device_ids"] if i not in by_id]
+            if missing:
+                raise ValueError(
+                    f"artifact compiled for device ids {blob['device_ids']}"
+                    f"; this process has no device {missing}")
             payload, in_tree, out_tree = blob["payload"]
             t0 = time.perf_counter()
-            loaded = deserialize_and_load(payload, in_tree, out_tree)
+            loaded = deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in blob["device_ids"]])
             _M_SECONDS_AOT.observe(time.perf_counter() - t0)
             return loaded
         except FileNotFoundError:
@@ -227,6 +240,10 @@ class AOTStore:
                 "fingerprint": fp,
                 "jax": doc.get("jax"),
                 "jaxlib": doc.get("jaxlib"),
+                # In device-assignment order: `load` hands the executable
+                # back to exactly these.
+                "device_ids": [int(d.id) for d in
+                               compiled.runtime_executable().local_devices()],
                 "payload": payload,
             }
             os.makedirs(self.root, exist_ok=True)
@@ -250,11 +267,8 @@ class AOTStore:
             os.replace(tmp, final[:-len(".jaxec")] + ".json")
             return True
         except Exception as e:
-            if not self._save_warned:
-                self._save_warned = True
-                warnings.warn(
-                    f"could not serialize a compiled executable into the "
-                    f"AOT store ({type(e).__name__}: {e}); this process "
-                    f"keeps its in-memory program, later processes will "
-                    f"recompile (further save failures are silent)")
+            self._warn_once("save:" + fp, (
+                f"could not write executable {fp[:12]} into the AOT store "
+                f"({type(e).__name__}: {e}); this process keeps its "
+                f"in-memory program, later processes will recompile"))
             return False

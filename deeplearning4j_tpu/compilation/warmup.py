@@ -9,17 +9,19 @@ optimizer state, RNG stream, and iteration counters are untouched.
 `ParallelWrapper.warmup` delegate here; `background=True` runs it on a
 daemon thread so compilation overlaps data loading.
 
-The CLI pre-populates a cache directory for deploy pipelines::
+The CLI pre-populates the compile cache for deploy pipelines::
 
+    JAX_COMPILATION_CACHE_DIR=DIR \
     python -m deeplearning4j_tpu.compilation.warmup <checkpoint> \
-        [--batch-size N] [--shape H,W,C] [--kinds output,train_step] \
-        [--cache-dir DIR]
+        [--batch-size N] [--shape H,W,C] [--kinds output,train_step]
 
 It loads the checkpoint (sharded dir / manager root / legacy ZIP —
 `checkpoint.load_any`), synthesizes a batch from the model's declared
-input type, and warms the requested programs; a later process pointed at
-the same ``DL4J_TPU_COMPILE_CACHE`` starts with zero cold compiles for
-those programs.
+input type, and warms the requested programs; a later process started
+with the same ``JAX_COMPILATION_CACHE_DIR`` (or, with it unset, from the
+same checkout) starts with zero cold compiles for those programs. The
+directory is named in the environment, not by a flag: JAX reads it as it
+is imported, which is before this module's `main` runs.
 """
 
 from __future__ import annotations
@@ -407,18 +409,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     parser.add_argument("--kinds", default=None,
                         help="comma list of program kinds (default: "
                              "train_step,output,score)")
-    parser.add_argument("--cache-dir", default=None,
-                        help=f"cache directory (default: ${_cache.ENV_KNOB} "
-                             "or the per-user dir)")
     args = parser.parse_args(argv)
 
-    if args.cache_dir:
-        os.environ[_cache.ENV_KNOB] = args.cache_dir
-        # The package import already latched a root (possibly the per-user
-        # default); drop it so the flag actually takes effect.
-        from deeplearning4j_tpu import compilation as _compilation
-
-        _compilation.reset()
     root = _cache.configure_persistent_cache()
     if root is None:
         parser.error(f"the compile cache is disabled (${_cache.ENV_KNOB}"

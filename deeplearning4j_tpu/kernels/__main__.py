@@ -15,7 +15,12 @@ instead: every candidate's availability and refusal reason is printed
 SHAPES is a comma-separated int tuple (the kernel's registry signature
 order), DTYPES a comma-separated dtype list, and repeatable
 ``--meta key=value`` pairs fill the meta tuple (``true``/``false``
-parse to booleans).
+parse to booleans, digits to ints). With ``--backend tpu`` the answers are
+the ones a TPU process would get, from any machine::
+
+    python -m deeplearning4j_tpu.kernels --backend tpu --probe \
+        flash_attention_paged 8,1,8,64,129,64,16 float32 \
+        --meta mesh_devices=4
 """
 
 from __future__ import annotations
@@ -31,6 +36,8 @@ def _parse_meta(pairs):
         k, _, v = p.partition("=")
         if v.lower() in ("true", "false"):
             v = v.lower() == "true"
+        elif v.isdigit():
+            v = int(v)
         meta.append((k, v))
     return tuple(meta)
 
@@ -81,7 +88,7 @@ def main(argv=None) -> int:
     ap.add_argument("--meta", action="append", default=None,
                     metavar="KEY=VALUE",
                     help="meta entries for --probe (repeatable; "
-                         "true/false parse to booleans)")
+                         "true/false parse to booleans, digits to ints)")
     args = ap.parse_args(argv)
 
     if args.probe:

@@ -56,7 +56,8 @@ def _working_set_bytes(b, h, w, cin, f1, f3, sh, sw, project):
     """f32 elements resident at once in one kernel invocation (coarse:
     input + each intermediate + weights; bf16 inputs still compute f32)."""
     ho, wo = -(-h // sh), -(-w // sw)
-    acts = b * h * w * cin + b * ho * wo * (cin + 3 * f1 + 3 * f3)
+    # The kernel sees the input already strided (`_strided`).
+    acts = b * ho * wo * (2 * cin + 3 * f1 + 3 * f3)
     weights = cin * f1 + 9 * f1 * f1 + f1 * f3 + (cin * f3 if project else 0)
     return 4 * (acts + weights)
 
@@ -190,8 +191,7 @@ def _in_kernel_norm(v, mean, var, gamma, beta, eps, act):
     return _ACTS[act](gamma * xhat + beta)
 
 
-def _f32(ref):
-    return ref[...].astype(jnp.float32)
+_f32 = _norm._f32
 
 
 def _load_w(ref, scale_ref):
@@ -205,8 +205,16 @@ def _load_w(ref, scale_ref):
     return w
 
 
-def _conv1x1(x, w, sh, sw):
-    return jnp.dot(x[:, ::sh, ::sw, :], w, preferred_element_type=jnp.float32)
+def _conv1x1(x, w):
+    return jnp.dot(x, w, preferred_element_type=jnp.float32)
+
+
+def _strided(x, sh, sw):
+    """The block's stride, taken by XLA before the kernel: Mosaic has no
+    strided value slice ("Only 2D gather is supported"), and every consumer
+    of `x` in a strided block is a strided 1x1 conv (a strided block always
+    projects its shortcut), so the kernel needs only these positions."""
+    return x if (sh, sw) == (1, 1) else x[:, ::sh, ::sw, :]
 
 
 def _conv3x3_same(x, w):
@@ -226,25 +234,25 @@ def _kernel_stats(v):
     return mean, var
 
 
-def _train_body(sh, sw, eps, act, project, x_ref, *refs):
+def _train_body(eps, act, project, x_ref, *refs):
     nw = 12 if project else 9
     win, outs = refs[:nw], refs[nw:]
     (wa, ga, ba, wb, gb, bb, wc, gc, bc) = win[:9]
     x = _f32(x_ref)
 
-    a = _conv1x1(x, _f32(wa), sh, sw)
+    a = _conv1x1(x, _f32(wa))
     ma, va = _kernel_stats(a)
     a = _in_kernel_norm(a, ma, va, _f32(ga), _f32(ba), eps, act)
     h = _conv3x3_same(a, _f32(wb))
     mb, vb = _kernel_stats(h)
     h = _in_kernel_norm(h, mb, vb, _f32(gb), _f32(bb), eps, act)
-    c = _conv1x1(h, _f32(wc), 1, 1)
+    c = _conv1x1(h, _f32(wc))
     mc, vc = _kernel_stats(c)
     c = _in_kernel_norm(c, mc, vc, _f32(gc), _f32(bc), eps, "identity")
     stats = [ma, va, mb, vb, mc, vc]
     if project:
         wp, gp, bp = win[9:]
-        p = _conv1x1(x, _f32(wp), sh, sw)
+        p = _conv1x1(x, _f32(wp))
         mp, vp = _kernel_stats(p)
         shortcut = _in_kernel_norm(p, mp, vp, _f32(gp), _f32(bp), eps,
                                    "identity")
@@ -258,7 +266,7 @@ def _train_body(sh, sw, eps, act, project, x_ref, *refs):
         ref[...] = s.reshape(1, -1)
 
 
-def _infer_body(sh, sw, eps, act, project, int8, x_ref, *refs):
+def _infer_body(eps, act, project, int8, x_ref, *refs):
     # Per-branch operand groups: (w, [scale], gamma, beta, mean, var).
     per = 6 if int8 else 5
     groups = [refs[i * per:(i + 1) * per]
@@ -274,40 +282,38 @@ def _infer_body(sh, sw, eps, act, project, int8, x_ref, *refs):
 
     x = _f32(x_ref)
     wa, ga, ba, ma, va = unpack(groups[0])
-    a = _in_kernel_norm(_conv1x1(x, wa, sh, sw), ma, va, ga, ba, eps, act)
+    a = _in_kernel_norm(_conv1x1(x, wa), ma, va, ga, ba, eps, act)
     wb, gb, bb, mb, vb = unpack(groups[1])
     h = _in_kernel_norm(_conv3x3_same(a, wb), mb, vb, gb, bb, eps, act)
     wc, gc, bc, mc, vc = unpack(groups[2])
-    c = _in_kernel_norm(_conv1x1(h, wc, 1, 1), mc, vc, gc, bc, eps,
-                        "identity")
+    c = _in_kernel_norm(_conv1x1(h, wc), mc, vc, gc, bc, eps, "identity")
     if project:
         wp, gp, bp, mp, vp = unpack(groups[3])
-        shortcut = _in_kernel_norm(_conv1x1(x, wp, sh, sw), mp, vp, gp, bp,
-                                   eps, "identity")
+        shortcut = _in_kernel_norm(_conv1x1(x, wp), mp, vp, gp, bp, eps,
+                                   "identity")
     else:
         shortcut = x
     y_ref[...] = _ACTS[act](c + shortcut).astype(y_ref.dtype)
 
 
 @functools.lru_cache(maxsize=32)
-def _train_call(b, h, w, cin, f1, f3, sh, sw, eps, act, project, xdtype,
+def _train_call(b, ho, wo, cin, f1, f3, eps, act, project, xdtype,
                 interpret):
+    """`ho`/`wo`: the block's output extent (the input arrives strided)."""
     from jax.experimental import pallas as pl
 
-    ho, wo = -(-h // sh), -(-w // sw)
     stat_dims = (f1, f1, f1, f1, f3, f3) + ((f3, f3) if project else ())
     outs = [jax.ShapeDtypeStruct((b, ho, wo, f3), jnp.dtype(xdtype))]
     outs += [jax.ShapeDtypeStruct((1, d), jnp.float32) for d in stat_dims]
-    body = functools.partial(_train_body, sh, sw, eps, act, project)
+    body = functools.partial(_train_body, eps, act, project)
     return pl.pallas_call(body, out_shape=outs, interpret=interpret)
 
 
 @functools.lru_cache(maxsize=32)
-def _infer_call(b, h, w, cin, f1, f3, sh, sw, eps, act, project, int8,
+def _infer_call(b, ho, wo, cin, f1, f3, eps, act, project, int8,
                 xdtype, interpret):
+    """`ho`/`wo`: the block's output extent (the input arrives strided)."""
     from jax.experimental import pallas as pl
-
-    ho, wo = -(-h // sh), -(-w // sw)
 
     def full(shape):
         nd = len(shape)
@@ -316,14 +322,14 @@ def _infer_call(b, h, w, cin, f1, f3, sh, sw, eps, act, project, int8,
     branch_dims = [(cin, f1), (f1, f1), (f1, f3)]
     if project:
         branch_dims.append((cin, f3))
-    in_specs = [pl.BlockSpec((1, h, w, cin), lambda i: (i, 0, 0, 0))]
+    in_specs = [pl.BlockSpec((1, ho, wo, cin), lambda i: (i, 0, 0, 0))]
     for bi, (ci, fo) in enumerate(branch_dims):
         wshape = (3, 3, f1, f1) if bi == 1 else (ci, fo)
         in_specs.append(full(wshape))               # weight
         if int8:
             in_specs.append(full((1, fo)))          # __scale
         in_specs += [full((1, fo))] * 4             # gamma, beta, mean, var
-    body = functools.partial(_infer_body, sh, sw, eps, act, project, int8)
+    body = functools.partial(_infer_body, eps, act, project, int8)
     return pl.pallas_call(
         body,
         grid=(b,),
@@ -404,16 +410,17 @@ def bottleneck_forward(x, params, state, *, stride, project, eps,
 
     from deeplearning4j_tpu.kernels import _diff
 
-    interpret = jax.default_backend() != "tpu"
+    interpret = registry.interpret_mode()
     b, h, w, cin = (int(d) for d in x.shape)
     sh, sw = int(stride[0]), int(stride[1])
+    ho, wo = -(-h // sh), -(-w // sw)
 
     def row(v, feats):
         return jnp.broadcast_to(
             jnp.asarray(v, jnp.float32), (int(feats),)).reshape(1, -1)
 
     if train:
-        call = _train_call(b, h, w, cin, f1, f3, sh, sw, eps, act,
+        call = _train_call(b, ho, wo, cin, f1, f3, eps, act,
                            bool(project), str(x.dtype), interpret)
         nstat = len(stat_keys(project))
 
@@ -427,7 +434,7 @@ def bottleneck_forward(x, params, state, *, stride, project, eps,
                 if n != "b":
                     wv = wv.reshape(wv.shape[-2], feats)
                 kin += [wv, row(gv, feats), row(bv, feats)]
-            out = call(xv, *kin)
+            out = call(_strided(xv, sh, sw), *kin)
             return out[0], tuple(s.reshape(-1) for s in out[1:1 + nstat])
 
         def ref_fn(xv, *wflat):
@@ -443,7 +450,7 @@ def bottleneck_forward(x, params, state, *, stride, project, eps,
         y, stats = _diff.pallas_fwd_ref_bwd(pallas_fn, ref_fn)(x, *args)
         return y, dict(zip(stat_keys(project), stats))
 
-    call = _infer_call(b, h, w, cin, f1, f3, sh, sw, eps, act,
+    call = _infer_call(b, ho, wo, cin, f1, f3, eps, act,
                        bool(project), int8, str(x.dtype), interpret)
 
     def kernel_inputs(xv, *wflat):
@@ -459,7 +466,7 @@ def bottleneck_forward(x, params, state, *, stride, project, eps,
             kin += [row(gv, feats), row(bv, feats),
                     row(state[f"mean_{n}"], feats),
                     row(state[f"var_{n}"], feats)]
-        return call(xv, *kin)
+        return call(_strided(xv, sh, sw), *kin)
 
     args = []
     for n in names:

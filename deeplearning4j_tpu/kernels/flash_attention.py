@@ -9,11 +9,10 @@ stops at the diagonal) while they fit VMEM, and a streaming kernel
 (k-blocks as the innermost grid dim, VMEM scratch accumulators, O(block)
 memory at any T) beyond it.
 
-Measured on the driver's v5e chip (bf16, BH=8, D=64, blocks 256):
-1.2x XLA dense at T=2k, 1.6x at 8k, 3.1x at 16k, and still running at
-T=65k where dense attention no longer fits at all (PERF.md §6). Reached
-via `parallel.sequence.attention(..., impl="auto")`, the framework's
-default attention entry.
+Its speed against XLA dense attention is not measured on the current
+machine (PERF.md §6 holds an earlier chip set-up's figures). Reached via
+`parallel.sequence.attention(..., impl="auto")`, the framework's default
+attention entry.
 
 The streaming layout enumerates its (q-block, k-block) pairs through a
 SCALAR-PREFETCHED index sequence (`_pair_arrays`): for causal attention
@@ -26,11 +25,12 @@ Differentiation: `flash_attention` carries a custom_vjp with a Pallas
 backward in BOTH regimes — the standard two-kernel flash formulation
 (dq over q-blocks; dk/dv over k-blocks) recomputing p from the saved lse
 per block, O(T·D) memory. While K/V fit VMEM the backward kernels keep
-them resident (fetched once per batch-head; measured fwd+bwd 1.5x the XLA
-dense VJP at T=8k bf16); beyond that they stream k/v (dq) and q/do (dkv)
-blocks through the same triangular prefetch sequences, so TRAINING at any
-block-multiple T never materializes a [T, T] matrix. Only non-multiple T
-falls back to the XLA dense VJP. For sequence-sharded long-T training use
+them resident (fetched once per batch-head); beyond that they stream k/v
+(dq) and q/do (dkv) blocks through the same triangular prefetch sequences,
+so TRAINING at any block-multiple T never materializes a [T, T] matrix. A
+T that is not a block multiple is the registry's decision, not the
+kernel's: `is_available` answers no and `auto` resolves the XLA dense
+path, forward and VJP. For sequence-sharded long-T training use
 ring attention (`parallel/sequence.py`); this kernel is the single-device
 path.
 
@@ -187,18 +187,32 @@ def _flash_stream_kernel(i_ref, j_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0] = m_ref[:] + jnp.log(l)
 
 
-def _on_tpu() -> bool:
-    return jax.devices()[0].platform == "tpu"
+# Above this footprint of the operands a resident kernel keeps whole, the
+# kernel would oversubscribe VMEM (16 MiB scoped by default on a v5e,
+# shared with the q/out blocks and double buffering). Set from what the
+# chip's compiler accepts: every resident forward and backward tried at or
+# under it compiles (D 64/128/256, f32 and bf16); the forward is refused
+# at 10 MiB (bf16 D=64 T=20480) and the backward at 12 MiB (T=8192).
+_RESIDENT_KV_LIMIT = 8 * 1024 * 1024
 
 
-# Above this K/V footprint the resident kernel would oversubscribe VMEM
-# (~16 MB/core, shared with q/out blocks and double buffering).
-_RESIDENT_KV_LIMIT = 6 * 1024 * 1024
+def _resident(T: int, D: int, itemsize: int, backward: bool = False) -> bool:
+    """Whether the resident kernels fit VMEM at this shape. Counts the
+    operands kept whole as the chip lays them out, minor dim padded to 128
+    lanes: K and V (forward, dq), or q and do plus the `[T, 1]` f32 lse and
+    d_row columns (dk/dv) — a 1-wide column costs a full lane tile per 8
+    rows, which at long T outweighs q and do themselves."""
+    lanes = -(-D // 128) * 128
+    held = 2 * T * lanes * itemsize
+    if backward:
+        held += 2 * T * 128 * 4
+    return held <= _RESIDENT_KV_LIMIT
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("causal", "scale", "block_q", "block_k"))
-def _flash_fwd_stream_bhtd(q, k, v, causal, scale, block_q, block_k):
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "scale", "block_q", "block_k", "interpret"))
+def _flash_fwd_stream_bhtd(q, k, v, causal, scale, block_q, block_k,
+                           interpret):
     """Streaming forward via the prefetched block sequence: (o, lse)."""
     from jax.experimental.pallas import tpu as pltpu
 
@@ -232,17 +246,16 @@ def _flash_fwd_stream_bhtd(q, k, v, causal, scale, block_q, block_k):
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct((BH, T, 1), jnp.float32)],
-        interpret=not _on_tpu(),
+        interpret=interpret,
     )(jnp.asarray(i_idx), jnp.asarray(j_idx), q, k, v)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("causal", "scale", "block_q", "block_k"))
-def _flash_fwd_bhtd(q, k, v, causal, scale, block_q, block_k):
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "scale", "block_q", "block_k", "interpret"))
+def _flash_fwd_bhtd(q, k, v, causal, scale, block_q, block_k, interpret):
     """q/k/v: [BH, T, D] -> [BH, T, D]."""
     BH, T, D = q.shape
-    kv_bytes = 2 * T * D * q.dtype.itemsize
-    if kv_bytes <= _RESIDENT_KV_LIMIT:
+    if _resident(T, D, q.dtype.itemsize):
         return pl.pallas_call(
             functools.partial(_flash_kernel_resident, block_k=block_k,
                               causal=causal, scale=scale),
@@ -254,9 +267,10 @@ def _flash_fwd_bhtd(q, k, v, causal, scale, block_q, block_k):
                 pl.BlockSpec((1, T, D), lambda b, i: (b, 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-            interpret=not _on_tpu(),
+            interpret=interpret,
         )(q, k, v)
-    o, _ = _flash_fwd_stream_bhtd(q, k, v, causal, scale, block_q, block_k)
+    o, _ = _flash_fwd_stream_bhtd(q, k, v, causal, scale, block_q, block_k,
+                                  interpret)
     return o
 
 
@@ -268,21 +282,27 @@ def _dense_ref(q, k, v, causal, scale):
     return dense_attention(q, k, v, causal=causal, scale=scale)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _require_block_multiple(T, block_q, block_k):
+    if T % block_q or T % block_k:
+        raise ValueError(
+            f"flash kernel needs T % block == 0; got T={T}, blocks "
+            f"({block_q}, {block_k})")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash_attention_pallas(q, k, v, causal: bool = True,
                             scale: Optional[float] = None,
-                            block_q: int = 256, block_k: int = 256):
+                            block_q: int = 256, block_k: int = 256,
+                            interpret: bool = False):
     """Flash multi-head attention. q/k/v: [B, T, H, Dh] -> [B, T, H, Dh].
-
-    Falls back to the XLA dense path when T is not a block multiple (the
-    kernel requires T % block == 0)."""
+    T must be a multiple of both blocks (`flash_attention` asks the
+    registry, which resolves the XLA dense path otherwise)."""
     scale = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
     B, T, H, D = q.shape
-    if T % block_q or T % block_k:
-        return _dense_ref(q, k, v, causal, scale)
+    _require_block_multiple(T, block_q, block_k)
     to_bhtd = lambda a: jnp.swapaxes(a, 1, 2).reshape(B * H, T, D)
     o = _flash_fwd_bhtd(to_bhtd(q), to_bhtd(k), to_bhtd(v), causal, scale,
-                        block_q, block_k)
+                        block_q, block_k, interpret)
     return jnp.swapaxes(o.reshape(B, H, T, D), 1, 2)
 
 
@@ -294,50 +314,49 @@ def flash_attention(q, k, v, causal: bool = True,
     ``auto``) or the XLA dense reference under ``DL4J_TPU_KERNELS=xla`` /
     a per-kernel override. Same [B, T, H, Dh] contract either way."""
     res = _registry.resolve("flash_attention",
-                            shapes=(tuple(int(d) for d in q.shape),),
-                            dtypes=(str(q.dtype),))
+                            shapes=tuple(int(d) for d in q.shape),
+                            dtypes=(str(q.dtype),),
+                            meta=(("block_q", int(block_q)),
+                                  ("block_k", int(block_k))))
     if res.impl != "pallas":
         s = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
         return _dense_ref(q, k, v, causal, s)
-    return _flash_attention_pallas(q, k, v, causal, scale, block_q, block_k)
+    return _flash_attention_pallas(q, k, v, causal, scale, block_q, block_k,
+                                   _registry.interpret_mode())
 
 
-def _fwd(q, k, v, causal, scale, block_q, block_k):
+def _fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     scale_v = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
     B, T, H, D = q.shape
-    if T % block_q or T % block_k:
-        # Non-multiple T: dense XLA forward AND backward.
-        return (_flash_attention_pallas(q, k, v, causal, scale, block_q,
-                                        block_k),
-                (q, k, v, None, None))
+    _require_block_multiple(T, block_q, block_k)
     to_bhtd = lambda a: jnp.swapaxes(a, 1, 2).reshape(B * H, T, D)
-    if 2 * T * D * q.dtype.itemsize <= _RESIDENT_KV_LIMIT:
+    if _resident(T, D, q.dtype.itemsize):
         o, lse = _flash_fwd_lse_bhtd(to_bhtd(q), to_bhtd(k), to_bhtd(v),
-                                     causal, scale_v, block_q, block_k)
+                                     causal, scale_v, block_q, block_k,
+                                     interpret)
     else:
         o, lse = _flash_fwd_stream_bhtd(
             to_bhtd(q), to_bhtd(k), to_bhtd(v), causal, scale_v,
-            block_q, block_k)
+            block_q, block_k, interpret)
     return (jnp.swapaxes(o.reshape(B, H, T, D), 1, 2), (q, k, v, o, lse))
 
 
-def _bwd(causal, scale, block_q, block_k, res, g):
+def _bwd(causal, scale, block_q, block_k, interpret, res, g):
     q, k, v, o_bhtd, lse = res
     scale_v = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
-    if lse is None:
-        _, vjp = jax.vjp(
-            lambda q, k, v: _dense_ref(q, k, v, causal, scale_v), q, k, v)
-        return vjp(g)
     B, T, H, D = q.shape
     to_bhtd = lambda a: jnp.swapaxes(a, 1, 2).reshape(B * H, T, D)
-    if 2 * T * D * q.dtype.itemsize <= _RESIDENT_KV_LIMIT:
+    # The backward decides for itself: either forward leaves the same
+    # (o, lse), and the resident dk/dv kernel outgrows VMEM well before
+    # the resident forward does.
+    if _resident(T, D, q.dtype.itemsize, backward=True):
         dq, dk, dv = _flash_bwd_bhtd(
             to_bhtd(q), to_bhtd(k), to_bhtd(v), to_bhtd(g), o_bhtd, lse,
-            causal, scale_v, block_q, block_k)
+            causal, scale_v, block_q, block_k, interpret)
     else:
         dq, dk, dv = _flash_bwd_stream_bhtd(
             to_bhtd(q), to_bhtd(k), to_bhtd(v), to_bhtd(g), o_bhtd, lse,
-            causal, scale_v, block_q, block_k)
+            causal, scale_v, block_q, block_k, interpret)
     back = lambda a: jnp.swapaxes(a.reshape(B, H, T, D), 1, 2)
     return (back(dq).astype(q.dtype), back(dk).astype(k.dtype),
             back(dv).astype(v.dtype))
@@ -347,6 +366,12 @@ _flash_attention_pallas.defvjp(_fwd, _bwd)
 
 
 def _pallas_available(backend, shapes, dtypes, meta=(), forced=False):
+    if shapes:
+        m = dict(meta)
+        T, bq, bk = shapes[1], m.get("block_q", 256), m.get("block_k", 256)
+        if T % bq or T % bk:
+            return False, (f"T={T} is not a multiple of the ({bq}, {bk}) "
+                           "blocks the kernel tiles by")
     if backend == "tpu":
         return True, "TPU flash kernel (resident/streaming hybrid, PERF.md §6)"
     return True, ("interpret mode off-TPU (numerics identical, speed "
@@ -471,9 +496,10 @@ def _flash_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, d_ref,
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("causal", "scale", "block_q", "block_k"))
-def _flash_fwd_lse_bhtd(q, k, v, causal, scale, block_q, block_k):
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "scale", "block_q", "block_k", "interpret"))
+def _flash_fwd_lse_bhtd(q, k, v, causal, scale, block_q, block_k,
+                        interpret):
     """Resident forward emitting (o, lse). [BH, T, D] ->
     ([BH, T, D], [BH, T, 1] fp32)."""
     BH, T, D = q.shape
@@ -490,13 +516,14 @@ def _flash_fwd_lse_bhtd(q, k, v, causal, scale, block_q, block_k):
         ],
         out_specs=[pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
                    pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0))],
-        interpret=not _on_tpu(),
+        interpret=interpret,
     )(q, k, v)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("causal", "scale", "block_q", "block_k"))
-def _flash_bwd_bhtd(q, k, v, do, o, lse, causal, scale, block_q, block_k):
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "scale", "block_q", "block_k", "interpret"))
+def _flash_bwd_bhtd(q, k, v, do, o, lse, causal, scale, block_q, block_k,
+                    interpret):
     """Resident backward: (dq, dk, dv) each [BH, T, D]."""
     BH, T, D = q.shape
     d_row = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
@@ -515,7 +542,7 @@ def _flash_bwd_bhtd(q, k, v, do, o, lse, causal, scale, block_q, block_k):
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-        interpret=not _on_tpu(),
+        interpret=interpret,
     )(q, k, v, do, lse, d_row)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
@@ -533,7 +560,7 @@ def _flash_bwd_bhtd(q, k, v, do, o, lse, causal, scale, block_q, block_k):
         ],
         out_specs=[pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
                    pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0))],
-        interpret=not _on_tpu(),
+        interpret=interpret,
     )(k, v, q, do, lse, d_row)
     return dq, dk, dv
 
@@ -636,10 +663,10 @@ def _flash_bwd_dkv_stream_kernel(i_ref, j_ref, k_ref, v_ref, q_ref, do_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("causal", "scale", "block_q", "block_k"))
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "scale", "block_q", "block_k", "interpret"))
 def _flash_bwd_stream_bhtd(q, k, v, do, o, lse, causal, scale, block_q,
-                           block_k):
+                           block_k, interpret):
     """Streaming backward: (dq, dk, dv) each [BH, T, D], O(block) VMEM."""
     from jax.experimental.pallas import tpu as pltpu
 
@@ -669,7 +696,7 @@ def _flash_bwd_stream_bhtd(q, k, v, do, o, lse, causal, scale, block_q,
                           block_k=block_k, nk=nk, causal=causal, scale=scale),
         grid_spec=dq_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
-        interpret=not _on_tpu(),
+        interpret=interpret,
     )(jnp.asarray(ir), jnp.asarray(jr), q, k, v, do, lse, d_row)
 
     ic, jc = _pair_arrays(nq, nk, block_q, block_k, causal, "col")
@@ -697,7 +724,7 @@ def _flash_bwd_stream_bhtd(q, k, v, do, o, lse, causal, scale, block_q,
         grid_spec=dkv_spec,
         out_shape=[jax.ShapeDtypeStruct(k.shape, jnp.float32),
                    jax.ShapeDtypeStruct(v.shape, jnp.float32)],
-        interpret=not _on_tpu(),
+        interpret=interpret,
     )(jnp.asarray(ic), jnp.asarray(jc), k, v, q, do, lse, d_row)
     return dq, dk, dv
 
@@ -731,16 +758,21 @@ def _paged_gather_dense(q, k_pages, v_pages, page_table, pos, causal):
 
 
 def _paged_flash_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                        acc_ref, m_ref, l_ref, *, page, n_pages, causal,
-                        scale):
-    """One (batch, head, logical-page) step: the page table is scalar-
-    prefetched, so the k/v BlockSpec index maps DMA exactly the physical
-    page this slot's logical page j resolves to — no dense gather ever
-    materializes. VMEM scratch (acc, m, l) carries the online softmax
-    across the NP sequential grid steps."""
+                        acc_ref, m_ref, l_ref, *, page, n_pages, heads,
+                        causal, scale):
+    """One (batch, logical-page) step over ALL heads: the page table is
+    scalar-prefetched, so the k/v BlockSpec index maps DMA exactly the
+    physical page this slot's logical page j resolves to — no dense gather
+    ever materializes. Operands arrive with heads folded into the lane dim
+    (`[.., H*D]`, a free reshape of the pool's `[.., H, D]`): a block then
+    spans whole trailing dims, which is what the TPU lowering requires (a
+    one-head `(1, page, 1, D)` block of the 4-D pool is refused), and each
+    head is a static lane slice. VMEM scratch (acc, m, l) carries the
+    online softmax across the NP sequential grid steps."""
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
     T = q_ref.shape[1]
+    D = q_ref.shape[2] // heads
 
     @pl.when(j == 0)
     def _init():
@@ -748,68 +780,77 @@ def _paged_flash_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32) * scale
-    k = k_ref[0, :, 0, :].astype(jnp.float32)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # [T, page]
-    kpos = j * page + jax.lax.broadcasted_iota(jnp.int32, (T, page), 1)
-    if causal:
-        limit = (pos_ref[b] + 1
-                 + jax.lax.broadcasted_iota(jnp.int32, (T, page), 0))
-    else:
-        limit = pos_ref[b] + T
-    s = jnp.where(kpos < limit, s, _NEG)
-    blk_max = jnp.max(s, axis=1, keepdims=True)
-    new_m = jnp.maximum(m_ref[...], blk_max)
-    p = jnp.exp(s - new_m)
-    corr = jnp.exp(m_ref[...] - new_m)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = new_m
+    # Pages wholly past this row's last new position hold nothing it may
+    # attend to (unallocated table entries point at the zero page).
+    @pl.when(j * page < pos_ref[b] + T)
+    def _fold():
+        kpos = j * page + jax.lax.broadcasted_iota(jnp.int32, (T, page), 1)
+        if causal:
+            limit = (pos_ref[b] + 1
+                     + jax.lax.broadcasted_iota(jnp.int32, (T, page), 0))
+        else:
+            limit = pos_ref[b] + T
+        visible = kpos < limit
+        for h in range(heads):
+            lanes = slice(h * D, (h + 1) * D)
+            q = q_ref[0, :, lanes].astype(jnp.float32) * scale
+            k = k_ref[0, :, lanes].astype(jnp.float32)
+            v = v_ref[0, :, lanes].astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(visible, s, _NEG)                  # [T, page]
+            m = m_ref[h]
+            new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - new_m)
+            corr = jnp.exp(m - new_m)
+            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = new_m
 
     @pl.when(j == n_pages - 1)
     def _finish():
-        o_ref[0, :, 0, :] = (
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        for h in range(heads):
+            o_ref[0, :, h * D:(h + 1) * D] = (
+                acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("causal",))
-def _paged_flash(q, k_pages, v_pages, page_table, pos, causal):
+@functools.partial(jax.jit, static_argnames=("causal", "interpret"))
+def _paged_flash(q, k_pages, v_pages, page_table, pos, causal, interpret):
     from jax.experimental.pallas import tpu as pltpu
 
     B, T, H, D = q.shape
-    page = k_pages.shape[1]
+    P, page = k_pages.shape[:2]
     NP = page_table.shape[1]
     scale = D ** -0.5
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, H, NP),
+        grid=(B, NP),
         in_specs=[
-            pl.BlockSpec((1, T, 1, D),
-                         lambda b, h, j, pt, pos: (b, 0, h, 0)),
-            pl.BlockSpec((1, page, 1, D),
-                         lambda b, h, j, pt, pos: (pt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, D),
-                         lambda b, h, j, pt, pos: (pt[b, j], 0, h, 0)),
+            pl.BlockSpec((1, T, H * D), lambda b, j, pt, pos: (b, 0, 0)),
+            pl.BlockSpec((1, page, H * D),
+                         lambda b, j, pt, pos: (pt[b, j], 0, 0)),
+            pl.BlockSpec((1, page, H * D),
+                         lambda b, j, pt, pos: (pt[b, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, T, 1, D),
-                               lambda b, h, j, pt, pos: (b, 0, h, 0)),
+        out_specs=pl.BlockSpec((1, T, H * D), lambda b, j, pt, pos: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((T, D), jnp.float32),
-            pltpu.VMEM((T, 1), jnp.float32),
-            pltpu.VMEM((T, 1), jnp.float32),
+            pltpu.VMEM((H, T, D), jnp.float32),
+            pltpu.VMEM((H, T, 1), jnp.float32),
+            pltpu.VMEM((H, T, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_paged_flash_kernel, page=page, n_pages=NP,
-                          causal=causal, scale=scale),
+                          heads=H, causal=causal, scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=not _on_tpu(),
+        out_shape=jax.ShapeDtypeStruct((B, T, H * D), q.dtype),
+        interpret=interpret,
     )(page_table, jnp.reshape(pos, (-1,)).astype(jnp.int32),
-      q, k_pages, v_pages)
+      q.reshape(B, T, H * D), k_pages.reshape(P, page, H * D),
+      v_pages.reshape(P, page, H * D))
+    return out.reshape(B, T, H, D)
 
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, pos, causal):
@@ -823,20 +864,39 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, pos, causal):
     float-close), else the XLA dense-gather composite, which is
     bit-identical to the dense stepper's `_cached_decode_attention`.
     Inference-only: no VJP is defined (see module note above)."""
+    B, T, H, D = q.shape
     res = _registry.resolve(
         "flash_attention_paged",
-        shapes=(tuple(q.shape), tuple(k_pages.shape),
-                tuple(page_table.shape)),
-        dtypes=(str(q.dtype),), meta=(bool(causal),))
+        shapes=(B, T, H, D, k_pages.shape[0], k_pages.shape[1],
+                page_table.shape[1]),
+        dtypes=(str(q.dtype),), meta=(("causal", bool(causal)),))
     if res.impl == "pallas":
-        return _paged_flash(q, k_pages, v_pages, page_table, pos, causal)
+        return _paged_flash(q, k_pages, v_pages, page_table, pos, causal,
+                            _registry.interpret_mode())
     return _paged_gather_dense(q, k_pages, v_pages, page_table, pos, causal)
 
 
+# VMEM the paged kernel may spend on its K and V page blocks (all heads of
+# a page each, double-buffered). The chip's compiler accepts 14 MiB and
+# refuses 16 MiB (f32, page 256, H=32, D=128) under the 16 MiB scoped
+# default.
+_PAGED_KV_VMEM = 12 * 1024 * 1024
+
+
 def _paged_pallas_available(backend, shapes, dtypes, meta=(), forced=False):
+    """`shapes` is `(B, T, H, D, pages, page_size, pages_per_seq)`."""
     if backend == "tpu":
+        if shapes:
+            _, _, H, D, _, page, _ = shapes
+            itemsize = 2 if dtypes and dtypes[0] == "bfloat16" else 4
+            held = 4 * page * H * D * itemsize
+            if held > _PAGED_KV_VMEM:
+                return False, (
+                    f"K+V page blocks need {held / 2**20:.1f} MiB of VMEM "
+                    f"(page={page}, H={H}, D={D}, double-buffered) > "
+                    f"{_PAGED_KV_VMEM / 2**20:.0f} MiB")
         return True, ("TPU paged-gather flash kernel (scalar-prefetched "
-                      "page table)")
+                      "page table, all heads of a page per block)")
     if forced:
         return True, ("interpret mode off-TPU (float-close parity tests "
                       "only)")

@@ -16,7 +16,7 @@ bodies moved here verbatim (bit-exactness contract): same tree_maps, same
 bias-correction branch, so `DL4J_TPU_KERNELS=xla` (and auto off-TPU)
 trains bit-identically to the pre-PR engines. Hyperparameters stay
 Python floats baked into the trace; `lr`/`step` may be traced scalars and
-are passed into the kernel as a tiny (1, 3) operand.
+are passed into the kernel as a (3,) SMEM operand.
 
 Scope: `adam`, `nesterovs`, `rmsprop` (the issue's set). Other updaters
 never enter the seam. Mixed-dtype or non-float32 trees fall back.
@@ -34,6 +34,10 @@ from deeplearning4j_tpu.kernels import registry
 
 _KINDS = ("adam", "nesterovs", "rmsprop")
 _TILE = 8 * 128
+# Rows of the [rows, 128] f32 view per grid step: 512 KiB per operand
+# block, so Adam's 3 inputs + 3 outputs, double-buffered, hold 6 MiB of
+# the chip's 16 MiB default scoped VMEM.
+_BLOCK_ROWS = 1024
 
 
 def _pallas_available(backend, shapes, dtypes, meta=(), forced=False):
@@ -107,9 +111,9 @@ def rmsprop_xla(state, grads, lr, step, decay, eps):
 
 
 def _adam_kernel(beta1, beta2, eps, m_ref, v_ref, g_ref, s_ref, mo, vo, do):
-    lr = s_ref[0, 0]
-    bc1 = s_ref[0, 1]
-    bc2 = s_ref[0, 2]
+    lr = s_ref[0]
+    bc1 = s_ref[1]
+    bc2 = s_ref[2]
     g = g_ref[...]
     m = beta1 * m_ref[...] + (1.0 - beta1) * g
     v = beta2 * v_ref[...] + (1.0 - beta2) * g * g
@@ -119,7 +123,7 @@ def _adam_kernel(beta1, beta2, eps, m_ref, v_ref, g_ref, s_ref, mo, vo, do):
 
 
 def _nesterovs_kernel(momentum, v_ref, g_ref, s_ref, vo, do):
-    lr = s_ref[0, 0]
+    lr = s_ref[0]
     v0 = v_ref[...]
     v = momentum * v0 - lr * g_ref[...]
     vo[...] = v
@@ -127,7 +131,7 @@ def _nesterovs_kernel(momentum, v_ref, g_ref, s_ref, vo, do):
 
 
 def _rmsprop_kernel(decay, eps, a_ref, g_ref, s_ref, ao, do):
-    lr = s_ref[0, 0]
+    lr = s_ref[0]
     a = decay * a_ref[...] + (1.0 - decay) * g_ref[...] * g_ref[...]
     ao[...] = a
     do[...] = lr * g_ref[...] / jnp.sqrt(a + eps)
@@ -136,16 +140,25 @@ def _rmsprop_kernel(decay, eps, a_ref, g_ref, s_ref, ao, do):
 @functools.lru_cache(maxsize=64)
 def _flat_call(kind: str, rows: int, hyper: tuple, interpret: bool):
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     body = {
         "adam": functools.partial(_adam_kernel, *hyper),
         "nesterovs": functools.partial(_nesterovs_kernel, *hyper),
         "rmsprop": functools.partial(_rmsprop_kernel, *hyper),
     }[kind]
+    # Tiled operands in = tiled operands out: state fields + gradient in,
+    # state fields + delta out.
     n_out = {"adam": 3, "nesterovs": 2, "rmsprop": 2}[kind]
     out = jax.ShapeDtypeStruct((rows, 128), jnp.float32)
-    return pl.pallas_call(body, out_shape=(out,) * n_out,
-                          interpret=interpret)
+    block = min(rows, _BLOCK_ROWS)
+    # A ragged last block is fine for an elementwise pass: rows past the
+    # end read unspecified values and their writes are dropped.
+    tile = pl.BlockSpec((block, 128), lambda i: (i, 0))
+    return pl.pallas_call(
+        body, out_shape=(out,) * n_out, grid=(pl.cdiv(rows, block),),
+        in_specs=[tile] * n_out + [pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_specs=(tile,) * n_out, interpret=interpret)
 
 
 def _to_tiles(vec):
@@ -163,8 +176,8 @@ def _scalars(lr, step, kind, hyper):
         t = jnp.asarray(step, jnp.float32) + 1.0
         bc1 = 1.0 - beta1 ** t
         bc2 = 1.0 - beta2 ** t
-        return jnp.stack([lr, bc1, bc2]).reshape(1, 3)
-    return jnp.stack([lr, lr, lr]).reshape(1, 3)
+        return jnp.stack([lr, bc1, bc2])
+    return jnp.stack([lr, lr, lr])
 
 
 def pallas_update(kind, state, grads, lr, step, hyper):
@@ -176,7 +189,7 @@ def pallas_update(kind, state, grads, lr, step, hyper):
     sflat = [ravel_pytree(state[f])[0] for f in fields]
     tiles = _to_tiles(gflat)
     call = _flat_call(kind, tiles.shape[0], hyper,
-                      interpret=jax.default_backend() != "tpu")
+                      interpret=registry.interpret_mode())
     outs = call(*[_to_tiles(s) for s in sflat], tiles,
                 _scalars(lr, step, kind, hyper))
     outs = [o.reshape(-1)[:n] for o in outs]

@@ -211,7 +211,7 @@ def resolve_cell(*, batch, n_out, dtype, peephole, masked, gate_activation,
 
         fused = pallas_cell(int(batch), int(n_out), bool(peephole),
                             bool(masked), str(activation), str(dtype),
-                            interpret=jax.default_backend() != "tpu")
+                            interpret=registry.interpret_mode())
         # The cell runs inside the engines' value_and_grad: Pallas forward,
         # XLA-reference backward (kernels/_diff.py).
         return _diff.pallas_fwd_ref_bwd(

@@ -4,18 +4,22 @@
 already does well, and whose single-pass form is part of the bit-
 exactness contract) and then runs an elementwise chain — normalize,
 scale/shift, activation — that re-reads the activation tensor from HBM
-between fusion boundaries. The Pallas path runs that chain in one VMEM
-pass over the `[rows, features]` view: BatchNorm takes the (XLA-computed)
-mean/var as operands; LayerNorm computes its per-row stats in-kernel.
+between fusion boundaries. The Pallas path runs that chain in one pass
+over the `[rows, features]` view, row-tiled so a grid step holds one
+`_BLOCK_BYTES` block in VMEM whatever the row count: BatchNorm takes the
+(XLA-computed) mean/var as operands; LayerNorm computes its per-row stats
+in-kernel. Arithmetic is f32 inside the kernel for either operand dtype
+(a v5e has no bf16 `sqrt`/`rsqrt`/`tanh` unit); the store casts back.
 
 The XLA fallbacks are the LITERAL pre-registry expressions moved here
 verbatim — same ops, same order — so `DL4J_TPU_KERNELS=xla` (and auto
 off-TPU) produces bit-identical jaxprs to the pre-PR layers.
 
 Availability (auto): TPU backend, float32 or bfloat16, activation in the
-in-kernel set, feature dim a lane (128) multiple and row count a sublane
-(8) multiple. Forced `pallas` keeps the structural constraints and runs
-interpret mode off-TPU (the CPU parity tests' path).
+in-kernel set, feature dim a lane (128) multiple no wider than
+`_MAX_FEATS` and row count a sublane (8) multiple. Forced `pallas` keeps
+the structural constraints and runs interpret mode off-TPU (the CPU parity
+tests' path).
 """
 
 from __future__ import annotations
@@ -54,6 +58,11 @@ def _pallas_available(backend, shapes, dtypes, meta=(), forced=False):
     if feats % 128 or rows % 8:
         return False, (f"rows={rows}, features={feats} not tile-aligned "
                        "(need features % 128 == 0 and rows % 8 == 0)")
+    if feats > _MAX_FEATS:
+        return False, (f"features={feats} > {_MAX_FEATS}: the smallest "
+                       "(32-row) block, double-buffered in and out, exceeds "
+                       "the 16 MiB scoped VMEM limit (the v5e compiler's "
+                       "RESOURCE_EXHAUSTED at f32 [1024, 32768])")
     return True, ("forced (TPU, tile-aligned)" if forced
                   else "TPU fused normalize+affine+activation")
 
@@ -93,17 +102,39 @@ def layernorm_xla(x, gamma, beta, eps, activation):
 # -------------------------------------------------------- Pallas path
 
 
+def _f32(ref):
+    return ref[...].astype(jnp.float32)
+
+
 def _bn_kernel(eps, act_name, x_ref, mu_ref, var_ref, g_ref, b_ref, o_ref):
-    xhat = (x_ref[...] - mu_ref[...]) / jnp.sqrt(var_ref[...] + eps)
-    o_ref[...] = _ACTS[act_name](g_ref[...] * xhat + b_ref[...])
+    xhat = (_f32(x_ref) - _f32(mu_ref)) / jnp.sqrt(_f32(var_ref) + eps)
+    out = _ACTS[act_name](_f32(g_ref) * xhat + _f32(b_ref))
+    o_ref[...] = out.astype(o_ref.dtype)
 
 
 def _ln_kernel(eps, act_name, x_ref, g_ref, b_ref, o_ref):
-    x = x_ref[...]
+    x = _f32(x_ref)
     mu = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.mean((x - mu) ** 2, axis=-1, keepdims=True)
     out = (x - mu) * jax.lax.rsqrt(var + eps)
-    o_ref[...] = _ACTS[act_name](out * g_ref[...] + b_ref[...])
+    out = _ACTS[act_name](out * _f32(g_ref) + _f32(b_ref))
+    o_ref[...] = out.astype(o_ref.dtype)
+
+
+# Widest feature dim whose smallest block the chip's compiler accepts.
+_MAX_FEATS = 16384
+
+# f32 bytes of one [block_rows, features] block. The kernel keeps the
+# input and output blocks double-buffered plus a few f32 temporaries of
+# the same extent, inside the chip's 16 MiB default scoped VMEM.
+_BLOCK_BYTES = 1024 * 1024
+
+
+def _block_rows(rows: int, feats: int) -> int:
+    """Rows per grid step: a multiple of 32 (whole sublane tiles for f32
+    and packed bf16 alike), or all rows when they fit one block."""
+    fit = max(32, _BLOCK_BYTES // (4 * feats) // 32 * 32)
+    return rows if rows <= fit else fit
 
 
 @functools.lru_cache(maxsize=64)
@@ -113,9 +144,16 @@ def _norm_call(op: str, rows: int, feats: int, eps: float, act_name: str,
 
     body = functools.partial(
         _bn_kernel if op == "batchnorm" else _ln_kernel, eps, act_name)
+    block = _block_rows(rows, feats)
+    tile = pl.BlockSpec((block, feats), lambda i: (i, 0))
+    vec = pl.BlockSpec((1, feats), lambda i: (0, 0))
+    n_vec = 4 if op == "batchnorm" else 2
+    # A ragged last block is safe: both bodies are row-independent, rows
+    # past the end read unspecified values and their writes are dropped.
     return pl.pallas_call(
         body, out_shape=jax.ShapeDtypeStruct((rows, feats), jnp.dtype(dtype)),
-        interpret=interpret)
+        grid=(pl.cdiv(rows, block),), in_specs=[tile] + [vec] * n_vec,
+        out_specs=tile, interpret=interpret)
 
 
 def _row_view(a):
@@ -149,7 +187,7 @@ def batchnorm_norm_act(x, mean, var, gamma, beta, eps, activation):
     feats = x.shape[-1]
     call = _norm_call("batchnorm", _row_view(x).shape[0], int(feats),
                       float(eps), str(activation), str(x.dtype),
-                      interpret=jax.default_backend() != "tpu")
+                      interpret=registry.interpret_mode())
     # Pallas forward, XLA-reference backward: the seam sits inside the
     # engines' value_and_grad (kernels/_diff.py).
     f = _diff.pallas_fwd_ref_bwd(
@@ -172,7 +210,7 @@ def layernorm_norm_act(x, gamma, beta, eps, activation):
     feats = x.shape[-1]
     call = _norm_call("layernorm", _row_view(x).shape[0], int(feats),
                       float(eps), str(activation), str(x.dtype),
-                      interpret=jax.default_backend() != "tpu")
+                      interpret=registry.interpret_mode())
     f = _diff.pallas_fwd_ref_bwd(
         call, lambda xv, g, b: layernorm_xla(xv, g, b, eps, activation))
     out = f(_row_view(x), _vec(gamma, feats, x.dtype),

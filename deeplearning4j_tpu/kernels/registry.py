@@ -46,6 +46,10 @@ from deeplearning4j_tpu import observability as _obs
 
 MODES = ("auto", "xla", "pallas")
 
+# Meta key the registry itself adds to a signature traced under a mesh of
+# more than one device (and `--probe --meta mesh_devices=N` passes by hand).
+MESH_DEVICES = "mesh_devices"
+
 # Kernel name -> module that registers its candidates at import.
 KERNEL_MODULES = {
     # Fused ResNet bottleneck chain (PR 19): conv1x1/BN/act x3 + residual
@@ -152,6 +156,14 @@ def probe_count() -> int:
     return _PROBES
 
 
+def resolved() -> Tuple[Resolution, ...]:
+    """Every resolution this process has made at a real call signature
+    (one per distinct signature): which implementation its programs were
+    traced with, and why — what `chip_smoke.py` prints beside each step."""
+    with _LOCK:
+        return tuple(res for key, res in _MEMO.items() if key[3])
+
+
 def clear_cache() -> None:
     """Drop the resolution memo (tests flip env knobs between asserts)."""
     with _LOCK:
@@ -161,7 +173,32 @@ def clear_cache() -> None:
 def _probe(impl: KernelImpl, backend, shapes, dtypes, meta, forced) -> Tuple[bool, str]:
     global _PROBES
     _PROBES += 1
-    return impl.is_available(backend, shapes, dtypes, meta=meta, forced=forced)
+    return _available(impl, backend, shapes, dtypes, meta, forced)
+
+
+def _available(impl: KernelImpl, backend, shapes, dtypes, meta,
+               forced) -> Tuple[bool, str]:
+    """`impl.is_available`, after the one rule that holds for every Pallas
+    body on a TPU: inside a program that GSPMD partitions over several
+    devices the chip's compiler refuses it outright, whatever the shape."""
+    n = dict(meta).get(MESH_DEVICES, 1)
+    if impl.name == "pallas" and backend == "tpu" and n > 1:
+        return False, (f"the program is partitioned over a {n}-device mesh "
+                       "and the TPU compiler refuses a Pallas body there "
+                       "(\"Mosaic kernels cannot be automatically "
+                       "partitioned. Please wrap the call in a shard_map.\")")
+    return impl.is_available(backend, shapes, dtypes, meta=meta,
+                             forced=forced)
+
+
+def _mesh_devices() -> int:
+    """Devices of the mesh the program being traced is partitioned over: the
+    active `ParallelContext`'s (wrappers and steppers install it around
+    every sharded dispatch), 1 without one."""
+    from deeplearning4j_tpu.parallel.context import current_context
+
+    ctx = current_context()
+    return 1 if ctx is None else int(ctx.mesh.devices.size)
 
 
 def _count_dispatch(kernel: str, impl: str) -> None:
@@ -178,6 +215,14 @@ def _default_backend() -> str:
     return jax.default_backend()
 
 
+def interpret_mode() -> bool:
+    """Whether a Pallas body that resolved must run in interpret mode: off
+    the TPU there is no Mosaic compiler. The one place the kernels ask —
+    on a TPU this is False, so a body the chip's compiler refuses fails
+    its compile instead of running somewhere else."""
+    return _default_backend() != "tpu"
+
+
 def resolve(kernel: str, *, backend: Optional[str] = None,
             shapes: Tuple = (), dtypes: Tuple = (), meta: Tuple = ()) -> Resolution:
     """Pick the implementation for `kernel` under the current env mode.
@@ -192,6 +237,9 @@ def resolve(kernel: str, *, backend: Optional[str] = None,
         backend = _default_backend()
     _ensure(kernel)
     mode, source = mode_for(kernel)
+    n = _mesh_devices()
+    if n > 1:
+        meta = tuple(meta) + ((MESH_DEVICES, n),)
     key = (kernel, mode, backend, shapes, dtypes, meta)
     with _LOCK:
         res = _MEMO.get(key)
@@ -220,11 +268,15 @@ def _resolve_uncached(kernel, mode, source, backend, shapes, dtypes,
         else:
             note = f"{mode} forced via {source} but not a candidate; "
     last = None
+    refused = ""
     for c in candidates:
         ok, reason = _probe(c, backend, shapes, dtypes, meta, forced=False)
-        last = Resolution(kernel, c.name, note + reason)
+        # The winner's reason carries why each earlier candidate said no:
+        # "xla" alone does not say that it was the registry's decision.
+        last = Resolution(kernel, c.name, note + reason + refused)
         if ok:
             return last
+        refused += f" [{c.name} unavailable: {reason}]"
     # No candidate available (should not happen: every kernel registers an
     # unconditional XLA fallback) — surface the last probe's reason.
     return last
@@ -247,8 +299,7 @@ def probe(kernel: str, *, backend: Optional[str] = None, shapes: Tuple = (),
     rows = []
     for c in _REGISTRY[kernel]:
         forced = mode == c.name
-        ok, reason = c.is_available(backend, shapes, dtypes, meta=meta,
-                                    forced=forced)
+        ok, reason = _available(c, backend, shapes, dtypes, meta, forced)
         rows.append({"impl": c.name, "available": bool(ok),
                      "forced": forced, "reason": reason})
     selected = None

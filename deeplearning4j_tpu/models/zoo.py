@@ -533,12 +533,9 @@ class DecodeStepper:
         """Context manager active around every jitted dispatch: installs
         the stepper's ParallelContext (no-op wrapper when unsharded, so an
         externally-installed context is left alone)."""
-        from contextlib import nullcontext
+        from deeplearning4j_tpu.parallel.context import context_if_any
 
-        from deeplearning4j_tpu.parallel.context import parallel_context
-
-        return (parallel_context(self.context) if self.context is not None
-                else nullcontext())
+        return context_if_any(self.context)
 
     # -- prompt path ------------------------------------------------------
 
@@ -794,15 +791,20 @@ class PagedDecodeStepper(DecodeStepper):
     def install(self, slot: int, slot_state, length: int):
         """Allocate pages for a freshly-prefilled prompt and scatter its
         dense batch-1 cache into them. The tail page's rows beyond
-        `length` carry prefill-pad garbage — masked until overwritten."""
-        import numpy as np
+        `length` carry prefill-pad garbage — masked until overwritten.
+
+        The scatter always covers the slot's WHOLE table row: entries past
+        the prompt are 0, the sink page every free slot already writes to,
+        so one gather/scatter shape serves every prompt length. Scattering
+        only the allocated pages compiled ten eager programs per distinct
+        page count — after warm-up, under traffic."""
         import jax.numpy as jnp
 
         if self._state is None:
             self._alloc(slot_state)
-        pages = self.pool.install_slot(slot, length)
-        idx = jnp.asarray(np.asarray(pages, np.int32))
-        page, npg = self.page_size, len(pages)
+        self.pool.install_slot(slot, length)
+        idx = jnp.asarray(self.pool.table[slot])
+        page, npg = self.page_size, self.pool.pages_per_seq
         for layer, s in slot_state.items():
             dst = self._state[layer]
             if "k_cache" in s:

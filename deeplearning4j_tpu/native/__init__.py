@@ -5,7 +5,8 @@ DataVec's JavaCV-backed readers); the TPU build keeps XLA as the compute
 path and implements its IO hot paths in C++ too. Modules here are built
 with `g++` on first use (no pybind11 in the image — plain `extern "C"` +
 ctypes) and every caller has a pure-Python fallback, so the package works
-on machines without a toolchain.
+on machines without a toolchain — saying so once, by warning, when a
+build or load fails.
 
 Current components:
 - `fastcsv` — numeric CSV -> float32 matrix parser
@@ -27,6 +28,7 @@ import ctypes
 import os
 import subprocess
 import threading
+import warnings
 from typing import Optional
 
 import numpy as np
@@ -50,7 +52,14 @@ def _build_and_load(name: str, configure) -> Optional[ctypes.CDLL]:
         lib = ctypes.CDLL(so)
         configure(lib)
         return lib
-    except Exception:
+    except (OSError, subprocess.SubprocessError, AttributeError) as e:
+        detail = getattr(e, "stderr", None)
+        warnings.warn(
+            f"native component {name!r} could not be built or loaded "
+            f"({type(e).__name__}: {e}"
+            + (f"; g++ said: {detail.decode(errors='replace')[-400:]}"
+               if detail else "")
+            + "); its callers use their pure-Python path, which is slower")
         return None
 
 

@@ -72,7 +72,7 @@ class DtypePolicy:
     output_dtype: Optional[str] = None
     # Host->device staging cast for superbatch/device-cache tiers
     # (datasets/iterators.py): features/labels ship at this dtype, halving
-    # H2D bytes for f32 pipelines (the BENCH_r05 1.91x, now a config knob).
+    # H2D bytes for f32 pipelines.
     transfer_dtype: Optional[str] = None
     # Dynamic loss scaling (None = preset default for the name).
     dynamic_loss_scale: Optional[bool] = None

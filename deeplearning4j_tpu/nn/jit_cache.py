@@ -20,8 +20,8 @@ already-seen signature is a cache hit, so kernel resolution (and its
 StepProfiler's jit-cache-growth heuristic relies on that to classify a
 tail block's first call as compile).
 
-When the compile cache is enabled (`DL4J_TPU_COMPILE_CACHE`, on by
-default) each freshly built program is wrapped in a
+When the compile cache is enabled (on unless
+`DL4J_TPU_COMPILE_CACHE=off`) each freshly built program is wrapped in a
 `compilation.CachedProgram`, which consults the fingerprinted AOT
 executable store before the first trace and writes back on miss; when
 disabled, the raw jitted callable is cached — byte-for-byte the old

@@ -37,7 +37,7 @@ from typing import Any, Dict, Optional
 
 from deeplearning4j_tpu.observability import propagate
 from deeplearning4j_tpu.observability.metrics import (
-    DEFAULT_BUCKETS, WIDE_BUCKETS, MetricsRegistry,
+    DEFAULT_BUCKETS, WIDE_BUCKETS, MetricsRegistry, backend_is_up,
     install_builtin_collectors)
 from deeplearning4j_tpu.observability.tracing import NOOP_SPAN, Tracer
 from deeplearning4j_tpu.observability.profiler import (
@@ -82,10 +82,13 @@ tracer = Tracer(enabled=OBS_ENABLED)
 
 def install_build_info(registry: Optional[MetricsRegistry] = None) -> None:
     """Register the `dl4j_build_info{version,jax,backend,device_kind}`
-    info-gauge (constant 1). Labels resolve at scrape time — jax is never
-    imported just to report a version, and the series upgrades in place
-    once jax/the backend come up. Federated scrapes read this to spot
-    mixed-version fleets mid-rolling-update."""
+    info-gauge (constant 1). Labels resolve at scrape time and only from
+    what the process has ALREADY done: jax is never imported just to
+    report a version, and a backend is never initialised just to name it —
+    a router, coordinator or manager that answers `/metrics` would
+    otherwise take the chip from the replica that needs it. The series
+    upgrades in place once jax / the backend come up. Federated scrapes
+    read this to spot mixed-version fleets mid-rolling-update."""
     reg = registry or metrics
     fam = reg.gauge(
         "dl4j_build_info",
@@ -105,12 +108,10 @@ def install_build_info(registry: Optional[MetricsRegistry] = None) -> None:
                   "device_kind": "unknown"}
         jax = sys.modules.get("jax")  # never import jax just to report it
         if jax is not None:
-            try:
-                labels["jax"] = jax.__version__
+            labels["jax"] = jax.__version__
+            if backend_is_up():
                 labels["backend"] = jax.default_backend()
                 labels["device_kind"] = jax.devices()[0].device_kind
-            except Exception:
-                pass
         key = tuple(labels.values())
         if state.get("key") != key:
             prev = state.get("child")

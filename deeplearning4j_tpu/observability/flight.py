@@ -207,14 +207,14 @@ class FlightRecorder:
             return None
 
     def _live_buffer_bytes(self) -> Optional[int]:
-        jax = sys.modules.get("jax")  # never import jax just to sample
-        if jax is None:
+        from deeplearning4j_tpu.observability.metrics import backend_is_up
+
+        if not backend_is_up():  # never bring a backend up just to sample
             return None
-        try:
-            return sum(int(getattr(a, "nbytes", 0) or 0)
-                       for a in jax.live_arrays())
-        except Exception:
-            return None
+        import jax
+
+        return sum(int(getattr(a, "nbytes", 0) or 0)
+                   for a in jax.live_arrays())
 
     # ------------------------------------------------------------- triggers
 
@@ -394,11 +394,14 @@ class FlightRecorder:
             pass
         jax = sys.modules.get("jax")
         if jax is not None:
-            try:
-                versions["jax"] = jax.__version__
+            versions["jax"] = jax.__version__
+            # Only a backend this process already brought up: a bundle
+            # written by a router or manager must not take the chip.
+            from deeplearning4j_tpu.observability.metrics import (
+                backend_is_up)
+
+            if backend_is_up():
                 versions["devices"] = [str(d) for d in jax.devices()]
-            except Exception:
-                pass
         manifest["versions"] = versions
         manifest["env"] = {k: v for k, v in sorted(os.environ.items())
                            if k.startswith(("DL4J_TPU_", "JAX_", "XLA_"))}

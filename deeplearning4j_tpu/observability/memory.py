@@ -145,11 +145,11 @@ def live_buffer_report() -> Dict[str, Any]:
     """Attribute `jax.live_arrays()` bytes to registered model trees,
     grouped per model by param-leaf prefix. Buffers owned by nothing
     registered land in `unattributed_bytes`."""
-    import sys
+    from deeplearning4j_tpu.observability.metrics import backend_is_up
 
-    jax = sys.modules.get("jax")
-    if jax is None:  # never import jax just to report an empty process
+    if not backend_is_up():  # never bring a backend up just to report zero
         return {"total_bytes": 0, "models": {}, "unattributed_bytes": 0}
+    import jax
 
     owners: Dict[int, tuple] = {}
     with _lock:
@@ -164,11 +164,7 @@ def live_buffer_report() -> Dict[str, Any]:
 
     models: Dict[str, Dict[str, Any]] = {}
     total = unattributed = 0
-    try:
-        arrays = jax.live_arrays()
-    except Exception:
-        arrays = []
-    for a in arrays:
+    for a in jax.live_arrays():
         nb = int(getattr(a, "nbytes", 0) or 0)
         total += nb
         who = owners.get(id(a))

@@ -389,6 +389,20 @@ def _host_rss_bytes() -> Optional[float]:
         return None
 
 
+def backend_is_up() -> bool:
+    """Whether this process has already initialised a jax backend. Asking
+    jax for its devices, its default backend or its live arrays would
+    initialise one, and on a TPU host take the chip: a scrape must only
+    ever report a backend the process brought up for its own work."""
+    import sys
+
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized()
+
+
 def install_builtin_collectors(reg: MetricsRegistry) -> None:
     """Process RSS + JAX live device buffers, sampled at scrape time."""
     rss = reg.gauge("dl4j_process_resident_memory_bytes",
@@ -402,16 +416,12 @@ def install_builtin_collectors(reg: MetricsRegistry) -> None:
         v = _host_rss_bytes()
         if v is not None:
             rss.set(v)
-        try:
-            import sys
+        if not backend_is_up():  # no backend, no buffers: report nothing
+            return
+        import jax
 
-            jax = sys.modules.get("jax")
-            if jax is None:  # never import jax just to report zero
-                return
-            arrays = jax.live_arrays()
-            live.set(len(arrays))
-            live_bytes.set(sum(getattr(a, "nbytes", 0) for a in arrays))
-        except Exception:
-            pass
+        arrays = jax.live_arrays()
+        live.set(len(arrays))
+        live_bytes.set(sum(getattr(a, "nbytes", 0) for a in arrays))
 
     reg.register_collector(collect)

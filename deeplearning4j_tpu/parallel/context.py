@@ -20,7 +20,7 @@ programs.
 from __future__ import annotations
 
 import threading
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass
 from typing import Optional
 
@@ -70,6 +70,14 @@ _state = threading.local()
 
 def current_context() -> Optional[ParallelContext]:
     return getattr(_state, "ctx", None)
+
+
+def context_if_any(ctx: Optional[ParallelContext]):
+    """`parallel_context(ctx)` for a sharded owner, a no-op for an unsharded
+    one (`ctx` None) — which leaves an externally installed context alone.
+    What every dispatcher that may own a mesh wraps its traces in (decode
+    steppers, the serving batcher)."""
+    return parallel_context(ctx) if ctx is not None else nullcontext()
 
 
 @contextmanager

@@ -28,7 +28,7 @@ from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
-from deeplearning4j_tpu.parallel._shard_map_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

@@ -34,8 +34,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from deeplearning4j_tpu.parallel._shard_map_compat import shard_map
 
 _NEG = -1e30  # finite mask value: keeps exp() well-defined for masked rows
 

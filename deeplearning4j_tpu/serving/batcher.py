@@ -166,8 +166,15 @@ class ShapeBucketBatcher:
                  buckets: Optional[Sequence[int]] = None,
                  max_delay_s: float = 0.005,
                  queue_depth: int = 256,
-                 warmup_shape=None):
+                 warmup_shape=None, context=None):
         self.net = net
+        # Tensor-parallel serving: the ParallelContext the host sharded
+        # `net` under. Warm-up and every forward trace inside it, like the
+        # decode stepper's dispatches: the jit-cache key and the kernel
+        # registry then see the mesh the program is partitioned over
+        # (outside it the registry resolves Pallas bodies the TPU compiler
+        # refuses in a partitioned program).
+        self.context = context
         self.model_name = model_name
         self.buckets = bucket_ladder(max_batch_size, buckets)
         self.max_batch_size = self.buckets[-1]
@@ -247,17 +254,24 @@ class ShapeBucketBatcher:
         variants = (self.param_variants() if callable(self.param_variants)
                     else self.param_variants)
         if hasattr(self.net, "_get_jit"):
-            warmup_buckets(self.net, self.buckets, shape=shape, dtype=dtype,
-                           param_variants=variants)
+            with self._in_context():
+                warmup_buckets(self.net, self.buckets, shape=shape,
+                               dtype=dtype, param_variants=variants)
         else:
             x = np.zeros((self.max_batch_size,) + tuple(shape), dtype)
             np.asarray(self._forward(x))
 
     # ------------------------------------------------------------ batching
 
+    def _in_context(self):
+        from deeplearning4j_tpu.parallel.context import context_if_any
+
+        return context_if_any(self.context)
+
     def _forward(self, x: np.ndarray, params=None) -> np.ndarray:
-        out = (self.net.output(x, params=params) if params is not None
-               else self.net.output(x))
+        with self._in_context():
+            out = (self.net.output(x, params=params) if params is not None
+                   else self.net.output(x))
         if isinstance(out, list):  # ComputationGraph returns [out, ...]
             out = out[0]
         return np.asarray(out)

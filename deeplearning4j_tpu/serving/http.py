@@ -3,7 +3,8 @@
 Routes (all JSON):
 
 - `GET  /health`     liveness (+ hosted model names)
-- `GET  /healthz`    readiness: `{"status": "warming"|"ready", "models": …}`
+- `GET  /healthz`    readiness: `{"status": "warming"|"ready"|"failed",
+                     "models": …}` ("failed": the warm-up raised)
 - `GET  /metrics`    Prometheus scrape (`?format=json` for the snapshot)
 - `GET  /v1/models`  per-model status / residency / HBM estimate / loaded
                      LoRA adapters (name, rank, bytes, pinned)
@@ -150,7 +151,7 @@ def make_handler(server):
         def _check_ready(self, name: Optional[str]) -> Optional[dict]:
             """503 + Retry-After while the server (or the target model) is
             warming: never park a caller behind an XLA compile."""
-            if server._status != "ready":
+            if server._status == "warming":
                 return {"error": "warming up", "status": server._status}
             if name is not None:
                 model = server.models._models.get(name)

@@ -121,6 +121,7 @@ class InferenceServer:
         self._httpd: Optional[ThreadingHTTPServer] = None
         self._serve_thread: Optional[threading.Thread] = None
         self._warmup_thread: Optional[threading.Thread] = None
+        self._warmup_error: Optional[Exception] = None
         if net is not None:
             self.add_model(default_model, net=net)
 
@@ -252,7 +253,7 @@ class InferenceServer:
             model.net, model_name=model.name,
             max_batch_size=o["max_batch_size"], buckets=o["batch_buckets"],
             max_delay_s=o["max_delay_s"], queue_depth=o["queue_depth"],
-            warmup_shape=o["warmup_shape"]).start()
+            warmup_shape=o["warmup_shape"], context=model.context).start()
         # Multi-tenant hooks: warmup and per-request dispatch resolve
         # adapter-merged trees through the ServedModel registry (lazy, so
         # adapters loaded after _attach are picked up too).
@@ -343,19 +344,29 @@ class InferenceServer:
     @property
     def _status(self) -> str:
         # Derived from the Event (its own lock) so the warmup thread and
-        # the HTTP handlers never race on a plain attribute.
-        return "ready" if self._ready.is_set() else "warming"
+        # the HTTP handlers never race on a plain attribute; the error is
+        # written before the Event is set.
+        if not self._ready.is_set():
+            return "warming"
+        return "ready" if self._warmup_error is None else "failed"
 
     def wait_ready(self, timeout: Optional[float] = None) -> bool:
-        """Block until warmup finished (immediately True without warmup)."""
-        return self._ready.wait(timeout)
+        """Block until warmup finished (immediately True without warmup).
+        Raises when the warm-up itself failed: a server whose programs did
+        not compile has not become ready, whatever it still admits."""
+        done = self._ready.wait(timeout)
+        if done and self._warmup_error is not None:
+            raise RuntimeError(
+                "serving warmup failed") from self._warmup_error
+        return done
 
     def _warmup_run(self) -> None:
         """Drive every model's batch-bucket ladder (and, for LMs, every
         prompt bucket + the decode step) through the AOT store so no real
-        request triggers an XLA compile. Failures flip to "ready" anyway —
-        the first real request then pays the compile, exactly the
-        no-warmup behavior."""
+        request triggers an XLA compile. A failure is kept, not survived in
+        silence: `/healthz` answers "failed" and `wait_ready` raises it.
+        Requests are still admitted — the first one meets the same error,
+        or pays the compile, exactly the no-warmup behavior."""
         try:
             for name in self.models.names():
                 model = self.models.get(name)
@@ -367,9 +378,9 @@ class InferenceServer:
                 except Exception as e:
                     import warnings
 
+                    self._warmup_error = e
                     warnings.warn(
-                        f"serving warmup failed ({type(e).__name__}: {e}); "
-                        "the first request will pay the compile")
+                        f"serving warmup failed ({type(e).__name__}: {e})")
                 finally:
                     model.ready.set()
         finally:

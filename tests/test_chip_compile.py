@@ -1,0 +1,236 @@
+"""Described-chip compiles: the main paths' Pallas kernels, at real widths,
+through the TPU v5e's own compiler.
+
+Every other kernel test runs the bodies in Pallas interpret mode, which
+accepts what the chip's compiler refuses: a block that is the whole operand
+(VMEM), a bf16 `sqrt` (a v5e has no bf16 transcendental unit), a block whose
+trailing dims are neither whole nor (8, 128)-aligned. The TPU compiler is
+installed beside the CPU backend and compiles for a chip that is DESCRIBED,
+not attached (`jax.experimental.topologies`), so these tests run on the CPU
+sandbox at no chip time. They call the kernels' own `pallas_call` builders
+with `interpret=False` and hand them shapes placed on the described device.
+
+What they show: the compiler accepts the kernel at this shape. Nothing
+runs, so they say nothing about results or speed — `chip_smoke.py` does.
+
+Only one process at a time may load the TPU's library, so the topology is
+described inside a module-scoped fixture (never at import: under xdist every
+worker imports every test file), and all of these tests live in this one
+file so that one worker runs them.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deeplearning4j_tpu.kernels import bottleneck_block as bb
+from deeplearning4j_tpu.kernels import flash_attention as fa
+from deeplearning4j_tpu.kernels import fused_update as fu
+from deeplearning4j_tpu.kernels import lstm_cell as lc
+from deeplearning4j_tpu.kernels import norm_act as na
+
+RESNET50_PARAMS = 25_557_032   # resnet50(n_classes=1000) trainable f32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """`chip(shape, dtype)` -> a ShapeDtypeStruct on the described chip.
+    The persistent cache is off around these compiles: an entry written
+    for a described device cannot be read back without one, and the next
+    compile would warn about it. So is x64, which conftest turns on for
+    the gradient checks: the chip runs with 32-bit defaults, and Mosaic
+    has no 64-bit index arithmetic to lower a grid's index maps to."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    one = SingleDeviceSharding(topo.devices[0])
+    cache_was, x64_was = (jax.config.jax_enable_compilation_cache,
+                          jax.config.jax_enable_x64)
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_enable_x64", False)
+    compilation_cache.reset_cache()
+    yield lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one)
+    jax.config.update("jax_enable_x64", x64_was)
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    compilation_cache.reset_cache()
+
+
+def assert_kernel_compiles(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kind,hyper", [
+    ("nesterovs", (0.9,)), ("adam", (0.9, 0.999, 1e-8))])
+def test_fused_update_resnet50(chip, kind, hyper):
+    rows = -(-RESNET50_PARAMS // fu._TILE) * 8   # `_to_tiles`' padding
+    call = fu._flat_call(kind, rows, hyper, False)
+    n_tiled = 3 if kind == "adam" else 2
+    assert_kernel_compiles(
+        lambda *a: call(*a),
+        *([chip((rows, 128))] * n_tiled + [chip((3,))]))
+    ok, why = fu._pallas_available(
+        "tpu", ((RESNET50_PARAMS,),), ("float32",), meta=(("kind", kind),))
+    assert ok, why
+
+
+def test_norm_act_batchnorm_relu_bf16_resnet50(chip):
+    # ResNet-50 batch 128, stage 1 output: [128*56*56, 256].
+    rows, feats = 401408, 256
+    call = na._norm_call("batchnorm", rows, feats, 1e-5, "relu", "bfloat16",
+                         False)
+    vec = chip((1, feats), jnp.bfloat16)
+    assert_kernel_compiles(lambda *a: call(*a),
+                           chip((rows, feats), jnp.bfloat16), *[vec] * 4)
+    ok, why = na._pallas_available(
+        "tpu", (rows, feats), ("bfloat16",),
+        meta=(("op", "batchnorm"), ("act", "relu")))
+    assert ok, why
+
+
+def test_norm_act_layernorm_f32_transformer(chip):
+    # transformer_lm d_model=512 at batch 16 x T 1024.
+    rows, feats = 16384, 512
+    call = na._norm_call("layernorm", rows, feats, 1e-5, "identity",
+                         "float32", False)
+    vec = chip((1, feats))
+    assert_kernel_compiles(lambda *a: call(*a), chip((rows, feats)),
+                           vec, vec)
+
+
+def test_norm_act_refuses_features_the_compiler_refuses(chip):
+    """Past `_MAX_FEATS` the smallest block no longer fits VMEM: the probe
+    says so, with the reason, and the compiler agrees."""
+    rows, feats = 1024, 2 * na._MAX_FEATS
+    ok, why = na._pallas_available(
+        "tpu", (rows, feats), ("float32",),
+        meta=(("op", "layernorm"), ("act", "identity")))
+    assert not ok and "VMEM" in why
+    call = na._norm_call("layernorm", rows, feats, 1e-5, "identity",
+                         "float32", False)
+    vec = chip((1, feats))
+    with pytest.raises(Exception, match="vmem"):
+        jax.jit(lambda *a: call(*a)).lower(
+            chip((rows, feats)), vec, vec).compile()
+
+
+def test_lstm_cell_char_rnn(chip):
+    b, n = 32, 256
+    cell = lc.pallas_cell(b, n, False, False, "tanh", "float32", False)
+    assert_kernel_compiles(
+        lambda xw, h, c, rw: cell(xw, h, c, rw, None, None),
+        chip((b, 4 * n)), chip((b, n)), chip((b, n)), chip((n, 4 * n)))
+    ok, why = lc._pallas_available(
+        "tpu", (b, n), ("float32",),
+        meta=(("gate", "sigmoid"), ("act", "tanh")))
+    assert ok, why
+
+
+def test_bottleneck_block_strided_projecting_inference(chip):
+    """`fused_blocks` is opt-in and off the smoke's path, but where its
+    probe says yes on a TPU the compiler must too: ResNet-50's first
+    stage-2 block (stride 2, projected shortcut) — the stride is taken
+    outside the kernel, Mosaic has no strided value slice."""
+    b, h, w, cin, f1, f3, sh, sw = 8, 56, 56, 256, 128, 512, 2, 2
+    meta = (("train", False), ("project", True), ("act", "relu"),
+            ("int8", False))
+    ok, why = bb._pallas_available("tpu", (b, h, w, cin, f1, f3, sh, sw),
+                                   ("bfloat16",), meta=meta)
+    assert ok, why
+    ho, wo = h // sh, w // sw
+    call = bb._infer_call(b, ho, wo, cin, f1, f3, 1e-5, "relu", True, False,
+                          "bfloat16", False)
+    args = [chip((b, ho, wo, cin), jnp.bfloat16)]
+    for wshape, fo in (((cin, f1), f1), ((3, 3, f1, f1), f1),
+                       ((f1, f3), f3), ((cin, f3), f3)):
+        args += [chip(wshape, jnp.bfloat16)] + [chip((1, fo))] * 4
+    assert_kernel_compiles(lambda *a: call(*a), *args)
+    # The stage's first block at f32 needs more VMEM than the budget: no.
+    ok, why = bb._pallas_available("tpu", (8, 56, 56, 256, 64, 256, 1, 1),
+                                   ("float32",), meta=meta)
+    assert not ok and "VMEM" in why
+
+
+def test_paged_flash_smoke_decode_shape(chip):
+    # chip_smoke.py's serve phase: 8 slots, one new token, 8 heads of 64,
+    # 64-token pages, 1024-token capacity (16 pages a sequence).
+    B, T, H, D, page, NP = 8, 1, 8, 64, 64, 16
+    P = B * NP + 1
+    assert_kernel_compiles(
+        lambda q, k, v, pt, pos: fa._paged_flash(q, k, v, pt, pos,
+                                                 causal=True,
+                                                 interpret=False),
+        chip((B, T, H, D)), chip((P, page, H, D)), chip((P, page, H, D)),
+        chip((B, NP), jnp.int32), chip((B,), jnp.int32))
+    ok, why = fa._paged_pallas_available(
+        "tpu", (B, T, H, D, P, page, NP), ("float32",),
+        meta=(("causal", True),))
+    assert ok, why
+
+
+def test_paged_flash_refuses_oversized_blocks():
+    """No topology needed: this is the registry's own answer."""
+    ok, why = fa._paged_pallas_available(
+        "tpu", (8, 1, 32, 128, 65, 256, 8), ("float32",),
+        meta=(("causal", True),))
+    assert not ok and "VMEM" in why
+
+
+def test_no_pallas_body_in_a_partitioned_program(topo, chip):
+    """Under a mesh of several devices the TPU compiler refuses any Pallas
+    body ("Mosaic kernels cannot be automatically partitioned"), so the
+    registry answers no for every kernel there — and says why."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from deeplearning4j_tpu.kernels import registry
+    from deeplearning4j_tpu.parallel.context import (ParallelContext,
+                                                     parallel_context)
+
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+    rows, feats = 1024, 512
+    call = na._norm_call("layernorm", rows, feats, 1e-5, "identity",
+                         "float32", False)
+    x = jax.ShapeDtypeStruct((rows, feats), jnp.float32,
+                             sharding=NamedSharding(mesh, P(None, "model")))
+    vec = jax.ShapeDtypeStruct((1, feats), jnp.float32,
+                               sharding=NamedSharding(mesh, P()))
+    with pytest.raises(Exception, match="automatically partitioned"):
+        jax.jit(lambda *a: call(*a)).lower(x, vec, vec).compile()
+
+    with parallel_context(ParallelContext(mesh, model_axis="model")):
+        for kernel in registry.kernel_names():
+            res = registry.resolve(kernel, backend="tpu")
+            assert res.impl == "xla", res
+            assert "partitioned over a 4-device mesh" in res.reason, res
+    assert registry.resolve("norm_act", backend="tpu").impl == "pallas"
+
+
+@pytest.mark.parametrize("B,T", [(16, 1024), (2, 8192)],
+                         ids=["resident-T1024", "stream-bwd-T8192"])
+def test_flash_attention_forward_backward(chip, B, T):
+    # T=8192: the forward stays resident, the backward streams (the
+    # resident dk/dv kernel's [T, 1] columns no longer fit VMEM there).
+    H, D = 8, 64
+    flash = functools.partial(fa._flash_attention_pallas, causal=True,
+                              scale=None, block_q=256, block_k=256,
+                              interpret=False)
+
+    def loss(q, k, v):
+        return jnp.sum(flash(q, k, v).astype(jnp.float32))
+
+    x = chip((B, T, H, D), jnp.bfloat16)
+    assert_kernel_compiles(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)

@@ -23,6 +23,7 @@ import pytest
 from deeplearning4j_tpu import (MultiLayerNetwork, NeuralNetConfiguration,
                                 compilation)
 from deeplearning4j_tpu import observability as obs
+from deeplearning4j_tpu.compilation import cache as cache_mod
 from deeplearning4j_tpu.compilation import store as store_mod
 from deeplearning4j_tpu.compilation import warmup as warmup_mod
 from deeplearning4j_tpu.datasets.dataset import DataSet
@@ -52,11 +53,19 @@ def small_dataset(n=16, n_in=4, n_out=3, seed=0):
 @pytest.fixture
 def cache_dir(tmp_path, monkeypatch):
     """Fresh per-test cache root (the session default from conftest stays
-    untouched); resets the store singleton on both sides."""
+    untouched); resets the store singleton on both sides. The program
+    leaves `jax_compilation_cache_dir` alone when the environment names
+    the directory (jax read it at import), so re-pointing jax's side
+    mid-process is the test's job."""
+    import jax
+
     d = str(tmp_path / "compile-cache")
-    monkeypatch.setenv(compilation.ENV_KNOB, d)
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(cache_mod.JAX_ENV_DIR, d)
+    jax.config.update("jax_compilation_cache_dir", d)
     compilation.reset()
     yield d
+    jax.config.update("jax_compilation_cache_dir", before)
     compilation.reset()
 
 
@@ -186,6 +195,188 @@ class TestAOTStoreFallback:
             compilation.reset()
 
 
+class TestCompileErrorsPropagate:
+    def test_cached_program_does_not_fall_back_to_plain_jit(self, cache_dir):
+        """Only the store may degrade to a miss, never the compile: the
+        plain jit path would compile the same program again, and fail
+        again at the first request behind a server that called itself
+        warm."""
+        class _Refuses:
+            calls = 0
+
+            def lower(self, *args):
+                raise RuntimeError("RESOURCE_EXHAUSTED: vmem")
+
+            def __call__(self, *args):
+                _Refuses.calls += 1
+
+        net = MultiLayerNetwork(mlp_conf())
+        program = compilation.CachedProgram(_Refuses(), net, "output", {})
+        x = np.zeros((2, 4), np.float32)
+        with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+            program.warm(x)
+        with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+            program(x)
+        assert _Refuses.calls == 0 and program.executables() == []
+
+
+class TestStoredProgramDevices:
+    """JAX 0.9 loads a deserialized executable for EVERY device of the
+    backend unless told otherwise; on a several-device host a stored
+    one-device program then died at its first call ("expected 8 shards").
+    The store records each artifact's device ids and loads for those."""
+
+    def _round_trip(self, cache_dir, fn, *args):
+        import jax
+        import jaxlib
+
+        compiled = jax.jit(fn).lower(*args).compile()
+        store = store_mod.AOTStore(cache_dir)
+        assert store.save("f" * 64, compiled, {"jax": jax.__version__,
+                                               "jaxlib": jaxlib.__version__})
+        loaded = store.load("f" * 64)
+        assert loaded is not None
+        return compiled, loaded
+
+    def test_one_device_program_on_a_several_device_host(self, cache_dir):
+        import jax
+        import jax.numpy as jnp
+
+        assert len(jax.devices()) >= 8
+        dev = jax.devices()[3]
+        x = jax.device_put(jnp.arange(12.0).reshape(3, 4), dev)
+        compiled, loaded = self._round_trip(
+            cache_dir, lambda a: jnp.tanh(a) @ a.T, x)
+        out = loaded(x)
+        np.testing.assert_array_equal(np.asarray(out),
+                                      np.asarray(compiled(x)))
+        assert out.sharding.device_set == {dev}
+
+    def test_two_device_mesh_program(self, cache_dir):
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        devs = [jax.devices()[5], jax.devices()[2]]  # order is the assignment
+        sharding = NamedSharding(Mesh(np.array(devs), ("m",)), P("m"))
+        x = jax.device_put(jnp.arange(32.0).reshape(8, 4), sharding)
+        compiled, loaded = self._round_trip(
+            cache_dir, lambda a: (a * 2.0).sum(axis=1), x)
+        out = loaded(x)
+        np.testing.assert_array_equal(np.asarray(out),
+                                      np.asarray(compiled(x)))
+        assert out.sharding.device_set == set(devs)
+
+    def test_missing_device_is_a_miss_at_load_time(self, cache_dir):
+        import pickle
+
+        import jax
+        import jax.numpy as jnp
+
+        x = jnp.ones((4, 4))
+        self._round_trip(cache_dir, lambda a: a + 1.0, x)
+        path = os.path.join(cache_dir, "aot", "f" * 64 + ".jaxec")
+        with open(path, "rb") as f:
+            blob = pickle.load(f)
+        blob["device_ids"] = [len(jax.devices()) + 7]
+        with open(path, "wb") as f:
+            pickle.dump(blob, f)
+        with pytest.warns(UserWarning, match="has no device"):
+            assert store_mod.AOTStore(cache_dir).load("f" * 64) is None
+
+
+# ------------------------------------------------------------- placement
+
+_PLACEMENT_CHILD = r"""
+import json, os
+import jax
+as_jax_set_it = jax.config.jax_compilation_cache_dir
+import numpy as np
+from deeplearning4j_tpu import (MultiLayerNetwork, NeuralNetConfiguration,
+                                compilation)
+from deeplearning4j_tpu.datasets.dataset import DataSet
+from deeplearning4j_tpu.nn.conf.inputs import InputType
+from deeplearning4j_tpu.nn.conf.layers import DenseLayer, OutputLayer
+
+written = []
+if os.environ.get("CHILD_COMPILES"):
+    conf = (NeuralNetConfiguration.builder().seed(1).learning_rate(0.1)
+            .updater("sgd").list()
+            .layer(DenseLayer(n_out=4, activation="relu"))
+            .layer(OutputLayer(n_out=2, activation="softmax",
+                               loss_function="mcxent"))
+            .set_input_type(InputType.feed_forward(3)).build())
+    net = MultiLayerNetwork(conf).init()
+    net.warmup(DataSet(np.zeros((2, 3), "float32"),
+                       np.zeros((2, 2), "float32")), kinds=["output"])
+    root = compilation.cache_root()
+    written = sorted(os.path.relpath(os.path.join(d, f), root)
+                     for d, _, fs in os.walk(root) for f in fs)
+store = compilation.get_store()
+print(json.dumps({
+    "as_jax_set_it": as_jax_set_it,
+    "jax_dir": jax.config.jax_compilation_cache_dir,
+    "root": compilation.cache_root(),
+    "aot_dir": None if store is None else store.root,
+    "written": written,
+}))
+"""
+
+
+def _placement_child(env_dir, compiles):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env.pop("CHILD_COMPILES", None)
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    if compiles:
+        env["CHILD_COMPILES"] = "1"
+    proc = subprocess.run([sys.executable, "-c", _PLACEMENT_CHILD],
+                          capture_output=True, text=True, env=env,
+                          timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+class TestCachePlacement:
+    """Where the cache lives is decided from outside the program."""
+
+    def test_environment_places_both_layers(self, tmp_path):
+        placed = str(tmp_path / "placed")
+        got = _placement_child(placed, compiles=True)
+        # The program did not touch what jax read from the environment.
+        assert got["as_jax_set_it"] == placed
+        assert got["jax_dir"] == placed
+        assert got["root"] == placed
+        assert got["aot_dir"] == os.path.join(placed, "aot")
+        assert any(w.startswith("aot" + os.sep) and w.endswith(".jaxec")
+                   for w in got["written"])
+        assert any(not w.startswith("aot" + os.sep)
+                   for w in got["written"]), "no jax cache entry written"
+
+    def test_unset_means_the_fixed_checkout_path(self):
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        fixed = os.path.join(repo, ".dl4j_compile_cache")
+        # No compile here: the checkout's cache stays as the user left it.
+        first = _placement_child(None, compiles=False)
+        assert first["as_jax_set_it"] is None
+        assert first["root"] == fixed == compilation.checkout_cache_dir()
+        assert first["jax_dir"] == os.path.join(fixed, "xla")
+        assert first["aot_dir"] == os.path.join(fixed, "aot")
+        # Fixed means fixed: no pid, time or temporary name in either path
+        # (the path is part of jax's cache key; one that moves never hits).
+        assert _placement_child(None, compiles=False) == first
+
+    def test_the_old_knob_only_switches_off(self, monkeypatch, tmp_path):
+        monkeypatch.setenv(compilation.ENV_KNOB, str(tmp_path))
+        with pytest.raises(ValueError, match="JAX_COMPILATION_CACHE_DIR"):
+            compilation.cache_root()
+        for off in ("off", "0", "false", ""):
+            monkeypatch.setenv(compilation.ENV_KNOB, off)
+            assert compilation.cache_root() is None
+
+
 # ---------------------------------------------------------------- warmup
 
 
@@ -275,7 +466,7 @@ print(json.dumps({
 
 def _run_child(cache_dir, mode):
     env = dict(os.environ, JAX_PLATFORMS="cpu", CHILD_MODE=mode)
-    env["DL4J_TPU_COMPILE_CACHE"] = cache_dir
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     env.pop("XLA_FLAGS", None)  # plain 1-device CPU child
     proc = subprocess.run([sys.executable, "-c", _CHILD_SCRIPT],
                           capture_output=True, text=True, env=env,
@@ -307,12 +498,12 @@ class TestWarmupCLI:
         ckpt = str(tmp_path / "ckpt")
         save_checkpoint(net, ckpt)
         cache = str(tmp_path / "cli-cache")
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   JAX_COMPILATION_CACHE_DIR=cache)
         env.pop("XLA_FLAGS", None)
-        env.pop("DL4J_TPU_COMPILE_CACHE", None)
         proc = subprocess.run(
             [sys.executable, "-m", "deeplearning4j_tpu.compilation.warmup",
-             ckpt, "--batch-size", "4", "--cache-dir", cache],
+             ckpt, "--batch-size", "4"],
             capture_output=True, text=True, env=env, timeout=300)
         assert proc.returncode == 0, proc.stderr[-2000:]
         summary = json.loads(proc.stdout.strip().splitlines()[-1])
@@ -377,6 +568,26 @@ class TestServingWarmup:
             assert len(preds) == 1
         finally:
             net.release.set()
+            server.stop()
+
+    def test_failed_warmup_is_reported_not_survived_in_silence(self):
+        """A warm-up that raised (on the chip: a kernel the compiler
+        refuses) used to flip the server to "ready" all the same."""
+        from deeplearning4j_tpu.serving import InferenceServer
+
+        class _Refused:
+            def output(self, x):
+                raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+        server = InferenceServer(_Refused(), max_batch_size=2, warmup=True,
+                                 warmup_shape=(3,))
+        with pytest.warns(UserWarning, match="serving warmup failed"):
+            server.start()
+            with pytest.raises(RuntimeError, match="serving warmup failed"):
+                server.wait_ready(timeout=30)
+        try:
+            assert _get_json(server.url + "/healthz")["status"] == "failed"
+        finally:
             server.stop()
 
     def test_warmed_first_request_latency_near_steady_state(self, cache_dir):

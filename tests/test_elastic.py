@@ -665,6 +665,7 @@ def _spawn_elastic_workers(tmp_path, plan, steps, save_every):
                       .replace("__STEPS__", str(steps)))
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     env["JAX_PLATFORMS"] = "cpu"
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "compile-cache")
     env["DL4J_TPU_FAULT_PLAN"] = json.dumps(plan)
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
@@ -678,7 +679,11 @@ def _spawn_elastic_workers(tmp_path, plan, steps, save_every):
     outputs = []
     try:
         for p in procs:
-            outputs.append(p.communicate(timeout=300)[0])
+            # XLA:CPU logs a kilobyte-long line per loaded cache entry;
+            # they would push the traceback out of the asserts' tails.
+            outputs.append("\n".join(
+                line for line in p.communicate(timeout=300)[0].splitlines()
+                if "cpu_aot_loader" not in line))
     finally:
         for p in procs:
             if p.poll() is None:
@@ -739,7 +744,8 @@ def test_preemption_forensics_two_process_then_resume(tmp_path):
         tmp_path, plan=[{"kind": "preempt", "step": 3}],
         steps=steps, save_every=0)
     for p, text in zip(procs, outputs):
-        assert p.returncode == 0, f"worker failed:\n{text[-3000:]}"
+        assert p.returncode == 0, (
+            f"worker {p.args[2]} failed:\n{text[-3000:]}")
     for wid in ("a", "b"):
         got = _load_out(tmp_path, wid)
         assert got["status"] == "preempted", got

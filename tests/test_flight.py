@@ -70,8 +70,8 @@ y = np.eye(3, dtype="float32")[rng.randint(0, 3, 8)]
 def _child_env(tmp_path, **extra):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                DL4J_TPU_FLIGHT="1",
-               DL4J_TPU_FLIGHT_DIR=str(tmp_path / "flight"))
-    env.setdefault("DL4J_TPU_COMPILE_CACHE", str(tmp_path / "cache"))
+               DL4J_TPU_FLIGHT_DIR=str(tmp_path / "flight"),
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
     env.update(extra)
     return env
 

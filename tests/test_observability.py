@@ -836,3 +836,65 @@ class TestUIServerObsRoutes:
             assert "/metrics" in nf["routes"]  # 404s advertise the index
         finally:
             server.stop()
+
+
+# ------------------------------------------------------- device identity
+
+
+class TestChipPeaks:
+    def test_unknown_device_raises_and_no_environment_override(
+            self, monkeypatch):
+        from deeplearning4j_tpu.observability import (chip_peak_flops,
+                                                      chip_peak_hbm_bw)
+        from deeplearning4j_tpu.observability.profiler import (
+            CHIP_PEAKS, UnknownDeviceError)
+
+        for name in ("DL4J_TPU_PEAK_FLOPS", "BENCH_PEAK_FLOPS",
+                     "DL4J_TPU_PEAK_HBM_BW", "BENCH_PEAK_HBM_BW"):
+            monkeypatch.setenv(name, "1e12")
+        # The CPU backend's device_kind ("cpu") is not a chip in the table.
+        with pytest.raises(UnknownDeviceError, match="'cpu'"):
+            chip_peak_flops()
+        with pytest.raises(UnknownDeviceError, match="'cpu'"):
+            chip_peak_hbm_bw()
+        flops, bw, source = CHIP_PEAKS["TPU v5 lite"]
+        assert (flops, bw) == (197e12, 819e9) and "TPU v5e" in source
+        # Exact keys: nothing unlisted inherits a peak by containing "v5".
+        assert "TPU v5 experimental" not in CHIP_PEAKS
+
+
+_SCRAPE_CHILD = r"""
+import json, sys
+from deeplearning4j_tpu import observability as obs
+from jax._src import xla_bridge
+first = obs.metrics.to_prometheus()
+untouched = not xla_bridge.backends_are_initialized()
+import jax
+jax.numpy.zeros(1).block_until_ready()
+second = obs.metrics.to_prometheus()
+pick = lambda text: [l for l in text.splitlines()
+                     if l.startswith("dl4j_build_info{") and l.endswith(" 1")]
+print(json.dumps({"untouched": untouched, "first": pick(first),
+                  "second": pick(second)}))
+"""
+
+
+def test_build_info_scrape_does_not_initialise_a_backend():
+    """A router, coordinator or manager that answers /metrics must not take
+    the chip: the info gauge names the backend only once the process has
+    initialised one itself."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run([sys.executable, "-c", _SCRAPE_CHILD],
+                          capture_output=True, text=True, env=env,
+                          timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    got = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert got["untouched"], "serving the scrape initialised a backend"
+    assert len(got["first"]) == 1 and 'backend="unknown"' in got["first"][0]
+    assert len(got["second"]) == 1 and 'backend="cpu"' in got["second"][0]

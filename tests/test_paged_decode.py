@@ -381,6 +381,37 @@ class TestSpeculativeDecoding:
             GenerationScheduler(lm, kv="nope")
 
 
+class TestInstallIsOneShape:
+    def test_no_compile_for_a_new_page_count_after_warmup(self):
+        """`install` used to scatter only the pages a prompt needs: each
+        distinct page count compiled ten eager gather/scatter programs —
+        under traffic, after a warm-up that had installed one page (seen
+        first on the chip, where real prompts span several pages)."""
+        from deeplearning4j_tpu import observability as obs
+
+        obs.install_jax_compile_hook(obs.metrics)
+
+        def compiles():
+            fam = obs.metrics.get_family("dl4j_xla_compiles_total")
+            return sum(c.get() for c in fam.children()) if fam else 0.0
+
+        lm = _lm()
+        sched = GenerationScheduler(lm, kv="paged", page_size=PAGE, slots=2,
+                                    prompt_buckets=(8, 16, 32)).start()
+        try:
+            sched.warmup()  # installs a 1-token prompt: one page
+            before = compiles()
+            prompts = [[1 + (i % (V - 1)) for i in range(n)]
+                       for n in (3, 9, 20, 27)]  # 1, 2, 3, 4 pages of 8
+            served = [sched.generate(p, 2, temperature=0.0, timeout_s=60)
+                      for p in prompts]
+            assert compiles() == before
+        finally:
+            sched.stop()
+        for p, got in zip(prompts, served):  # the references compile theirs
+            assert list(got) == list(_ref(lm, p, 2, temperature=0.0))
+
+
 # ---------------------------------------------------------------- metrics
 
 
@@ -416,6 +447,45 @@ class TestPagedMetricsScrape:
 
 
 class TestShardedServing:
+    def test_sharded_programs_are_acquired_inside_the_context(
+            self, monkeypatch):
+        """Every program a model-parallel server builds — the batcher's
+        /predict buckets as well as the decode stepper's — is traced
+        inside the model's ParallelContext. Outside it the kernel registry
+        cannot see the mesh, and on a TPU resolves Pallas bodies the
+        compiler refuses in a partitioned program (found on four chips:
+        the batcher's warm-up ran outside the context)."""
+        import jax
+
+        from deeplearning4j_tpu.compilation import program
+        from deeplearning4j_tpu.parallel.context import current_context
+
+        outside = []
+        acquire = program.CachedProgram._acquire
+
+        def spy(self, args):
+            ctx = current_context()
+            if ctx is None or ctx.mesh.devices.size == 1:
+                if any(len(getattr(leaf, "sharding").device_set) > 1
+                       for leaf in jax.tree_util.tree_leaves(args)
+                       if hasattr(leaf, "sharding")):
+                    outside.append((self.kind, dict(self.static)))
+            return acquire(self, args)
+
+        monkeypatch.setattr(program.CachedProgram, "_acquire", spy)
+        server = InferenceServer(_lm(), port=0, kv_cache="paged",
+                                 kv_page_size=PAGE, decode_slots=2,
+                                 max_batch_size=2, warmup=True,
+                                 model_parallel=4).start()
+        try:
+            assert server.wait_ready(timeout=120)
+            out = server.predict(np.ones((1, 16), np.int32))
+            assert out.shape == (1, 16, V)
+            server.generate([1, 2, 3], 3, temperature=0.0)
+        finally:
+            server.stop()
+        assert outside == []
+
     def test_model_parallel_serving_matches_unsharded(self):
         """PR 20 end to end at the server tier: a 4-way tensor-parallel
         paged LM serves the same greedy completion as an unsharded one,

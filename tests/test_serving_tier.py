@@ -382,7 +382,7 @@ print(json.dumps({"xla_compiles": total,
 
 def _run_child(cache_dir, mode):
     env = dict(os.environ, JAX_PLATFORMS="cpu", CHILD_MODE=mode)
-    env["DL4J_TPU_COMPILE_CACHE"] = cache_dir
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     env.pop("XLA_FLAGS", None)  # plain 1-device CPU child
     proc = subprocess.run([sys.executable, "-c", _CHILD_SCRIPT],
                           capture_output=True, text=True, env=env,
