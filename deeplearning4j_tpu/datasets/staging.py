@@ -491,7 +491,8 @@ class DeviceStager:
                     if not self._admit(nb):
                         return
                     try:
-                        staged = self._stage_fn(item)
+                        with _obs.tracer.span("staging.put", cat="input"):
+                            staged = self._stage_fn(item)
                     except BaseException:
                         self._retire(nb)
                         raise

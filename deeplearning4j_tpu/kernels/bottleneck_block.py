@@ -306,7 +306,8 @@ def _train_call(b, ho, wo, cin, f1, f3, eps, act, project, xdtype,
     outs = [jax.ShapeDtypeStruct((b, ho, wo, f3), jnp.dtype(xdtype))]
     outs += [jax.ShapeDtypeStruct((1, d), jnp.float32) for d in stat_dims]
     body = functools.partial(_train_body, eps, act, project)
-    return pl.pallas_call(body, out_shape=outs, interpret=interpret)
+    return pl.pallas_call(body, out_shape=outs, interpret=interpret,
+                          name="bottleneck_block_train")
 
 
 @functools.lru_cache(maxsize=32)
@@ -336,7 +337,7 @@ def _infer_call(b, ho, wo, cin, f1, f3, eps, act, project, int8,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, ho, wo, f3), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, ho, wo, f3), jnp.dtype(xdtype)),
-        interpret=interpret)
+        interpret=interpret, name="bottleneck_block_infer")
 
 
 # ----------------------------------------------------- dispatch seam

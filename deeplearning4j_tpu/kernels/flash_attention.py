@@ -247,6 +247,7 @@ def _flash_fwd_stream_bhtd(q, k, v, causal, scale, block_q, block_k,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct((BH, T, 1), jnp.float32)],
         interpret=interpret,
+        name="flash_fwd_stream",
     )(jnp.asarray(i_idx), jnp.asarray(j_idx), q, k, v)
 
 
@@ -268,6 +269,7 @@ def _flash_fwd_bhtd(q, k, v, causal, scale, block_q, block_k, interpret):
             ],
             out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
             interpret=interpret,
+            name="flash_fwd_resident",
         )(q, k, v)
     o, _ = _flash_fwd_stream_bhtd(q, k, v, causal, scale, block_q, block_k,
                                   interpret)
@@ -517,6 +519,7 @@ def _flash_fwd_lse_bhtd(q, k, v, causal, scale, block_q, block_k,
         out_specs=[pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
                    pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0))],
         interpret=interpret,
+        name="flash_fwd_lse",
     )(q, k, v)
 
 
@@ -543,6 +546,7 @@ def _flash_bwd_bhtd(q, k, v, do, o, lse, causal, scale, block_q, block_k,
         ],
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, d_row)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
@@ -561,6 +565,7 @@ def _flash_bwd_bhtd(q, k, v, do, o, lse, causal, scale, block_q, block_k,
         out_specs=[pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
                    pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0))],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(k, v, q, do, lse, d_row)
     return dq, dk, dv
 
@@ -697,6 +702,7 @@ def _flash_bwd_stream_bhtd(q, k, v, do, o, lse, causal, scale, block_q,
         grid_spec=dq_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
         interpret=interpret,
+        name="flash_bwd_dq_stream",
     )(jnp.asarray(ir), jnp.asarray(jr), q, k, v, do, lse, d_row)
 
     ic, jc = _pair_arrays(nq, nk, block_q, block_k, causal, "col")
@@ -725,6 +731,7 @@ def _flash_bwd_stream_bhtd(q, k, v, do, o, lse, causal, scale, block_q,
         out_shape=[jax.ShapeDtypeStruct(k.shape, jnp.float32),
                    jax.ShapeDtypeStruct(v.shape, jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkv_stream",
     )(jnp.asarray(ic), jnp.asarray(jc), k, v, q, do, lse, d_row)
     return dq, dk, dv
 
@@ -847,6 +854,7 @@ def _paged_flash(q, k_pages, v_pages, page_table, pos, causal, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, T, H * D), q.dtype),
         interpret=interpret,
+        name="paged_flash",
     )(page_table, jnp.reshape(pos, (-1,)).astype(jnp.int32),
       q.reshape(B, T, H * D), k_pages.reshape(P, page, H * D),
       v_pages.reshape(P, page, H * D))

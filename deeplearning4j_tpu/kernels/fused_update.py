@@ -158,7 +158,8 @@ def _flat_call(kind: str, rows: int, hyper: tuple, interpret: bool):
     return pl.pallas_call(
         body, out_shape=(out,) * n_out, grid=(pl.cdiv(rows, block),),
         in_specs=[tile] * n_out + [pl.BlockSpec(memory_space=pltpu.SMEM)],
-        out_specs=(tile,) * n_out, interpret=interpret)
+        out_specs=(tile,) * n_out, interpret=interpret,
+        name=f"fused_update_{kind}")
 
 
 def _to_tiles(vec):

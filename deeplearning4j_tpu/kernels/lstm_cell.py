@@ -176,6 +176,7 @@ def _pallas_call(batch: int, n_out: int, peephole: bool, masked: bool,
         lambda *refs: _cell_kernel(n_out, peephole, masked, act_name, refs),
         out_shape=(out, out, out),
         interpret=interpret,
+        name="lstm_cell",
     )
 
 

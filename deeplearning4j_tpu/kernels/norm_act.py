@@ -153,7 +153,7 @@ def _norm_call(op: str, rows: int, feats: int, eps: float, act_name: str,
     return pl.pallas_call(
         body, out_shape=jax.ShapeDtypeStruct((rows, feats), jnp.dtype(dtype)),
         grid=(pl.cdiv(rows, block),), in_specs=[tile] + [vec] * n_vec,
-        out_specs=tile, interpret=interpret)
+        out_specs=tile, interpret=interpret, name=f"norm_act_{op}")
 
 
 def _row_view(a):

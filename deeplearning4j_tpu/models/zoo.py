@@ -548,6 +548,7 @@ class DecodeStepper:
         `(probs [V], slot_state, length)`."""
         import numpy as np
         import jax.numpy as jnp
+        from deeplearning4j_tpu import observability as _obs
         from deeplearning4j_tpu.nn import rnn_state as rnn_mod
 
         ids = [int(i) for i in ids]
@@ -565,14 +566,17 @@ class DecodeStepper:
         x[0, :n, 0] = ids
         with self._in_context():
             fn = self.cg._get_jit("output", train=False, keep_rnn_state=True)
-            outs, new_state = fn(self._params(), self.cg.state,
-                                 [jnp.asarray(x)], None, self._rng0)
+            args = (self._params(), self.cg.state, [jnp.asarray(x)], None,
+                    self._rng0)
+            with _obs.tracer.span("serving.enqueue", cat="serving"):
+                outs, new_state = fn(*args)
         rnn = rnn_mod.split_rnn_state(new_state, self._declared)
         # Rewind every cursor from pad_to to the real length.
         rnn = {layer: {k: (jnp.int32(n) if jnp.ndim(v) == 0 else v)
                        for k, v in s.items()}
                for layer, s in rnn.items()}
-        probs = np.asarray(outs[0])[0, n - 1]
+        with _obs.tracer.span("serving.fetch", cat="serving"):
+            probs = np.asarray(outs[0])[0, n - 1]
         return probs, rnn, n
 
     # -- slot management --------------------------------------------------
@@ -630,6 +634,7 @@ class DecodeStepper:
         Returns [slots, T, V] distributions (one per fed token)."""
         import numpy as np
         import jax.numpy as jnp
+        from deeplearning4j_tpu import observability as _obs
         from deeplearning4j_tpu.nn import rnn_state as rnn_mod
 
         if self._state is None:
@@ -637,10 +642,15 @@ class DecodeStepper:
         with self._in_context():
             fn = self.cg._get_jit("output", train=False, keep_rnn_state=True)
             state = rnn_mod.merge_rnn_state(self.cg.state, self._state)
-            outs, new_state = fn(self._params(), state,
-                                 [jnp.asarray(x)], None, self._rng0)
+            args = (self._params(), state, [jnp.asarray(x)], None,
+                    self._rng0)
+            with _obs.tracer.span("serving.enqueue", cat="serving"):
+                outs, new_state = fn(*args)
         self._state = rnn_mod.split_rnn_state(new_state, self._declared)
-        out = np.asarray(outs[0])
+        # The wait for the device and the copy of the distributions to the
+        # host, as one: `np.asarray` does not tell them apart.
+        with _obs.tracer.span("serving.fetch", cat="serving"):
+            out = np.asarray(outs[0])
         return out if out.ndim == 3 else out[:, None, :]
 
     def step(self, tokens):

@@ -1,0 +1,115 @@
+"""The fit loop's instruments, shared by both engines.
+
+`MultiLayerNetwork` ("mln") and `ComputationGraph` ("graph") run the same
+loop: wait for a batch, dispatch it, enqueue the compiled step. `FitObs`
+holds one engine's hot-loop metric series (resolved once at import,
+observability/metrics.py rule 2) and opens the spans of that loop:
+
+    <e>.fit          one epoch                       (the engine's `fit`)
+    <e>.input_wait   `next()` of the batch source    (`batches`)
+    <e>.iteration    one `_fit_dispatch`             (`dispatch`)
+    <e>.enqueue      the call of the compiled step   (`enqueue`)
+
+`<e>.iteration` minus `<e>.enqueue` is the engine's own host time per
+step. The names are read by `benchmark/harness/host_spans.py`.
+"""
+
+from __future__ import annotations
+
+import time
+
+from deeplearning4j_tpu import observability as _obs
+
+
+class FitObs:
+    """One engine's metric series and spans (see module docstring)."""
+
+    def __init__(self, engine: str):
+        m = _obs.metrics
+        self.engine = engine
+        self.iters = m.counter(
+            "dl4j_train_iterations_total", "Completed training iterations",
+            label_names=("engine",)).labels(engine=engine)
+        self.epochs = m.counter(
+            "dl4j_train_epochs_total", "Completed fit() epochs",
+            label_names=("engine",)).labels(engine=engine)
+        self._dispatch_family = m.histogram(
+            "dl4j_step_dispatch_seconds",
+            "Host time to dispatch one staged batch (async — completion is "
+            "NOT awaited; see dl4j_step_latency_seconds from StepProfiler "
+            "for settled latency); `k` = train iterations fused into the "
+            "dispatch (superstep)",
+            label_names=("engine", "k"))
+        # Few distinct k values per process; children are cached.
+        self._dispatch_k = {
+            1: self._dispatch_family.labels(engine=engine, k="1")}
+        self.h2d = m.counter(
+            "dl4j_host_to_device_bytes_total",
+            "Host-resident bytes staged to device with training batches",
+            label_names=("engine",)).labels(engine=engine)
+        self.jit_hit = m.counter(
+            "dl4j_jit_cache_hits_total", "Engine jit-program cache hits",
+            label_names=("engine",)).labels(engine=engine)
+        self.jit_miss = m.counter(
+            "dl4j_jit_cache_misses_total",
+            "Engine jit-program cache misses (a new program will "
+            "trace+compile)",
+            label_names=("engine",)).labels(engine=engine)
+        self.input_wait = m.histogram(
+            "dl4j_input_wait_seconds",
+            "Host seconds blocked in iterator-next waiting for the next "
+            "batch (input starvation; the device is idle while this "
+            "accrues)",
+            label_names=("source",)).labels(source=engine)
+
+    def enqueue(self):
+        """Span around the call of the compiled step, and nothing else."""
+        return _obs.tracer.span(f"{self.engine}.enqueue", cat="train")
+
+    def batches(self, net, source):
+        """Yield `source`'s items, each `next()` under `<e>.input_wait`.
+        The wait is timed separately from the dispatch: with async/staged
+        input tiers it is pure device starvation."""
+        source = iter(source)
+        while True:
+            t_wait = time.perf_counter()
+            with _obs.tracer.span(f"{self.engine}.input_wait", cat="train"):
+                try:
+                    item = next(source)
+                except StopIteration:
+                    return
+            net._last_input_wait = time.perf_counter() - t_wait
+            self.input_wait.observe(net._last_input_wait)
+            yield item
+
+    def dispatch(self, net, batch, h2d: int, inner):
+        """`inner(batch)` under `<e>.iteration` — the engine's observability
+        choke point: every training path (plain / tBPTT / solver /
+        superstep, local or sharded) goes through here."""
+        self.h2d.inc(h2d)
+        k = int(getattr(batch, "k", 1))
+        it0 = net.iteration
+        t0 = time.perf_counter()
+        with _obs.iteration_span(self.engine, it0 + 1):
+            try:
+                return inner(batch)
+            except Exception as e:
+                # Forensics for uncaught dispatch failures: the bundle is
+                # written before the exception unwinds the fit loop.
+                _obs.flight.on_crash(f"{self.engine}.dispatch", e)
+                raise
+            finally:
+                dt = time.perf_counter() - t0
+                child = self._dispatch_k.get(k)
+                if child is None:
+                    child = self._dispatch_family.labels(
+                        engine=self.engine, k=str(k))
+                    self._dispatch_k[k] = child
+                child.observe(dt)
+                self.iters.inc(max(0, net.iteration - it0))
+                _obs.flight.record_step(
+                    self.engine, net.iteration, loss=net._score, seconds=dt,
+                    k=k, h2d_bytes=h2d,
+                    input_wait=getattr(net, "_last_input_wait", None),
+                    jit_hits=self.jit_hit.get(),
+                    jit_misses=self.jit_miss.get())

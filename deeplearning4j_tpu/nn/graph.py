@@ -12,7 +12,6 @@ from __future__ import annotations
 import copy
 import math
 import os
-import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -52,45 +51,10 @@ from deeplearning4j_tpu.datasets.iterators import (
     transfer_cast,
 )
 from deeplearning4j_tpu import observability as _obs
+from deeplearning4j_tpu.nn.fit_obs import FitObs
 
-# Hot-loop series resolved once at import (observability/metrics.py rule 2).
-_M_ITERS = _obs.metrics.counter(
-    "dl4j_train_iterations_total", "Completed training iterations",
-    label_names=("engine",)).labels(engine="graph")
-_M_EPOCHS = _obs.metrics.counter(
-    "dl4j_train_epochs_total", "Completed fit() epochs",
-    label_names=("engine",)).labels(engine="graph")
-_M_DISPATCH_FAMILY = _obs.metrics.histogram(
-    "dl4j_step_dispatch_seconds",
-    "Host time to dispatch one staged batch (async — completion is NOT "
-    "awaited; see dl4j_step_latency_seconds from StepProfiler for settled "
-    "latency); `k` = train iterations fused into the dispatch (superstep)",
-    label_names=("engine", "k"))
-_M_DISPATCH_K = {1: _M_DISPATCH_FAMILY.labels(engine="graph", k="1")}
-
-
-def _dispatch_observe(k: int, seconds: float) -> None:
-    child = _M_DISPATCH_K.get(k)
-    if child is None:  # few distinct k values per process; cache children
-        child = _M_DISPATCH_FAMILY.labels(engine="graph", k=str(k))
-        _M_DISPATCH_K[k] = child
-    child.observe(seconds)
-_M_H2D = _obs.metrics.counter(
-    "dl4j_host_to_device_bytes_total",
-    "Host-resident bytes staged to device with training batches",
-    label_names=("engine",)).labels(engine="graph")
-_M_JIT_HIT = _obs.metrics.counter(
-    "dl4j_jit_cache_hits_total", "Engine jit-program cache hits",
-    label_names=("engine",)).labels(engine="graph")
-_M_JIT_MISS = _obs.metrics.counter(
-    "dl4j_jit_cache_misses_total",
-    "Engine jit-program cache misses (a new program will trace+compile)",
-    label_names=("engine",)).labels(engine="graph")
-_M_INPUT_WAIT = _obs.metrics.histogram(
-    "dl4j_input_wait_seconds",
-    "Host seconds blocked in iterator-next waiting for the next batch "
-    "(input starvation; the device is idle while this accrues)",
-    label_names=("source",)).labels(source="graph")
+# This engine's hot-loop metric series and fit-loop spans.
+_FIT = FitObs("graph")
 
 
 def _as_mds(data, labels=None) -> MultiDataSet:
@@ -332,7 +296,7 @@ class ComputationGraph:
     def _get_jit(self, kind: str, **static):
         # Key construction/lookup + compile-cache store hook shared with
         # MultiLayerNetwork (see nn/jit_cache.py).
-        return jit_cache_mod.get_jit(self, _M_JIT_HIT, _M_JIT_MISS,
+        return jit_cache_mod.get_jit(self, _FIT.jit_hit, _FIT.jit_miss,
                                      kind, **static)
 
     def warmup(self, data=None, kinds=None, background: bool = False,
@@ -777,16 +741,7 @@ class ComputationGraph:
                                        "transfer_dtype", None))
             src_it = iter(src)
             try:
-                while True:
-                    # iterator-next is timed separately: with async/staged
-                    # input tiers this wait is pure device starvation.
-                    t_wait = time.perf_counter()
-                    try:
-                        item = next(src_it)
-                    except StopIteration:
-                        break
-                    self._last_input_wait = time.perf_counter() - t_wait
-                    _M_INPUT_WAIT.observe(self._last_input_wait)
+                for item in _FIT.batches(self, src_it):
                     self._fit_dispatch(
                         item if isinstance(item, MultiSuperbatch)
                         else _as_mds(item))
@@ -795,7 +750,7 @@ class ComputationGraph:
                 _staging.close_stager(src_it)
                 _staging.close_stager(src)
         self.epoch += 1
-        _M_EPOCHS.inc()
+        _FIT.epochs.inc()
         for listener in self.listeners:
             listener.on_epoch_end(self)
         return self
@@ -816,26 +771,7 @@ class ComputationGraph:
                                mds.labels_masks
                                if hasattr(mds, "labels_masks")
                                else mds.labels_mask)
-        _M_H2D.inc(h2d)
-        it0 = self.iteration
-        t0 = time.perf_counter()
-        with _obs.iteration_span("graph", it0 + 1):
-            try:
-                return self._fit_dispatch_inner(mds)
-            except Exception as e:
-                # Forensics for uncaught dispatch failures: the bundle is
-                # written before the exception unwinds the fit loop.
-                _obs.flight.on_crash("graph.dispatch", e)
-                raise
-            finally:
-                dt = time.perf_counter() - t0
-                _dispatch_observe(int(getattr(mds, "k", 1)), dt)
-                _M_ITERS.inc(max(0, self.iteration - it0))
-                _obs.flight.record_step(
-                    "graph", self.iteration, loss=self._score, seconds=dt,
-                    k=int(getattr(mds, "k", 1)), h2d_bytes=h2d,
-                    input_wait=getattr(self, "_last_input_wait", None),
-                    jit_hits=_M_JIT_HIT.get(), jit_misses=_M_JIT_MISS.get())
+        return _FIT.dispatch(self, mds, h2d, self._fit_dispatch_inner)
 
     def _fit_dispatch_inner(self, mds):
         if isinstance(mds, (MultiSuperbatch, Superbatch)):
@@ -864,12 +800,12 @@ class ComputationGraph:
         fn = self._get_jit("solver_step", algo=str(algo))
         fmasks = _as_mask_list(mds.features_masks)
         lmasks = _as_mask_list(mds.labels_masks)
-        self.params_tree, loss = fn(
-            self.params_tree, self.state,
-            [jnp.asarray(f) for f in mds.features],
-            [jnp.asarray(l) for l in mds.labels],
-            fmasks, lmasks,
-        )
+        args = (self.params_tree, self.state,
+                [jnp.asarray(f) for f in mds.features],
+                [jnp.asarray(l) for l in mds.labels],
+                fmasks, lmasks)
+        with _FIT.enqueue():
+            self.params_tree, loss = fn(*args)
         self._score = loss
         self.iteration += max(1, g.iterations)
         # Stats snapshots are SGD-path only; clear stale ones (see
@@ -957,15 +893,15 @@ class ComputationGraph:
         step_fn = self._get_jit("train_superstep", k=k,
                                 scan=_superstep.use_scan(),
                                 kernels=_superstep.kernel_config())
-        (self.params_tree, self.state, self.opt_state, losses,
-         self._clock) = step_fn(
-            self.params_tree, self.state, self.opt_state,
-            [jnp.asarray(f) for f in sb.features],
-            [jnp.asarray(l) for l in sb.labels],
-            _as_mask_list(sb.features_masks),
-            _as_mask_list(sb.labels_masks),
-            self._device_clock(),
-        )
+        args = (self.params_tree, self.state, self.opt_state,
+                [jnp.asarray(f) for f in sb.features],
+                [jnp.asarray(l) for l in sb.labels],
+                _as_mask_list(sb.features_masks),
+                _as_mask_list(sb.labels_masks),
+                self._device_clock())
+        with _FIT.enqueue():
+            (self.params_tree, self.state, self.opt_state, losses,
+             self._clock) = step_fn(*args)
         for i in range(k):
             self._score = losses[i]  # device scalar; sync deferred
             self.iteration += 1
@@ -1027,13 +963,13 @@ class ComputationGraph:
             step_fn = self._get_jit("train_step_tbptt_scan")
             fmasks = _as_mask_list(mds.features_masks)
             lmasks = _as_mask_list(mds.labels_masks)
-            (self.params_tree, self.state, self.opt_state, loss,
-             self._clock) = step_fn(
-                self.params_tree, self.state, self.opt_state,
-                [jnp.asarray(f) for f in mds.features],
-                [jnp.asarray(l) for l in mds.labels],
-                fmasks, lmasks, self._device_clock(), ebs,
-            )
+            args = (self.params_tree, self.state, self.opt_state,
+                    [jnp.asarray(f) for f in mds.features],
+                    [jnp.asarray(l) for l in mds.labels],
+                    fmasks, lmasks, self._device_clock(), ebs)
+            with _FIT.enqueue():
+                (self.params_tree, self.state, self.opt_state, loss,
+                 self._clock) = step_fn(*args)
             self._score = loss
             return self._finish_tbptt(saved_state)
         n_chunks = math.ceil(t / fwd)
@@ -1092,7 +1028,8 @@ class ComputationGraph:
         ]
         if tbptt:
             args.append(ebs)
-        out = step_fn(*args)
+        with _FIT.enqueue():
+            out = step_fn(*args)
         if len(out) == 6:
             self.params_tree, self.state, self.opt_state, loss, stats, self._clock = out
             self.last_training_stats = stats

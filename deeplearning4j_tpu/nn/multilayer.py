@@ -22,7 +22,6 @@ from __future__ import annotations
 import copy
 import math
 import os
-import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -57,45 +56,10 @@ from deeplearning4j_tpu.datasets.iterators import (
     transfer_cast,
 )
 from deeplearning4j_tpu import observability as _obs
+from deeplearning4j_tpu.nn.fit_obs import FitObs
 
-# Hot-loop series resolved once at import (observability/metrics.py rule 2).
-_M_ITERS = _obs.metrics.counter(
-    "dl4j_train_iterations_total", "Completed training iterations",
-    label_names=("engine",)).labels(engine="mln")
-_M_EPOCHS = _obs.metrics.counter(
-    "dl4j_train_epochs_total", "Completed fit() epochs",
-    label_names=("engine",)).labels(engine="mln")
-_M_DISPATCH_FAMILY = _obs.metrics.histogram(
-    "dl4j_step_dispatch_seconds",
-    "Host time to dispatch one staged batch (async — completion is NOT "
-    "awaited; see dl4j_step_latency_seconds from StepProfiler for settled "
-    "latency); `k` = train iterations fused into the dispatch (superstep)",
-    label_names=("engine", "k"))
-_M_DISPATCH_K = {1: _M_DISPATCH_FAMILY.labels(engine="mln", k="1")}
-
-
-def _dispatch_observe(k: int, seconds: float) -> None:
-    child = _M_DISPATCH_K.get(k)
-    if child is None:  # few distinct k values per process; cache children
-        child = _M_DISPATCH_FAMILY.labels(engine="mln", k=str(k))
-        _M_DISPATCH_K[k] = child
-    child.observe(seconds)
-_M_H2D = _obs.metrics.counter(
-    "dl4j_host_to_device_bytes_total",
-    "Host-resident bytes staged to device with training batches",
-    label_names=("engine",)).labels(engine="mln")
-_M_JIT_HIT = _obs.metrics.counter(
-    "dl4j_jit_cache_hits_total", "Engine jit-program cache hits",
-    label_names=("engine",)).labels(engine="mln")
-_M_JIT_MISS = _obs.metrics.counter(
-    "dl4j_jit_cache_misses_total",
-    "Engine jit-program cache misses (a new program will trace+compile)",
-    label_names=("engine",)).labels(engine="mln")
-_M_INPUT_WAIT = _obs.metrics.histogram(
-    "dl4j_input_wait_seconds",
-    "Host seconds blocked in iterator-next waiting for the next batch "
-    "(input starvation; the device is idle while this accrues)",
-    label_names=("source",)).labels(source="mln")
+# This engine's hot-loop metric series and fit-loop spans.
+_FIT = FitObs("mln")
 
 
 _cast_floating = params_mod.cast_floating
@@ -320,7 +284,7 @@ class MultiLayerNetwork:
     def _get_jit(self, kind: str, **static):
         # Key construction/lookup + compile-cache store hook shared with
         # ComputationGraph (see nn/jit_cache.py).
-        return jit_cache_mod.get_jit(self, _M_JIT_HIT, _M_JIT_MISS,
+        return jit_cache_mod.get_jit(self, _FIT.jit_hit, _FIT.jit_miss,
                                      kind, **static)
 
     def warmup(self, data=None, kinds=None, background: bool = False,
@@ -806,23 +770,14 @@ class MultiLayerNetwork:
                                            "transfer_dtype", None))
                 src_it = iter(src)
                 try:
-                    while True:
-                        # iterator-next is timed separately: with async/staged
-                        # input tiers this wait is pure device starvation.
-                        t_wait = time.perf_counter()
-                        try:
-                            ds = next(src_it)
-                        except StopIteration:
-                            break
-                        self._last_input_wait = time.perf_counter() - t_wait
-                        _M_INPUT_WAIT.observe(self._last_input_wait)
+                    for ds in _FIT.batches(self, src_it):
                         self._fit_dispatch(ds)
                 finally:
                     # An abandoned epoch must not leave staged HBM buffers.
                     _staging.close_stager(src_it)
                     _staging.close_stager(src)
         self.epoch += 1
-        _M_EPOCHS.inc()
+        _FIT.epochs.inc()
         for listener in self.listeners:
             listener.on_epoch_end(self)
         return self
@@ -840,26 +795,7 @@ class MultiLayerNetwork:
             ds = transfer_cast(ds, tdt)
         h2d = _obs.host_nbytes(ds.features, ds.labels,
                                ds.features_mask, ds.labels_mask)
-        _M_H2D.inc(h2d)
-        it0 = self.iteration
-        t0 = time.perf_counter()
-        with _obs.iteration_span("mln", it0 + 1):
-            try:
-                return self._fit_dispatch_inner(ds)
-            except Exception as e:
-                # Forensics for uncaught dispatch failures: the bundle is
-                # written before the exception unwinds the fit loop.
-                _obs.flight.on_crash("mln.dispatch", e)
-                raise
-            finally:
-                dt = time.perf_counter() - t0
-                _dispatch_observe(int(getattr(ds, "k", 1)), dt)
-                _M_ITERS.inc(max(0, self.iteration - it0))
-                _obs.flight.record_step(
-                    "mln", self.iteration, loss=self._score, seconds=dt,
-                    k=int(getattr(ds, "k", 1)), h2d_bytes=h2d,
-                    input_wait=getattr(self, "_last_input_wait", None),
-                    jit_hits=_M_JIT_HIT.get(), jit_misses=_M_JIT_MISS.get())
+        return _FIT.dispatch(self, ds, h2d, self._fit_dispatch_inner)
 
     def _fit_dispatch_inner(self, ds):
         if isinstance(ds, Superbatch):
@@ -948,14 +884,16 @@ class MultiLayerNetwork:
         step_fn = self._get_jit("train_superstep", k=k,
                                 scan=_superstep.use_scan(),
                                 kernels=_superstep.kernel_config())
-        (self.params_tree, self.state, self.opt_state, losses,
-         self._clock) = step_fn(
+        args = (
             self.params_tree, self.state, self.opt_state,
             jnp.asarray(sb.features), jnp.asarray(sb.labels),
             None if sb.features_mask is None else jnp.asarray(sb.features_mask),
             None if sb.labels_mask is None else jnp.asarray(sb.labels_mask),
             self._device_clock(),
         )
+        with _FIT.enqueue():
+            (self.params_tree, self.state, self.opt_state, losses,
+             self._clock) = step_fn(*args)
         for i in range(k):
             self._score = losses[i]  # device scalar; sync deferred
             self.iteration += 1
@@ -971,12 +909,14 @@ class MultiLayerNetwork:
         self._check_sgd_only_policy("solver optimizers (LBFGS/CG/line search)")
         g = self.conf.global_conf
         fn = self._get_jit("solver_step", algo=str(algo))
-        self.params_tree, loss = fn(
+        args = (
             self.params_tree, self.state,
             jnp.asarray(ds.features), jnp.asarray(ds.labels),
             None if ds.features_mask is None else jnp.asarray(ds.features_mask),
             None if ds.labels_mask is None else jnp.asarray(ds.labels_mask),
         )
+        with _FIT.enqueue():
+            self.params_tree, loss = fn(*args)
         self._score = loss
         self.iteration += max(1, g.iterations)
         # Per-layer grad/update stats are an SGD-path feature; clear any
@@ -1071,7 +1011,7 @@ class MultiLayerNetwork:
     def _fit_one(self, ds: DataSet):
         collect = self._collect_stats
         step_fn = self._get_jit("train_step_stats" if collect else "train_step")
-        out = step_fn(
+        args = (
             self.params_tree, self.state, self.opt_state,
             jnp.asarray(ds.features),
             jnp.asarray(ds.labels),
@@ -1079,6 +1019,8 @@ class MultiLayerNetwork:
             None if ds.labels_mask is None else jnp.asarray(ds.labels_mask),
             self._device_clock(),
         )
+        with _FIT.enqueue():
+            out = step_fn(*args)
         if collect:
             self.params_tree, self.state, self.opt_state, loss, stats, self._clock = out
             self.last_training_stats = stats  # device scalars, fetched lazily
@@ -1123,14 +1065,16 @@ class MultiLayerNetwork:
             # Fast path: the entire chunk loop is one jitted scan — ONE
             # dispatch per sequence instead of one per chunk (PERF.md §4).
             step_fn = self._get_jit("train_step_tbptt_scan")
-            (self.params_tree, self.state, self.opt_state, loss,
-             self._clock) = step_fn(
+            args = (
                 self.params_tree, self.state, self.opt_state,
                 jnp.asarray(ds.features), jnp.asarray(ds.labels),
                 None if ds.features_mask is None else jnp.asarray(ds.features_mask),
                 None if ds.labels_mask is None else jnp.asarray(ds.labels_mask),
                 self._device_clock(), eb,
             )
+            with _FIT.enqueue():
+                (self.params_tree, self.state, self.opt_state, loss,
+                 self._clock) = step_fn(*args)
             self._score = loss
             self._finish_tbptt(saved_state)
             return
@@ -1147,7 +1091,7 @@ class MultiLayerNetwork:
             collect = self._collect_stats
             step_fn = self._get_jit("train_step_tbptt",
                                     advance=ci == n_chunks - 1, collect=collect)
-            out = step_fn(
+            args = (
                 self.params_tree, self.state, self.opt_state,
                 jnp.asarray(chunk.features),
                 jnp.asarray(chunk.labels),
@@ -1155,6 +1099,8 @@ class MultiLayerNetwork:
                 None if chunk.labels_mask is None else jnp.asarray(chunk.labels_mask),
                 self._device_clock(), eb,
             )
+            with _FIT.enqueue():
+                out = step_fn(*args)
             if collect:
                 (self.params_tree, self.state, self.opt_state, loss, stats,
                  self._clock) = out

@@ -16,8 +16,6 @@ Env knobs (read once at import):
 - `DL4J_TPU_OBS`              — "0"/"false"/"off" disables both the default
                                 registry and tracer (mutators become one
                                 bool check; spans become a shared no-op).
-- `DL4J_TPU_OBS_SAMPLE_EVERY` — record every Nth iteration span (default 1;
-                                metrics are never sampled, only spans).
 - `DL4J_TPU_TRACE_BUFFER`     — trace ring-buffer capacity (default 16384).
 - `DL4J_TPU_FLIGHT*`          — flight-recorder knobs (see `flight.py`).
 
@@ -39,13 +37,13 @@ from deeplearning4j_tpu.observability import propagate
 from deeplearning4j_tpu.observability.metrics import (
     DEFAULT_BUCKETS, WIDE_BUCKETS, MetricsRegistry, backend_is_up,
     install_builtin_collectors)
-from deeplearning4j_tpu.observability.tracing import NOOP_SPAN, Tracer
+from deeplearning4j_tpu.observability.tracing import Tracer
 from deeplearning4j_tpu.observability.profiler import (
     StepProfiler, chip_peak_flops, chip_peak_hbm_bw, estimate_step_cost,
     estimate_step_flops)
 
 __all__ = [
-    "metrics", "tracer", "config", "StepProfiler", "MetricsRegistry",
+    "metrics", "tracer", "StepProfiler", "MetricsRegistry",
     "Tracer", "DEFAULT_BUCKETS", "WIDE_BUCKETS", "enable", "disable",
     "iteration_span", "host_nbytes", "install_jax_compile_hook",
     "bench_snapshot", "prometheus_payload", "chip_peak_flops",
@@ -57,20 +55,6 @@ __all__ = [
 
 OBS_ENABLED = os.environ.get("DL4J_TPU_OBS", "1").lower() not in (
     "0", "false", "off")
-
-
-class _Config:
-    """Mutable runtime knobs (import-time defaults from the environment)."""
-
-    def __init__(self):
-        try:
-            self.sample_every = max(
-                1, int(os.environ.get("DL4J_TPU_OBS_SAMPLE_EVERY", "1")))
-        except ValueError:
-            self.sample_every = 1
-
-
-config = _Config()
 
 # The process-global instruments. Hot-loop call sites resolve their labeled
 # children from `metrics` once at module import; `enable()`/`disable()` flip
@@ -138,11 +122,7 @@ def disable() -> None:
 
 
 def iteration_span(engine: str, iteration: int, **args):
-    """Span for one training iteration, honoring `config.sample_every`.
-    Returns the shared no-op for sampled-out iterations so the hot loop
-    never allocates for them."""
-    if not tracer.enabled or iteration % config.sample_every:
-        return NOOP_SPAN
+    """Span for one training iteration (`<engine>.iteration`)."""
     return tracer.span(f"{engine}.iteration", cat="train", engine=engine,
                        iteration=iteration, **args)
 

@@ -11,8 +11,17 @@ live from a running system via the UIServer's `/api/trace` route.
 The buffer is a bounded `deque` (ring): a long-running server keeps the most
 recent `max_events` spans and never grows without bound. Span begin/end is a
 perf_counter_ns read + a deque append — cheap enough for per-iteration spans
-at training cadence; `DL4J_TPU_OBS_SAMPLE_EVERY` thins them further (see
-`observability.iteration_span`).
+at training cadence.
+
+One clock with the device: in a process that has already imported jax, a
+live span also opens a `jax.profiler.TraceAnnotation` of the same name for
+its lifetime, with its scalar arguments as the annotation's stats. While a
+`jax.profiler` capture runs (`ui/stats.py::ProfilerListener`, the
+benchmark's `--trace 1`), the framework's spans therefore sit on
+`/host:CPU`, on their thread's line, nested, beside the device's own
+operations; with no capture running the annotation is one flag check. jax
+is never imported for it (routers and coordinators stay jax-free), and
+`complete()` stays ring-only: a retroactive wait is not host work.
 
 Cross-process spans (`observability/propagate.py`): a span opened with
 ``span_ctx=`` takes that context's (trace_id, span_id) as its identity; one
@@ -27,6 +36,7 @@ from __future__ import annotations
 
 import functools
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -36,8 +46,19 @@ from deeplearning4j_tpu.analysis.locktrace import named_lock
 from deeplearning4j_tpu.observability import propagate as _prop
 
 
+def _annotation_class():
+    """`jax.profiler.TraceAnnotation`, or None in a process that has not
+    imported jax (this never imports it)."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)  # None while jax is importing
+    return getattr(profiler, "TraceAnnotation", None)
+
+
+_SCALARS = (bool, int, float, str)
+
+
 class _NoopSpan:
-    """Shared reusable no-op (disabled tracer / sampled-out iteration)."""
+    """Shared reusable no-op (disabled tracer)."""
 
     __slots__ = ()
 
@@ -58,7 +79,7 @@ NOOP_SPAN = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0",
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_annotation",
                  "trace_id", "span_id", "parent_span_id")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
@@ -104,11 +125,20 @@ class _Span:
                 self.span_id = _prop.new_span_id()
                 self.parent_span_id = encl.span_id
         stack.append(self)
+        self._annotation = None
+        cls = _annotation_class()
+        if cls is not None and cls.is_enabled():  # a capture is running
+            self._annotation = cls(self.name, **{
+                k: v for k, v in self.args.items()
+                if isinstance(v, _SCALARS)})
+            self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         dur_ns = time.perf_counter_ns() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         tracer = self._tracer
         stack = tracer._tls.stack
         stack.pop()

@@ -300,13 +300,15 @@ class ParallelWrapper:
             src = _staging.DeviceStager(
                 groups, stage_fn=stage, net=net, engine="parallel",
                 depth=self.prefetch_buffer)
+        input_wait = ("graph" if is_graph else "mln") + ".input_wait"
         try:
             while True:
                 t_wait = time.perf_counter()
-                try:
-                    sharded = next(src)
-                except StopIteration:
-                    break
+                with _obs.tracer.span(input_wait, cat="train"):
+                    try:
+                        sharded = next(src)
+                    except StopIteration:
+                        break
                 wait = time.perf_counter() - t_wait
                 _M_INPUT_WAIT.observe(wait)
                 # K batches feed one stacked dispatch: the flight record's

@@ -62,7 +62,8 @@ class GenerationRequest:
     __slots__ = ("prompt", "n_steps", "temperature", "top_k", "top_p",
                  "seed", "eos_id", "ids", "error", "deadline", "cancelled",
                  "event", "t_submit", "rng", "ctx", "t_submit_ns",
-                 "adapter", "params", "ledger_rec", "_last_tok_ns")
+                 "adapter", "params", "ledger_rec", "_last_tok_ns",
+                 "_rounds", "_decode_t0_ns")
 
     def __init__(self, prompt, n_steps, *, temperature=1.0, top_k=0,
                  top_p=0.0, seed=0, eos_id=None, deadline=None,
@@ -97,6 +98,10 @@ class GenerationRequest:
         # default keeps direct scheduler users (tests, bench) branch-free.
         self.ledger_rec = NOOP_RECORD if ledger_rec is None else ledger_rec
         self._last_tok_ns: Optional[int] = None  # ITL anchor
+        # Decode rounds this request rode, and when the first one began:
+        # its one `serving.decode` span is written at retirement.
+        self._rounds = 0
+        self._decode_t0_ns = 0
 
     @property
     def done(self) -> bool:
@@ -190,6 +195,7 @@ class GenerationScheduler:
                                                         phase="prefill")
         self._disp_decode = _m.DISPATCH_SECONDS.labels(model=model_name,
                                                        phase="decode")
+        self._round_end_ns = 0  # end of the latest decode round
         if kv == "paged":
             pool = self.stepper.pool
             for st in ("free", "used", "shared"):
@@ -411,6 +417,10 @@ class GenerationScheduler:
     def _admit(self, slot: int, req: GenerationRequest) -> bool:
         """Prefill + install + first token. Returns True when the request
         stays active in `slot` (False: finished or failed at admission)."""
+        with _obs.tracer.span("serving.admit", cat="serving"):
+            return self._admit_inner(slot, req)
+
+    def _admit_inner(self, slot: int, req: GenerationRequest) -> bool:
         pad_to = next(b for b in self.prompt_buckets
                       if len(req.prompt) <= b)
         if req.ctx is not None:
@@ -431,7 +441,8 @@ class GenerationScheduler:
             return False
         _m.TTFT_SECONDS.labels(model=self.model_name).observe(
             time.monotonic() - req.t_submit)
-        self._sample(req, probs)
+        with _obs.tracer.span("serving.sample", cat="serving"):
+            self._sample(req, probs)
         req.ledger_rec.mark("first_token")
         if req.done:
             self._clear_slot(slot)
@@ -449,11 +460,38 @@ class GenerationScheduler:
         if self.kv == "paged":
             req.ledger_rec.add_cow_copies(
                 self.stepper.pool.cow_count(slot))
+        if req.ctx is not None and req._rounds:
+            # One span a request, first round's start to last round's
+            # end: the federated request tree keeps router -> replica ->
+            # admission_wait -> prefill -> decode at O(1) spans a request.
+            _obs.tracer.complete(
+                "serving.decode", req._decode_t0_ns,
+                self._round_end_ns - req._decode_t0_ns, cat="serving",
+                parent_ctx=req.ctx, model=self.model_name,
+                rounds=req._rounds,
+                tokens=len(req.ids) - len(req.prompt) - 1)
         self._clear_slot(slot)
         if timed_out:
             self._finish_timeout(req)
         else:
             req.event.set()
+
+    def _credit_round(self, active: Dict[int, GenerationRequest],
+                      t0_ns: int, step_hist) -> None:
+        """Cost attribution choke point: one round's wall time (begun at
+        `t0_ns`, ending now) splits EVENLY across the co-batched slots
+        (every slot rides every dispatch of the round, including other
+        groups' rewinds)."""
+        self._round_end_ns = time.perf_counter_ns()
+        round_s = (self._round_end_ns - t0_ns) / 1e9
+        step_hist.observe(round_s)
+        self._disp_decode.inc(round_s)
+        share = round_s / len(active)
+        for req in active.values():
+            req.ledger_rec.add_device_seconds(share)
+            if not req._rounds:
+                req._decode_t0_ns = t0_ns
+            req._rounds += 1
 
     def _loop(self) -> None:
         active: Dict[int, GenerationRequest] = {}
@@ -516,35 +554,24 @@ class GenerationScheduler:
                 self._spec_round(active, free, step_hist)
                 continue
             t0_ns = time.perf_counter_ns()
-            rows = self._decode_round(active)
-            dur_ns = time.perf_counter_ns() - t0_ns
-            step_hist.observe(dur_ns / 1e9)
-            # Cost attribution choke point: one round's wall time splits
-            # EVENLY across the co-batched slots (every slot rides every
-            # dispatch of the round, including other groups' rewinds).
-            round_s = dur_ns / 1e9
-            self._disp_decode.inc(round_s)
-            share = round_s / len(active)
-            for req in active.values():
-                req.ledger_rec.add_device_seconds(share)
-                if req.ctx is not None:
-                    _obs.tracer.complete(
-                        "serving.decode_step", t0_ns, dur_ns,
-                        cat="serving", parent_ctx=req.ctx,
-                        model=self.model_name)
+            with _obs.tracer.span("serving.decode_round", cat="serving",
+                                  slots=len(active)):
+                rows = self._decode_round(active)
+            self._credit_round(active, t0_ns, step_hist)
             now = time.monotonic()
-            for slot, req in list(active.items()):
-                if req.cancelled or (req.deadline is not None
-                                     and now > req.deadline):
-                    self._retire(slot, req, timed_out=True)
-                    del active[slot]
-                    free.append(slot)
-                    continue
-                self._sample(req, rows[slot])
-                if req.done:
-                    self._retire(slot, req)
-                    del active[slot]
-                    free.append(slot)
+            with _obs.tracer.span("serving.sample", cat="serving"):
+                for slot, req in list(active.items()):
+                    if req.cancelled or (req.deadline is not None
+                                         and now > req.deadline):
+                        self._retire(slot, req, timed_out=True)
+                        del active[slot]
+                        free.append(slot)
+                        continue
+                    self._sample(req, rows[slot])
+                    if req.done:
+                        self._retire(slot, req)
+                        del active[slot]
+                        free.append(slot)
 
     def _decode_round(self, active: Dict[int, GenerationRequest]):
         """One decode step for every active slot, grouped by adapter.
@@ -627,53 +654,47 @@ class GenerationScheduler:
         tok = np.zeros((self.slots, k + 1), np.int64)
         tok[:, 0] = x
         t0_ns = time.perf_counter_ns()
-        for j in range(k):
-            dprobs = draft.step(tok[:, j])
-            tok[:, j + 1] = dprobs.argmax(axis=-1)
-        if k:
-            # Feed the last proposal so the draft has consumed tok[:, :k+1]
-            # too; the result is unused (rewound below either way).
-            draft.step(tok[:, k])
-        probs = self.stepper.step_k(tok)
-        dur_ns = time.perf_counter_ns() - t0_ns
-        step_hist.observe(dur_ns / 1e9)
-        round_s = dur_ns / 1e9
-        self._disp_decode.inc(round_s)
-        share = round_s / len(active)
-        for req in active.values():
-            req.ledger_rec.add_device_seconds(share)
-            if req.ctx is not None:
-                _obs.tracer.complete(
-                    "serving.decode_step", t0_ns, dur_ns, cat="serving",
-                    parent_ctx=req.ctx, model=self.model_name)
+        with _obs.tracer.span("serving.decode_round", cat="serving",
+                              slots=len(active), k=k):
+            for j in range(k):
+                dprobs = draft.step(tok[:, j])
+                tok[:, j + 1] = dprobs.argmax(axis=-1)
+            if k:
+                # Feed the last proposal so the draft has consumed
+                # tok[:, :k+1] too; the result is unused (rewound below
+                # either way).
+                draft.step(tok[:, k])
+            probs = self.stepper.step_k(tok)
+        self._credit_round(active, t0_ns, step_hist)
         spec_acc = _m.SPECULATIVE_TOKENS.labels(model=self.model_name,
                                                 outcome="accepted")
         spec_rej = _m.SPECULATIVE_TOKENS.labels(model=self.model_name,
                                                 outcome="rejected")
         now = time.monotonic()
-        for slot, req in list(active.items()):
-            if req.cancelled or (req.deadline is not None
-                                 and now > req.deadline):
-                self._retire(slot, req, timed_out=True)
-                del active[slot]
-                free.append(slot)
-                continue
-            greedy = req.temperature <= 0
-            accepted = 0
-            for j in range(k + 1):
-                t = self._sample(req, probs[slot, j])
-                if (req.done or not greedy or j >= k
-                        or t != int(tok[slot, j + 1])):
-                    break
-                accepted += 1
-            if greedy and k:
-                spec_acc.inc(accepted)
-                spec_rej.inc(k - accepted)
-                req.ledger_rec.add_speculative(accepted, k - accepted)
-            if req.done:
-                self._retire(slot, req)
-                del active[slot]
-                free.append(slot)
+        with _obs.tracer.span("serving.sample", cat="serving"):
+            for slot, req in list(active.items()):
+                if req.cancelled or (req.deadline is not None
+                                     and now > req.deadline):
+                    self._retire(slot, req, timed_out=True)
+                    del active[slot]
+                    free.append(slot)
+                    continue
+                greedy = req.temperature <= 0
+                accepted = 0
+                for j in range(k + 1):
+                    t = self._sample(req, probs[slot, j])
+                    if (req.done or not greedy or j >= k
+                            or t != int(tok[slot, j + 1])):
+                        break
+                    accepted += 1
+                if greedy and k:
+                    spec_acc.inc(accepted)
+                    spec_rej.inc(k - accepted)
+                    req.ledger_rec.add_speculative(accepted, k - accepted)
+                if req.done:
+                    self._retire(slot, req)
+                    del active[slot]
+                    free.append(slot)
         # Restore the invariant: truncate both caches back to the tokens
         # actually kept (retired slots to 0 — their pool pages are
         # already freed and their table rows zeroed).
