@@ -204,9 +204,11 @@ def max_rel_diff(a, b) -> float:
 
 
 def train_kernel_parity(seed: int) -> dict:
-    """The train step's two Pallas kernels against their XLA references on
+    """The train path's two Pallas bodies against their XLA references on
     a small input, on this device, through the layers' own seams: the
-    interpret-mode parity tests say nothing about what Mosaic compiles."""
+    interpret-mode parity tests say nothing about what Mosaic compiles.
+    `fused_update` is in the step under `auto`; `norm_act`'s BatchNorm
+    body only when forced, which is how `under_each_impl` drives it."""
     import jax
     import jax.numpy as jnp
 
@@ -309,11 +311,17 @@ def phase_train(args, size: Sizes):
     memory = hbm(jax.devices()[0])
 
     # Which implementation did the step's kernels resolve to, and does the
-    # compiled step agree? A Pallas body is a `tpu_custom_call` in the
-    # compiled program; off-chip it is interpreted, and there is none. The
-    # registry resolves while a program is traced, and a step that came
-    # from the AOT store was not traced here: the cost estimate lowers the
-    # same step again (a persistent-cache hit), which asks the registry.
+    # compiled step agree, kernel by kernel? A Pallas body is a
+    # `tpu_custom_call` named after its `pallas_call(name=...)` in the
+    # compiled program; off-chip it is interpreted, and there is none.
+    # Under `auto` on the chip: 107 `fused_update` calls and no `norm_act`
+    # one (every BatchNorm resolves to `xla`, with the reason in its row;
+    # 153 with 46 `norm_act` before PR 25, 0 under `DL4J_TPU_KERNELS=xla`).
+    # A step loaded from an AOT store written under other rules would show
+    # here as a kernel's calls without its `pallas` row. The registry
+    # resolves while a program is traced, and a step that came from the
+    # AOT store was not traced here: the cost estimate lowers the same step
+    # again (a persistent-cache hit), which asks the registry.
     cost = estimate_step_cost(net, transfer_cast(
         MultiDataSet.from_dataset(batches[0]),
         net.dtype_policy.transfer_dtype))
@@ -326,11 +334,21 @@ def phase_train(args, size: Sizes):
     check(texts, "the train step left no compiled executable to inspect "
                  "(is DL4J_TPU_COMPILE_CACHE=off?)")
     custom_calls = sum(t.count("tpu_custom_call") for t in texts)
-    says_pallas = any(r["impl"] == "pallas" for r in rows)
-    if jax.devices()[0].platform == "tpu":
-        check((custom_calls > 0) == says_pallas,
-              f"registry says pallas={says_pallas} but the compiled step "
-              f"holds {custom_calls} tpu_custom_call(s): {rows}")
+    calls_by_kernel = {}
+    for kernel in ("norm_act", "fused_update"):
+        calls = calls_by_kernel[kernel] = sum(
+            1 for t in texts for line in t.splitlines()
+            if "tpu_custom_call" in line and kernel in line)
+        says_pallas = any(r["kernel"] == kernel and r["impl"] == "pallas"
+                          for r in rows)
+        if jax.devices()[0].platform == "tpu":
+            check((calls > 0) == says_pallas,
+                  f"registry says {kernel} pallas={says_pallas} but the "
+                  f"compiled step holds {calls} tpu_custom_call(s) of that "
+                  f"name: {rows}")
+    check(sum(calls_by_kernel.values()) == custom_calls,
+          f"{custom_calls} tpu_custom_call(s) in the step, of which "
+          f"{calls_by_kernel} carry a registry kernel's name")
     emit("train", model=f"resnet50 {image}x{image}x{classes}",
          batch=size.batch, policy="mixed_bfloat16",
          steps_after_warmup=steps, losses=[round(l, 5) for l in losses],
@@ -340,6 +358,7 @@ def phase_train(args, size: Sizes):
          hbm=memory, step_flops=cost["flops"],
          step_bytes_accessed=cost["bytes"], kernels=rows,
          tpu_custom_calls_in_step=custom_calls,
+         tpu_custom_calls_by_kernel=calls_by_kernel,
          kernel_parity=train_kernel_parity(args.seed))
 
 

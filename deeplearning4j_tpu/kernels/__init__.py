@@ -12,8 +12,10 @@ and resolves once per jit signature. Kernels:
 - ``fused_update``    — Adam/Nesterov/RMSProp over the stacked flattened
                         param leaves in one elementwise kernel
                         (`ops/updaters.py`, superstep carry);
-- ``norm_act``        — BatchNorm/LayerNorm normalize+affine+activation
-                        (`nn/layers/normalization.py`);
+- ``norm_act``        — LayerNorm stats+normalize+affine+activation in
+                        one pass (`nn/layers/normalization.py`); BatchNorm's
+                        tail goes through the same seam and `auto` leaves
+                        it to XLA's fusions (its Pallas body: forced only);
 - ``flash_attention`` — the PERF.md §6 flash kernel, migrated here from
                         `ops/flash_attention.py` (shim kept);
 - ``bottleneck_block``— the fused ResNet bottleneck chain (conv1x1/BN/act

@@ -1,25 +1,34 @@
-"""Fused normalize + affine + activation for BatchNorm / LayerNorm.
+"""Normalize + affine + activation for BatchNorm / LayerNorm, behind the seam.
 
-`nn/layers/normalization.py` computes batch statistics (a reduction XLA
+`nn/layers/normalization.py` hands both layers' tails to this module.
+
+LayerNorm: per-row statistics, normalize, scale/shift and activation run
+as one Pallas pass over the `[rows, features]` view, row-tiled so a grid
+step holds one `_BLOCK_BYTES` block in VMEM whatever the row count.
+Arithmetic is f32 inside the kernel for either operand dtype (a v5e has
+no bf16 `sqrt`/`rsqrt`/`tanh` unit); the store casts back.
+
+BatchNorm: the layer computes the batch statistics itself (a reduction XLA
 already does well, and whose single-pass form is part of the bit-
-exactness contract) and then runs an elementwise chain — normalize,
-scale/shift, activation — that re-reads the activation tensor from HBM
-between fusion boundaries. The Pallas path runs that chain in one pass
-over the `[rows, features]` view, row-tiled so a grid step holds one
-`_BLOCK_BYTES` block in VMEM whatever the row count: BatchNorm takes the
-(XLA-computed) mean/var as operands; LayerNorm computes its per-row stats
-in-kernel. Arithmetic is f32 inside the kernel for either operand dtype
-(a v5e has no bf16 `sqrt`/`rsqrt`/`tanh` unit); the store casts back.
+exactness contract), so what reaches the seam is an elementwise chain, the
+one thing XLA fuses into its producer and consumers for free. A custom call
+there is a fusion barrier: the convolution writes its whole output, the
+call reads and writes it again, the next convolution reads the result, and
+the backward (`_diff.pallas_fwd_ref_bwd`) recomputes the chain apart from
+BN's own reductions. `auto` therefore resolves BatchNorm to the XLA
+expression on every backend (`_BATCHNORM_AUTO_REFUSAL`); the Pallas body
+(`_bn_kernel`, mean/var as operands) runs only when forced.
 
 The XLA fallbacks are the LITERAL pre-registry expressions moved here
 verbatim — same ops, same order — so `DL4J_TPU_KERNELS=xla` (and auto
-off-TPU) produces bit-identical jaxprs to the pre-PR layers.
+off-TPU, and auto for BatchNorm anywhere) produces bit-identical jaxprs to
+the pre-PR layers.
 
-Availability (auto): TPU backend, float32 or bfloat16, activation in the
-in-kernel set, feature dim a lane (128) multiple no wider than
-`_MAX_FEATS` and row count a sublane (8) multiple. Forced `pallas` keeps
-the structural constraints and runs interpret mode off-TPU (the CPU parity
-tests' path).
+Availability (auto): LayerNorm only — TPU backend, float32 or bfloat16,
+activation in the in-kernel set, feature dim a lane (128) multiple no
+wider than `_MAX_FEATS` and row count a sublane (8) multiple. Forced
+`pallas` takes either operation, keeps the structural constraints and runs
+interpret mode off-TPU (the CPU parity tests' path).
 """
 
 from __future__ import annotations
@@ -39,6 +48,18 @@ _ACTS = {
 }
 
 
+# Why `auto` never picks the Pallas body for BatchNorm. Decided by the layer
+# type, not by a shape: the body's best case (one read and one write of x)
+# is XLA's worst case for the same chain.
+_BATCHNORM_AUTO_REFUSAL = (
+    "BatchNorm's statistics are XLA's already and what is left is an "
+    "elementwise chain: a custom call there is a fusion barrier between "
+    "the convolution, BN's reductions and the activation (ResNet-50 at "
+    "batch 256 on a v5e: 116.5 GB and ~143 ms a step with it, 81.1 GB and "
+    "~101 ms without; ledger PR 24, PERF.md §6 PR 21); "
+    "DL4J_TPU_KERNEL_NORM_ACT=pallas forces the body")
+
+
 def _pallas_available(backend, shapes, dtypes, meta=(), forced=False):
     m = dict(meta)
     act = m.get("act")
@@ -46,6 +67,8 @@ def _pallas_available(backend, shapes, dtypes, meta=(), forced=False):
         return False, f"activation {act!r} not expressible in-kernel"
     if dtypes and not set(dtypes) <= {"float32", "bfloat16"}:
         return False, f"dtype {sorted(set(dtypes))} not in (float32, bfloat16)"
+    if not forced and m.get("op") == "batchnorm":
+        return False, _BATCHNORM_AUTO_REFUSAL
     if forced and backend != "tpu":
         return True, "forced (interpret mode off-TPU)"
     if backend != "tpu":
@@ -53,7 +76,8 @@ def _pallas_available(backend, shapes, dtypes, meta=(), forced=False):
                        f"{backend} (DL4J_TPU_KERNEL_NORM_ACT=pallas forces "
                        "interpret mode)")
     if not shapes:
-        return True, "TPU backend (shapes unknown: assumed tile-aligned)"
+        return True, ("TPU backend (shapes unknown: LayerNorm assumed, "
+                      "tile-aligned; BatchNorm resolves to xla)")
     rows, feats = shapes
     if feats % 128 or rows % 8:
         return False, (f"rows={rows}, features={feats} not tile-aligned "

@@ -13,7 +13,10 @@ calls into the seam for every block; a memo hit must not re-probe).
 Selection is part of the PROGRAM IDENTITY: `nn/jit_cache.py` folds
 `config_key()` into every cache key and `compilation/store.py` folds
 `config_fingerprint()` into the AOT fingerprint document, so flipping a
-kernel knob can never serve a stale cached program or executable.
+kernel knob can never serve a stale cached program or executable. The
+fingerprint also carries `SELECTION_RULES`, because a stored executable
+outlives the code that chose its kernels: the same mode under other rules
+is another program.
 
 Env knobs (read at resolve time, so tests can monkeypatch):
 
@@ -45,6 +48,13 @@ from typing import Callable, NamedTuple, Optional, Sequence, Tuple
 from deeplearning4j_tpu import observability as _obs
 
 MODES = ("auto", "xla", "pallas")
+
+# Version of the rules that turn a mode into an implementation, for
+# `config_fingerprint()`. Bump it whenever an `is_available` rule changes
+# which body a signature gets, so that no AOT artifact written under the
+# old rules is loaded for the new program. 1 (implicit, no key): PR 10-24. 2: `norm_act` refuses
+# the Pallas body for BatchNorm under `auto` (PR 25).
+SELECTION_RULES = 2
 
 # Meta key the registry itself adds to a signature traced under a mesh of
 # more than one device (and `--probe --meta mesh_devices=N` passes by hand).
@@ -146,8 +156,11 @@ def config_key() -> Tuple:
 
 def config_fingerprint() -> dict:
     """JSON-able form of `config_key()` for the AOT fingerprint document
-    (`compilation/store.py::build_fingerprint_doc`)."""
-    return {k: mode_for(k)[0] for k in kernel_names()}
+    (`compilation/store.py::build_fingerprint_doc`), with the version of
+    the rules that turn a mode into an implementation: the jit cache dies
+    with the process, a stored executable does not."""
+    return {**{k: mode_for(k)[0] for k in kernel_names()},
+            "selection_rules": SELECTION_RULES}
 
 
 def probe_count() -> int:

@@ -34,8 +34,10 @@ def batchnorm_apply(conf, params, state, x, *, rng=None, train=False, mask=None)
         var = state["var"]
         new_state = state
     # Normalize + affine + activation through the kernel dispatch seam
-    # (kernels/norm_act.py): the XLA fallback is the literal pre-registry
-    # expression; the Pallas path fuses the chain into one VMEM pass.
+    # (kernels/norm_act.py). Under `auto` this is the literal pre-registry
+    # expression on every backend: the statistics above are XLA's, and a
+    # custom call here would be a fusion barrier between the producer, the
+    # reductions and the activation. The Pallas body runs only when forced.
     if conf.lock_gamma_beta or not params:
         gamma, beta = conf.gamma, conf.beta
     else:

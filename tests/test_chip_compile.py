@@ -95,10 +95,67 @@ def test_norm_act_batchnorm_relu_bf16_resnet50(chip):
     vec = chip((1, feats), jnp.bfloat16)
     assert_kernel_compiles(lambda *a: call(*a),
                            chip((rows, feats), jnp.bfloat16), *[vec] * 4)
-    ok, why = na._pallas_available(
-        "tpu", (rows, feats), ("bfloat16",),
-        meta=(("op", "batchnorm"), ("act", "relu")))
+    # The body still compiles, so forcing it works; `auto` declines it for
+    # every BatchNorm, whatever the shape (a fusion barrier, PR 25).
+    meta = (("op", "batchnorm"), ("act", "relu"))
+    ok, why = na._pallas_available("tpu", (rows, feats), ("bfloat16",),
+                                   meta=meta, forced=True)
     assert ok, why
+    ok, why = na._pallas_available("tpu", (rows, feats), ("bfloat16",),
+                                   meta=meta)
+    assert not ok and "fusion barrier" in why, why
+
+
+@pytest.mark.parametrize("hw,feats", [(56, 256), (7, 2048)],
+                         ids=["56x56x256", "7x7x2048"])
+def test_auto_leaves_no_custom_call_between_conv_and_batchnorm(
+        chip, monkeypatch, hw, feats):
+    """conv -> BatchNorm(train) + ReLU -> conv, forward and backward, for
+    the described chip as a TPU process would trace it: under `auto` the
+    compiled program holds no `tpu_custom_call` (the chain is XLA's to
+    fuse), forced it holds the BatchNorm body and moves more bytes."""
+    from deeplearning4j_tpu.kernels import registry
+    from deeplearning4j_tpu.nn.conf.layers import BatchNormalization
+    from deeplearning4j_tpu.nn.layers.normalization import batchnorm_apply
+
+    # The registry asks `jax.default_backend()`, which is the CPU here.
+    monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")
+    conf = BatchNormalization(n_in=feats, n_out=feats, activation="relu")
+    conv = functools.partial(
+        jax.lax.conv_general_dilated, window_strides=(1, 1), padding="SAME",
+        dimension_numbers=("NHWC", "HWIO", "NHWC"))
+
+    def loss(w1, w2, gamma, beta, x):
+        state = {"mean": jnp.zeros((feats,), jnp.float32),
+                 "var": jnp.ones((feats,), jnp.float32)}
+        y, _, _ = batchnorm_apply(conf, {"gamma": gamma, "beta": beta},
+                                  state, conv(x, w1), train=True)
+        return jnp.sum(conv(y, w2).astype(jnp.float32))
+
+    w = chip((1, 1, feats, feats), jnp.bfloat16)
+    vec = chip((feats,), jnp.bfloat16)
+    args = (w, w, vec, vec, chip((32, hw, hw, feats), jnp.bfloat16))
+
+    def compiled():
+        registry.clear_cache()
+        exe = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+            *args).compile()
+        cost = exe.cost_analysis()
+        cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+        took = {r.impl for r in registry.resolved() if r.kernel == "norm_act"}
+        return exe.as_text(), float(cost["bytes accessed"]), took
+
+    try:
+        text, auto_bytes, took = compiled()
+        assert took == {"xla"}
+        assert "tpu_custom_call" not in text
+        monkeypatch.setenv("DL4J_TPU_KERNEL_NORM_ACT", "pallas")
+        text, forced_bytes, took = compiled()
+        assert took == {"pallas"}
+        assert "norm_act_batchnorm" in text and "tpu_custom_call" in text
+        assert auto_bytes < forced_bytes
+    finally:
+        registry.clear_cache()
 
 
 def test_norm_act_layernorm_f32_transformer(chip):
@@ -109,6 +166,10 @@ def test_norm_act_layernorm_f32_transformer(chip):
     vec = chip((1, feats))
     assert_kernel_compiles(lambda *a: call(*a), chip((rows, feats)),
                            vec, vec)
+    ok, why = na._pallas_available(
+        "tpu", (rows, feats), ("float32",),
+        meta=(("op", "layernorm"), ("act", "identity")))
+    assert ok, why
 
 
 def test_norm_act_refuses_features_the_compiler_refuses(chip):

@@ -144,7 +144,8 @@ class TestModeKnobs:
         assert flipped != base
         assert dict(flipped) == {k: "xla" for k in registry.kernel_names()}
         fp = registry.config_fingerprint()
-        assert fp == dict(flipped)
+        assert fp == {**dict(flipped),
+                      "selection_rules": registry.SELECTION_RULES}
         json.dumps(fp)  # must stay JSON-able for the AOT sidecar
 
 
@@ -237,10 +238,116 @@ class TestResolution:
 
 
 # --------------------------------------------------------------------------
+# `auto` and BatchNorm: the layer type decides, not the shape (PR 25)
+
+# ResNet-50 at batch 256: stage 1's output and the last stage's.
+_RESNET50_BN_SHAPES = [(256 * 56 * 56, 256), (256 * 7 * 7, 2048)]
+
+
+def _norm_sig(op, shape, dtype, act):
+    return dict(backend="tpu", shapes=shape, dtypes=(dtype,),
+                meta=(("op", op), ("act", act)))
+
+
+class TestNormActAutoSelection:
+    @pytest.mark.parametrize("act", ["relu", "identity"])
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+    @pytest.mark.parametrize("shape", _RESNET50_BN_SHAPES,
+                             ids=["56x56x256", "7x7x2048"])
+    def test_auto_by_layer_type_at_resnet50_shapes(self, monkeypatch, shape,
+                                                   dtype, act):
+        bn = registry.resolve("norm_act",
+                              **_norm_sig("batchnorm", shape, dtype, act))
+        assert bn.impl == "xla", bn
+        # The winner's reason carries why Pallas said no.
+        assert "pallas unavailable" in bn.reason, bn
+        assert "fusion barrier" in bn.reason, bn
+        ln = registry.resolve("norm_act",
+                              **_norm_sig("layernorm", shape, dtype, act))
+        assert ln.impl == "pallas", ln
+        assert ln.reason == "TPU fused normalize+affine+activation"
+        # The knob still drives the BatchNorm body (parity tests, smoke).
+        monkeypatch.setenv("DL4J_TPU_KERNEL_NORM_ACT", "pallas")
+        registry.clear_cache()
+        forced = registry.resolve(
+            "norm_act", **_norm_sig("batchnorm", shape, dtype, act))
+        assert forced.impl == "pallas", forced
+        assert "forced via DL4J_TPU_KERNEL_NORM_ACT" in forced.reason
+
+    def test_probe_reports_the_refusal(self):
+        selected, rows = registry.probe(
+            "norm_act", **_norm_sig("batchnorm", _RESNET50_BN_SHAPES[0],
+                                    "bfloat16", "relu"))
+        assert selected == "xla"
+        by_impl = {r["impl"]: r for r in rows}
+        assert not by_impl["pallas"]["available"]
+        assert "fusion barrier" in by_impl["pallas"]["reason"]
+        assert by_impl["xla"]["available"]
+
+    def test_batchnorm_refusal_comes_before_the_shape_checks(self):
+        # Not a shape rule: an unaligned BatchNorm is refused for being a
+        # BatchNorm, an unaligned LayerNorm for its shape.
+        ok, why = norm_act._pallas_available(
+            "tpu", (10, 64), ("float32",),
+            meta=(("op", "batchnorm"), ("act", "relu")))
+        assert not ok and "fusion barrier" in why
+        ok, why = norm_act._pallas_available(
+            "tpu", (10, 64), ("float32",),
+            meta=(("op", "layernorm"), ("act", "relu")))
+        assert not ok and "tile-aligned" in why
+
+    def test_batchnorm_seam_traces_no_pallas_call_on_tpu(self, monkeypatch):
+        # What the layer gets: under `auto` with a TPU for a backend the
+        # BatchNorm seam traces the XLA expression, and nothing else.
+        monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")
+        x = jnp.zeros((64, 128), jnp.float32)
+        v = jnp.ones((128,), jnp.float32)
+        bn = jax.make_jaxpr(lambda *a: norm_act.batchnorm_norm_act(
+            *a, 1e-5, "relu"))(x, v, v, v, v)
+        assert "pallas_call" not in str(bn)
+        ref = jax.make_jaxpr(lambda *a: norm_act.batchnorm_xla(
+            *a, 1e-5, "relu"))(x, v, v, v, v)
+        assert str(bn) == str(ref)
+        took = [r for r in registry.resolved() if r.kernel == "norm_act"]
+        assert [r.impl for r in took] == ["xla"], took
+
+
+# --------------------------------------------------------------------------
 # Program identity: jit-cache keys and the AOT fingerprint
 
 
+# What `config_fingerprint()` returned at PR 24 (commit 5644a09) under a
+# default environment, pinned: the modes alone. An AOT artifact written
+# then, under `auto`, was traced with BatchNorm on the Pallas path.
+_PARENTS_KERNELS_FINGERPRINT = {
+    "bottleneck_block": "auto", "flash_attention": "auto",
+    "flash_attention_paged": "auto", "fused_update": "auto",
+    "lstm_cell": "auto", "norm_act": "auto"}
+
+
 class TestProgramIdentity:
+    def test_fingerprint_differs_from_the_parents_rules(self):
+        # The same modes now select another program, so the registry's
+        # part of the fingerprint document must differ from the parent's.
+        fp = registry.config_fingerprint()
+        assert {k: fp[k] for k in registry.kernel_names()} \
+            == _PARENTS_KERNELS_FINGERPRINT
+        assert fp != _PARENTS_KERNELS_FINGERPRINT
+        assert fp["selection_rules"] >= 2
+
+    def test_fingerprint_doc_differs_from_the_parents(self):
+        from deeplearning4j_tpu.compilation.store import (
+            build_fingerprint_doc, fingerprint)
+
+        net = MultiLayerNetwork(_mlp_conf()).init()
+        X = jnp.zeros((6, N_IN), jnp.float32)
+        Y = jnp.zeros((6, N_OUT), jnp.float32)
+        doc = build_fingerprint_doc(net, "train_step", {}, (X, Y))
+        # The parent's document for the same net, batch and environment:
+        # this PR changes no other field of it.
+        parents = dict(doc, kernels=_PARENTS_KERNELS_FINGERPRINT)
+        assert fingerprint(doc) != fingerprint(parents)
+
     def test_fingerprint_doc_invalidates_on_knob_flip(self, monkeypatch):
         from deeplearning4j_tpu.compilation.store import (
             build_fingerprint_doc, fingerprint)
@@ -249,8 +356,9 @@ class TestProgramIdentity:
         X = jnp.zeros((6, N_IN), jnp.float32)
         Y = jnp.zeros((6, N_OUT), jnp.float32)
         doc_auto = build_fingerprint_doc(net, "train_step", {}, (X, Y))
-        assert doc_auto["kernels"] == {k: "auto"
-                                       for k in registry.kernel_names()}
+        assert doc_auto["kernels"] == {
+            **{k: "auto" for k in registry.kernel_names()},
+            "selection_rules": registry.SELECTION_RULES}
         monkeypatch.setenv("DL4J_TPU_KERNELS", "xla")
         doc_xla = build_fingerprint_doc(net, "train_step", {}, (X, Y))
         assert doc_xla["kernels"]["lstm_cell"] == "xla"
