@@ -7,6 +7,9 @@ process that holds the chip (nothing here starts a child):
   device  `jax.devices()`: the platform must be `tpu`, else exit non-zero.
   train   ResNet-50 (224x224, 1000 classes, `mixed_bfloat16`, Nesterov) through
           `ComputationGraph.fit` on a `DeviceCacheDataSetIterator`.
+  sparse  two layers of `zoo.sparse_moe_lm` at Keye-VL-2.0-30B-A3B's widths
+          (4,096 tokens, top-2,048 indexer, 16 of 128 experts held) take
+          train steps through `ComputationGraph.fit` on integer ids.
   serve   `transformer_lm` (d_model 512 x 4 blocks, vocab 8192, T 1024) behind
           `InferenceServer(warmup=True, kv_cache="paged", decode_slots=8)`,
           driven over HTTP from a thread of this process.
@@ -71,6 +74,11 @@ class Sizes:
             self.batch, self.epochs = 8, 3
             self.lm = dict(vocab_size=64, t=32, d_model=64, n_heads=4,
                            n_blocks=1, decode_cache_length=64)
+            self.sparse_lm = dict(
+                vocab_size=48, t=64, d_model=64, n_blocks=2, n_heads=4,
+                n_kv_heads=2, head_dim=16, n_experts=8, top_k=2,
+                expert_hidden=32, experts_held=(0, 2), index_top_k=16,
+                index_n_heads=2, index_head_dim=8)
             self.slots, self.page = 2, 16
             self.prompt_buckets = (8, 32)
             # Like the real ones, these span one to three KV pages.
@@ -80,6 +88,13 @@ class Sizes:
             self.batch, self.epochs = 256, 3
             self.lm = dict(vocab_size=8192, t=1024, d_model=512, n_heads=8,
                            n_blocks=4, decode_cache_length=1024)
+            # Keye-VL-2.0-30B-A3B's widths, two layers, 4,096 tokens: the
+            # indexer selects (4,096 > 2,048), 16 of 128 experts are held.
+            self.sparse_lm = dict(
+                vocab_size=18992, t=4096, d_model=2048, n_blocks=2,
+                n_heads=32, n_kv_heads=4, head_dim=128, n_experts=128,
+                top_k=8, expert_hidden=768, experts_held=(0, 16),
+                index_top_k=2048, index_n_heads=16, index_head_dim=64)
             self.slots, self.page = 8, 64
             self.prompt_buckets = (16, 64, 256)
             # (prompt length, tokens to generate): mixed, one per bucket
@@ -360,6 +375,69 @@ def phase_train(args, size: Sizes):
          tpu_custom_calls_in_step=custom_calls,
          tpu_custom_calls_by_kernel=calls_by_kernel,
          kernel_parity=train_kernel_parity(args.seed))
+
+
+# ---------------------------------------------------------------- sparse lm
+
+
+def phase_sparse_lm(args, size: Sizes):
+    """Two layers of `zoo.sparse_moe_lm` (grouped-query attention under the
+    indexer's selection, dropless routed experts of which a share is held)
+    take train steps through `ComputationGraph.fit` on integer ids."""
+    import jax
+
+    from deeplearning4j_tpu import observability as obs
+    from deeplearning4j_tpu.datasets.dataset import DataSet
+    from deeplearning4j_tpu.datasets.iterators import (
+        DeviceCacheDataSetIterator)
+    from deeplearning4j_tpu.models import zoo
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+
+    kw = dict(size.sparse_lm)
+    vocab, t = kw.pop("vocab_size"), kw["t"]
+    net = ComputationGraph(zoo.sparse_moe_lm(
+        vocab, seed=args.seed, dtype_policy={
+            "name": "mixed_bfloat16", "transfer_dtype": "bfloat16"},
+        **kw)).init()
+    ids = np.random.RandomState(args.seed).randint(
+        0, vocab, (1, t + 1)).astype(np.int32)
+    data = DeviceCacheDataSetIterator(
+        [DataSet(ids[:, :-1], ids[:, 1:], None,
+                 np.full((1, t), 1.0 / t, np.float32))],
+        transfer_dtype=net.dtype_policy.transfer_dtype)
+    frozen = np.asarray(net.params_tree["attn0"]["Wiq"])
+    t0 = time.perf_counter()
+    net.fit(data)
+    losses = [net.score_value]
+    first_fit_s = time.perf_counter() - t0
+    with CompileNames() as compiled:
+        t0 = time.perf_counter()
+        for _ in range(3):
+            net.fit(data)
+        losses.append(net.score_value)
+        steps_s = time.perf_counter() - t0
+    check(not compiled.names, f"compiled after warm-up: {compiled.names}")
+    check(all(np.isfinite(l) for l in losses) and losses[-1] < losses[0],
+          f"sparse lm loss did not fall: {losses}")
+    check(np.array_equal(np.asarray(net.params_tree["attn0"]["Wiq"]), frozen),
+          "the indexer's frozen weights changed")
+    gauges = {
+        name: {c.labels["layer"]: round(c.get(), 4)
+               for c in obs.metrics.get_family(name).children()}
+        for name in ("dl4j_dsa_selected_keys_mean",
+                     "dl4j_moe_pairs_held_share",
+                     "dl4j_moe_expert_load_max_over_mean")}
+    top = kw["index_top_k"]
+    want = float(np.minimum(np.arange(t) + 1, top).mean())
+    check(all(abs(v - want) < 1e-3 * want
+              for v in gauges["dl4j_dsa_selected_keys_mean"].values()),
+          f"selected keys a query {gauges} != {want}")
+    emit("sparse_lm", model=f"sparse_moe_lm d{kw['d_model']}x{kw['n_blocks']}"
+         f" T{t} experts {kw['experts_held'][1]}/{kw['n_experts']}",
+         losses=[round(l, 5) for l in losses],
+         first_fit_seconds_with_compile=round(first_fit_s, 2),
+         seconds_per_step_after=round(steps_s / 3, 4), gauges=gauges,
+         hbm=hbm(jax.devices()[0]))
 
 
 # -------------------------------------------------------------------- serve
@@ -752,6 +830,7 @@ def main(argv=None) -> int:
         phase_four_chips(args, size)
     else:
         phase_train(args, size)
+        phase_sparse_lm(args, size)
         phase_serve(args, size)
     emit("cache", **cache_counts(),
          total_seconds=round(time.perf_counter() - t0, 2))

@@ -157,23 +157,33 @@ def alexnet(n_classes: int = 1000, seed: int = 123, image: int = 224,
 
 def _add_transformer_block(gb, prev, i, d_model, n_heads, *, causal,
                            moe=False, n_experts=4,
-                           decode_cache_length=None):
-    """One pre-LN transformer block: x + Attn(LN(x)); x + FFN(LN(x)).
-    Shared by `transformer_lm` (causal, optional MoE/KV cache) and
-    `transformer_classifier` (bidirectional)."""
+                           decode_cache_length=None, norm=None, attn=None,
+                           ffn=None):
+    """One pre-norm transformer block: x + Attn(N(x)); x + FFN(N(x)).
+    Shared by `transformer_lm` (causal, optional MoE/KV cache),
+    `transformer_classifier` (bidirectional) and `sparse_moe_lm`, which
+    passes its own pieces: `norm()` makes a norm layer (default
+    `LayerNormalization`), `attn` and `ffn` are the block's attention and
+    feed-forward layers."""
+    import copy
+
     from deeplearning4j_tpu.nn.conf.graph import ElementWiseVertex
     from deeplearning4j_tpu.nn.conf.layers import (
         LayerNormalization, MoELayer, SelfAttentionLayer,
     )
 
-    gb.add_layer(f"ln_a{i}", LayerNormalization(), prev)
+    norm = norm or LayerNormalization
+    gb.add_layer(f"ln_a{i}", norm(), prev)
     gb.add_layer(f"attn{i}",
+                 copy.deepcopy(attn) if attn is not None else
                  SelfAttentionLayer(
                      n_out=d_model, n_heads=n_heads, causal=causal,
                      decode_cache_length=decode_cache_length), f"ln_a{i}")
     gb.add_vertex(f"res_a{i}", ElementWiseVertex(op="add"), prev, f"attn{i}")
-    gb.add_layer(f"ln_f{i}", LayerNormalization(), f"res_a{i}")
-    if moe:
+    gb.add_layer(f"ln_f{i}", norm(), f"res_a{i}")
+    if ffn is not None:
+        gb.add_layer(f"ffn{i}", copy.deepcopy(ffn), f"ln_f{i}")
+    elif moe:
         gb.add_layer(f"ffn{i}",
                      MoELayer(n_out=d_model, n_experts=n_experts,
                               expert_hidden=4 * d_model, top_k=2,
@@ -237,6 +247,84 @@ def transformer_lm(vocab_size: int, *, t: int = 64, d_model: int = 64,
     gb.add_layer("out", RnnOutputLayer(n_out=vocab_size,
                                        activation="softmax",
                                        loss_function="mcxent"), "ln_out")
+    gb.set_outputs("out")
+    gb.set_input_types(InputType.recurrent(vocab_size, t))
+    return gb.build()
+
+
+def sparse_moe_lm(vocab_size: int, *, t: int, d_model: int, n_blocks: int,
+                  n_heads: int, n_kv_heads: int, head_dim: int,
+                  n_experts: int, top_k: int, expert_hidden: int,
+                  experts_held=None, index_top_k=None, index_n_heads=None,
+                  index_head_dim=None, rope_theta: float = 1e7,
+                  rms_eps: float = 1e-6, norm_topk_prob: bool = True,
+                  aux_loss_weight: float = 1e-3, lr: float = 1e-5,
+                  adam_mean_decay: float = 0.9, adam_var_decay: float = 0.95,
+                  seed: int = 123, dtype_policy=None):
+    """Decoder-only sparse-attention mixture-of-experts language model
+    (the Qwen3-MoE block with DeepSeek sparse attention's indexer, as
+    Keye-VL-2.0-30B-A3B's language model has it), built from DSL layers and
+    trained by `ComputationGraph.fit` on integer ids `[B, T]` with integer
+    next-token labels `[B, T]`:
+
+        emb -> n_blocks x [ x + Attn(RMSNorm(x)); x + MoE(RMSNorm(x)) ]
+            -> RMSNorm -> untied head (no bias), softmax cross-entropy
+
+    Attn: `n_heads` query heads of `head_dim` over `n_kv_heads` key/value
+    heads, no biases, RMS norm on each q and k head, rotate-half RoPE; with
+    `index_top_k` an indexer of `index_n_heads` x `index_head_dim` keeps
+    that many keys per query, and its leaves are frozen (`nn/layers/dsa.py`).
+    MoE: `n_experts` gated SiLU experts of `expert_hidden`, `top_k` per
+    token, dropless; `experts_held = (first, count)` keeps only those
+    experts' weights here and computes their part of the sum
+    (`parallel/expert.py::moe_ffn_dropless`). `vocab_size` is the number of
+    embedding and head rows held (a slice of the model's vocabulary).
+
+    Device-trace scopes: `dsa.indexer`, `dsa.select`, `dsa.attend`,
+    `moe.route`, `moe.experts`, `lm.head`."""
+    from deeplearning4j_tpu.nn.conf.distributions import NormalDistribution
+    from deeplearning4j_tpu.nn.conf.layers import (
+        EmbeddingLayer, MoELayer, RMSNormalization, SelfAttentionLayer,
+    )
+
+    nb = (NeuralNetConfiguration.builder()
+          .seed(seed).learning_rate(lr).updater(Updater.ADAM)
+          .adam_mean_decay(adam_mean_decay).adam_var_decay(adam_var_decay)
+          .weight_init("xavier"))
+    if dtype_policy is not None:
+        nb = nb.dtype_policy(dtype_policy)
+    gb = (nb.graph_builder()
+          .add_inputs("tokens")
+          # Embedding rows of unit variance (`torch.nn.Embedding`'s default):
+          # a token's own row then outweighs what random attention adds to
+          # every row alike, and a random router spreads its load; Xavier
+          # over [vocab, d_model] rows are a hundredth of that, and every
+          # token then went to the same few experts (PERF.md PR 26).
+          .add_layer("emb", EmbeddingLayer(
+              n_out=d_model, has_bias=False, input_format="ids",
+              activation="identity", weight_init="distribution",
+              dist=NormalDistribution(0.0, 1.0)), "tokens"))
+    attn = SelfAttentionLayer(
+        n_out=d_model, n_heads=n_heads, n_kv_heads=n_kv_heads,
+        head_dim=head_dim, causal=True,
+        rope_theta=float(rope_theta), qk_norm_eps=float(rms_eps),
+        index_top_k=index_top_k, index_n_heads=index_n_heads,
+        index_head_dim=index_head_dim)
+    ffn = MoELayer(
+        n_out=d_model, n_experts=n_experts, expert_hidden=expert_hidden,
+        top_k=top_k, dropless=True,
+        norm_topk_prob=norm_topk_prob, aux_loss_weight=aux_loss_weight,
+        experts_held=None if experts_held is None else tuple(experts_held))
+    prev = "emb"
+    for i in range(n_blocks):
+        prev = _add_transformer_block(
+            gb, prev, i, d_model, n_heads, causal=True,
+            norm=lambda: RMSNormalization(eps=rms_eps), attn=attn, ffn=ffn)
+    gb.add_layer("ln_out", RMSNormalization(eps=rms_eps), prev)
+    gb.add_layer("out", RnnOutputLayer(n_out=vocab_size, has_bias=False,
+                                       activation="softmax",
+                                       loss_function="mcxent",
+                                       scope="lm.head"), "ln_out")
     gb.set_outputs("out")
     gb.set_input_types(InputType.recurrent(vocab_size, t))
     return gb.build()

@@ -107,6 +107,11 @@ class Layer:
     frozen: Optional[bool] = None
     lora_rank: Optional[int] = None
     lora_alpha: Optional[float] = None
+    # `jax.named_scope` around the layer's forward (and, for an output
+    # layer, its loss): the name reaches every HLO operation's `op_name`,
+    # forward and backward, so a device trace can be read by it. None
+    # (the default) adds no scope and keeps the traced program as it was.
+    scope: Optional[str] = None
 
     # ---- shape inference ----
     def get_output_type(self, input_type: InputType) -> InputType:
@@ -134,6 +139,19 @@ class Layer:
 
     def has_params(self) -> bool:
         return bool(self.param_shapes())
+
+    def frozen_param_names(self) -> Sequence[str]:
+        """Params that never train, whatever `frozen` says (`nn/transfer.py`
+        puts them in the frozen spec: no gradient, no updater state)."""
+        return ()
+
+    def full_precision_param_names(self) -> Sequence[str]:
+        """Params used as they are stored, never cast to the dtype policy's
+        compute dtype (`nn/params.py::prep_layer_params`): a norm's scale.
+        bfloat16 has 8 bits, so a scale of 1 + d reads 1 until d passes
+        0.002: under `mixed_bfloat16` a fine-tune's changes to it would
+        never reach the forward pass (PERF.md PR 26)."""
+        return ()
 
     def is_pretrainable(self) -> bool:
         return False
@@ -163,7 +181,8 @@ class Layer:
         names = {f.name for f in dataclasses.fields(cls)}
         kwargs = {k: v for k, v in kwargs.items() if k in names}
         for key in ("kernel_size", "stride", "padding", "pooling_dimensions",
-                    "encoder_layer_sizes", "decoder_layer_sizes"):
+                    "encoder_layer_sizes", "decoder_layer_sizes",
+                    "experts_held"):
             if key in kwargs and isinstance(kwargs[key], list):
                 kwargs[key] = tuple(kwargs[key])
         return cls(**kwargs)
@@ -207,6 +226,14 @@ class DenseLayer(FeedForwardLayer):
 @dataclass
 class BaseOutputLayer(FeedForwardLayer):
     loss_function: Any = LossFunction.MCXENT
+    # None/True: `W` and `b` as ever; False: no bias (an LM head).
+    has_bias: Optional[bool] = None
+
+    def param_shapes(self):
+        shapes = super().param_shapes()
+        if self.has_bias is False:
+            shapes.pop("b", None)
+        return shapes
 
     def to_dict(self):
         d = super().to_dict()
@@ -641,6 +668,29 @@ class LayerNormalization(FeedForwardLayer):
 
 @register_layer
 @dataclass
+class RMSNormalization(FeedForwardLayer):
+    """Root-mean-square norm over the feature axis, `x / rms(x) * gamma`
+    (Zhang & Sennrich 2019): no mean, no shift. Statistics in at least
+    float32 whatever the compute dtype. Works on [B, F] and [B, T, F]."""
+
+    eps: float = 1e-6
+    activation: Any = "identity"
+
+    def get_output_type(self, input_type: InputType) -> InputType:
+        return input_type
+
+    def set_n_in(self, input_type, override):
+        self.n_in = self.n_out = input_type.flat_size()
+
+    def param_shapes(self):
+        return {"gamma": (self.n_out,)}
+
+    def full_precision_param_names(self):
+        return ("gamma",)
+
+
+@register_layer
+@dataclass
 class PositionalEmbeddingLayer(FeedForwardLayer):
     """Learned position table added to a [B, T, F] sequence (GPT-style).
 
@@ -696,8 +746,35 @@ class SelfAttentionLayer(BaseRecurrentLayer):
     # eliminates it everywhere else, so training cost is zero.
     decode_cache_length: Optional[int] = None
     activation: Any = "identity"
+    # Grouped-query / rotary / sparse attention (`nn/layers/dsa.py`). All
+    # None: the layer above, its params and its JSON unchanged. Any set:
+    # `n_heads` query heads of `head_dim` (default n_out / n_heads) over
+    # `n_kv_heads` key/value heads (default n_heads), no biases, rotate-half
+    # RoPE at `rope_theta` in place of a position table, RMS norm over each
+    # q and k head at `qk_norm_eps`.
+    n_kv_heads: Optional[int] = None
+    head_dim: Optional[int] = None
+    rope_theta: Optional[float] = None
+    qk_norm_eps: Optional[float] = None
+    # Learned sparse attention (DeepSeek sparse attention's indexer): with
+    # `index_top_k` set, `index_n_heads` heads of `index_head_dim` over one
+    # shared key head score every earlier position, and each query attends
+    # to its `index_top_k` best keys only (all of them while there are
+    # fewer). The indexer's five leaves never train (`frozen_param_names`).
+    index_top_k: Optional[int] = None
+    index_n_heads: Optional[int] = None
+    index_head_dim: Optional[int] = None
+
+    INDEXER_PARAMS = ("Wiq", "Wik", "Wiw", "gamma_ik", "beta_ik")
+
+    def is_extended(self) -> bool:
+        return any(v is not None for v in (
+            self.n_kv_heads, self.head_dim, self.rope_theta,
+            self.qk_norm_eps, self.index_top_k))
 
     def param_shapes(self):
+        if self.is_extended():
+            return self._extended_param_shapes()
         # No key bias: softmax is invariant to the per-query constant q·kB
         # adds to every score, so kB's true gradient is identically zero —
         # a degenerate parameter that adaptive updaters would random-walk.
@@ -707,6 +784,36 @@ class SelfAttentionLayer(BaseRecurrentLayer):
             "Wv": (self.n_in, self.n_out), "vB": (self.n_out,),
             "Wo": (self.n_out, self.n_out), "oB": (self.n_out,),
         }
+
+    def _extended_param_shapes(self):
+        H = self.n_heads
+        KV = self.n_kv_heads or H
+        Dh = self.head_dim or self.n_out // H
+        if H % KV:
+            raise ValueError(f"n_heads ({H}) must be a multiple of "
+                             f"n_kv_heads ({KV})")
+        shapes = {"Wq": (self.n_in, H * Dh), "Wk": (self.n_in, KV * Dh),
+                  "Wv": (self.n_in, KV * Dh), "Wo": (H * Dh, self.n_out)}
+        if self.qk_norm_eps is not None:
+            shapes.update(gamma_q=(Dh,), gamma_k=(Dh,))
+        if self.index_top_k is not None:
+            IH, ID = self.index_n_heads, self.index_head_dim
+            shapes.update(Wiq=(self.n_in, IH * ID), Wik=(self.n_in, ID),
+                          Wiw=(self.n_in, IH), gamma_ik=(ID,), beta_ik=(ID,))
+        return shapes
+
+    def frozen_param_names(self):
+        return self.INDEXER_PARAMS if self.index_top_k is not None else ()
+
+    def full_precision_param_names(self):
+        # the q/k norms' scales and the indexer's key norm (extended path)
+        return ("gamma_q", "gamma_k", "gamma_ik", "beta_ik")
+
+    def state_shapes(self):
+        # Mean number of keys a query attends to, of the last forward pass
+        # (`dl4j_dsa_selected_keys_mean`, read where the score is read).
+        return {"selected_keys_mean": ()} if self.index_top_k is not None \
+            else {}
 
 
 @register_layer
@@ -732,19 +839,60 @@ class MoELayer(FeedForwardLayer):
     router_jitter: float = 0.0
     aux_loss_weight: float = 1e-2
     activation: Any = "identity"
+    # Dropless top-k routing (`parallel/expert.py::moe_ffn_dropless`): any
+    # `top_k`, no capacity and no dropped token; (token, expert) pairs are
+    # sorted by expert and each matrix is one grouped product. Its experts
+    # are gated, `W_down(silu(W_gate x) * W_up x)` without biases, not the
+    # two-layer ReLU FFN. `norm_topk_prob`: the k gate values are
+    # renormalised to sum to 1. `experts_held = (first, count)`: the layer
+    # holds only those experts' weights, routes over all `n_experts` and
+    # computes its own experts' part of the sum (the other parts are other
+    # devices'; under a `ParallelContext` with an expert axis the layer
+    # holds all experts and each device takes its share by its index).
+    dropless: Optional[bool] = None
+    norm_topk_prob: Optional[bool] = None
+    experts_held: Optional[Tuple[int, int]] = None
+
+    def __post_init__(self):
+        if self.experts_held is not None:
+            self.experts_held = tuple(int(v) for v in self.experts_held)
+        if (self.experts_held is not None
+                or self.norm_topk_prob is not None) and not self.dropless:
+            raise ValueError("norm_topk_prob and experts_held belong to the "
+                             "dropless path: set dropless=True")
 
     def set_n_in(self, input_type: InputType, override: bool) -> None:
         super().set_n_in(input_type, override)
         if not self.expert_hidden:
             self.expert_hidden = 4 * self.n_in
 
+    def held(self) -> Tuple[int, int]:
+        first, count = self.experts_held or (0, self.n_experts)
+        if not (0 <= first and count >= 1
+                and first + count <= self.n_experts):
+            raise ValueError(f"experts_held {self.experts_held} is not "
+                             f"inside 0..{self.n_experts}")
+        return first, count
+
     def param_shapes(self):
         E, h = self.n_experts, self.expert_hidden or 4 * self.n_in
+        if self.dropless:
+            Eh = self.held()[1]
+            return {"gate_w": (self.n_in, E), "w_gate": (Eh, self.n_in, h),
+                    "w_up": (Eh, self.n_in, h), "w_down": (Eh, h, self.n_out)}
         return {
             "gate_w": (self.n_in, E),
             "w1": (E, self.n_in, h), "b_1": (E, h),
             "w2": (E, h, self.n_out), "b_2": (E, self.n_out),
         }
+
+    def state_shapes(self):
+        # Routing statistics of the last forward pass, read where the score
+        # is read (`dl4j_moe_pairs_held_share`,
+        # `dl4j_moe_expert_load_max_over_mean`).
+        if self.dropless:
+            return {"pairs_held_share": (), "expert_load_max_over_mean": ()}
+        return {}
 
 
 @register_layer

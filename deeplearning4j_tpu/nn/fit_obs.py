@@ -21,6 +21,25 @@ import time
 from deeplearning4j_tpu import observability as _obs
 
 
+# Layer statistics that ride in a net's declared state (small arrays the
+# compiled step returns anyway) -> the gauge each is published under, per
+# layer, where the score is read: no fetch of its own per step.
+LAYER_STATS = {
+    "pairs_held_share": (
+        "dl4j_moe_pairs_held_share",
+        "Share of a dropless MoE layer's (token, expert) pairs routed to "
+        "the experts the layer holds (last step read)"),
+    "expert_load_max_over_mean": (
+        "dl4j_moe_expert_load_max_over_mean",
+        "Largest load over mean load among the experts a dropless MoE "
+        "layer holds (last step read)"),
+    "selected_keys_mean": (
+        "dl4j_dsa_selected_keys_mean",
+        "Mean number of keys a query of a sparse attention layer attends "
+        "to (last step read)"),
+}
+
+
 class FitObs:
     """One engine's metric series and spans (see module docstring)."""
 
@@ -61,6 +80,22 @@ class FitObs:
             "batch (input starvation; the device is idle while this "
             "accrues)",
             label_names=("source",)).labels(source=engine)
+
+    def publish_layer_stats(self, net) -> None:
+        """Set the `LAYER_STATS` gauges from `net.state`. Called where the
+        score is read (a sync the caller asked for); a net none of whose
+        layers declares such state pays one attribute lookup."""
+        keys = getattr(net, "_layer_stat_keys", None)
+        if keys is None:
+            # (layer, state key, its gauge's series), resolved once per net
+            keys = net._layer_stat_keys = [
+                (name, key, _obs.metrics.gauge(
+                    *LAYER_STATS[key], label_names=("layer",)).labels(
+                        layer=name))
+                for name, entries in (net.state or {}).items()
+                for key in entries if key in LAYER_STATS]
+        for name, key, series in keys:
+            series.set(float(net.state[name][key]))
 
     def enqueue(self):
         """Span around the call of the compiled step, and nothing else."""

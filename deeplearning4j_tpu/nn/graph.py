@@ -12,6 +12,7 @@ from __future__ import annotations
 import copy
 import math
 import os
+from contextlib import nullcontext
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -55,6 +56,12 @@ from deeplearning4j_tpu.nn.fit_obs import FitObs
 
 # This engine's hot-loop metric series and fit-loop spans.
 _FIT = FitObs("graph")
+
+
+def _layer_scope(layer):
+    """`jax.named_scope(layer.scope)` where a layer names one, else nothing
+    (the traced program of a net without scopes is unchanged)."""
+    return jax.named_scope(layer.scope) if layer.scope else nullcontext()
 
 
 def _as_mds(data, labels=None) -> MultiDataSet:
@@ -113,7 +120,10 @@ class ComputationGraph:
         device (the train loop itself never blocks — important over
         high-latency device transports)."""
         v = self._score
-        return float(v) if v is not None else float("nan")
+        if v is None:
+            return float("nan")
+        _FIT.publish_layer_stats(self)
+        return float(v)
 
     @score_value.setter
     def score_value(self, v):
@@ -147,6 +157,7 @@ class ComputationGraph:
             for name, v in self.layer_vertices.items()
             if v.layer.state_shapes()
         }
+        self._layer_stat_keys = None  # found anew by fit_obs
         self._updaters = {}
         self._schedules = {}
         for name, v in self.layer_vertices.items():
@@ -258,10 +269,11 @@ class ComputationGraph:
                 # policy's compute dtype at use (nn/params.py).
                 lparams = params_mod.prep_layer_params(params.get(name, {}),
                                                        cdt, layer=layer)
-                out, lstate_new, mask = get_impl(layer)(
-                    layer, lparams, state.get(name, {}), x,
-                    rng=lrng, train=train, mask=mask,
-                )
+                with _layer_scope(layer):
+                    out, lstate_new, mask = get_impl(layer)(
+                        layer, lparams, state.get(name, {}), x,
+                        rng=lrng, train=train, mask=mask,
+                    )
                 if lstate_new and "_aux_loss" in lstate_new:
                     # Reserved key: auxiliary loss terms (MoE load balance)
                     # go into the objective, never persist as state.
@@ -269,11 +281,20 @@ class ComputationGraph:
                     aux["aux_loss"] = aux.get("aux_loss", 0.0) + \
                         lstate_new.pop("_aux_loss")
                 if lstate_new:
+                    # `_name`: a layer's by-product (the keys an attention
+                    # layer selected, the experts a token was routed to),
+                    # never state; a collecting pass hands it out as
+                    # `<layer>.<name>`.
                     declared = set(layer.state_shapes())
                     keep = {k: v for k, v in lstate_new.items()
-                            if k in declared or keep_rnn_state}
+                            if not k.startswith("_")
+                            and (k in declared or keep_rnn_state)}
                     if keep:
                         new_state[name] = keep
+                    if collect:
+                        values.update({f"{name}.{k[1:]}": v
+                                       for k, v in lstate_new.items()
+                                       if k.startswith("_")})
                 values[name] = out
                 masks[name] = mask
             elif isinstance(vertex, DuplicateToTimeSeriesVertex):
@@ -535,10 +556,11 @@ class ComputationGraph:
             eb = ebs[i] if ebs is not None else losses_mod.effective_batch_size(y, lmask)
             if i == 0:
                 eb0 = eb
-            total = total + losses_mod.score(
-                layer.loss_function, y, preout, layer.activation, lmask,
-                average=False,
-            ) / eb
+            with _layer_scope(layer):
+                total = total + losses_mod.score(
+                    layer.loss_function, y, preout, layer.activation, lmask,
+                    average=False,
+                ) / eb
             if type(layer).__name__ == "CenterLossOutputLayer":
                 feats = aux[f"center_loss_input:{name}"].astype(self._loss_dtype)
                 centers = aux[f"centers:{name}"]
@@ -1116,6 +1138,51 @@ class ComputationGraph:
             [jnp.asarray(l) for l in mds.labels],
             fmasks, lmasks,
         ))
+
+    def loss_and_gradients(self, data, labels=None, wrt=None, collect=()):
+        """The training objective and its gradients on one batch, at the
+        current parameters and without an update: the very loss the train
+        step differentiates (`_forward_fn` with train=True under the net's
+        dtype policy, `_loss_from_outputs`), for checks against a reference.
+
+        `wrt`: `{layer: [param names]}` to differentiate (default: every
+        trainable leaf). `collect`: names of vertices whose values are
+        returned too (an output layer's value is its pre-activation), or
+        `<layer>.<name>` for a layer's by-product of this very pass
+        (`attn0.selected_keys`, `ffn0.expert_idx`).
+        Returns `(loss, {layer: {name: gradient}}, {vertex: value})`. One
+        compile per call: not for a loop."""
+        mds = _as_mds(data, labels)
+        spec = dict(self._frozen_spec or {})
+        if wrt is None:
+            wrt = {n: [k for k in p if k not in spec.get(n, ())]
+                   for n, p in self.params_tree.items() if p}
+        wrt = {n: list(ks) for n, ks in wrt.items() if ks}
+        for n, ks in wrt.items():
+            frozen = set(ks) & set(spec.get(n, ()))
+            if frozen:
+                raise ValueError(f"{n}: {sorted(frozen)} are frozen")
+
+        def fn(sub, params, state, inputs, labels_, fmasks, lmasks, rng):
+            def loss_fn(sub):
+                p = {n: ({**lp, **sub[n]} if n in sub else lp)
+                     for n, lp in params.items()}
+                outs, _, values, aux, omasks = self._forward_fn(
+                    p, state, inputs, rng, True, fmasks, collect=True)
+                loss, _ = self._loss_from_outputs(p, outs, labels_, lmasks,
+                                                  aux, omasks)
+                return loss, {n: values[n] for n in collect}
+
+            (loss, vals), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(sub)
+            return loss, grads, vals
+
+        sub = {n: {k: self.params_tree[n][k] for k in ks}
+               for n, ks in wrt.items()}
+        return jax.jit(fn)(
+            sub, self.params_tree, self.state, list(mds.features),
+            list(mds.labels), _as_mask_list(mds.features_masks),
+            _as_mask_list(mds.labels_masks), jax.random.PRNGKey(0))
 
     def evaluate(self, iterator, top_n: int = 1):
         from deeplearning4j_tpu.eval.evaluation import Evaluation

@@ -39,6 +39,7 @@ LAYER_IMPLS = {
     "LocalResponseNormalization": convolution.lrn_apply,
     "BatchNormalization": normalization.batchnorm_apply,
     "LayerNormalization": normalization.layernorm_apply,
+    "RMSNormalization": normalization.rmsnorm_apply,
     "PositionalEmbeddingLayer": feedforward.positional_embedding_apply,
     "GravesLSTM": recurrent.graves_lstm_apply,
     "LSTM": recurrent.standard_lstm_apply,

@@ -102,6 +102,12 @@ def self_attention_apply(conf, params, state, x, *, rng=None, train=False,
     from deeplearning4j_tpu.parallel import sequence as seq_mod
 
     x = layer_input_dropout(conf, x, rng, train)
+    if conf.is_extended():
+        # Grouped-query heads, rotary positions, QK-norm, learned sparse
+        # selection: `nn/layers/dsa.py` (no cache, mask or mesh path yet).
+        from deeplearning4j_tpu.nn.layers import dsa
+
+        return dsa.extended_attention_apply(conf, params, state, x, mask)
     B, T, _ = x.shape
     H = conf.n_heads
     if conf.n_out % H:

@@ -1,0 +1,284 @@
+"""Grouped-query attention with rotary positions, QK-norm and a learned
+sparse selection of keys (DeepSeek sparse attention: an indexer beside the
+attention scores every earlier position and each query attends to its
+`index_top_k` best keys only).
+
+`extended_attention_apply` is `SelfAttentionLayer`'s forward when any of
+its extended fields is set (`nn/conf/layers.py`). One sequence [S, n_in]:
+
+    q = h Wq -> [S, H, Dh]    k = h Wk, v = h Wv -> [S, KV, Dh]
+    q, k <- RMSNorm over Dh (gamma_q, gamma_k), then rotate-half RoPE
+    indexer  I(t, s) = sum_j w(t, j) IH^-1/2 relu(qi(t, j) . ki(s)) ID^-1/2
+             with qi = h Wiq, ki = LayerNorm(h Wik), w = h Wiw, RoPE on qi, ki
+    select   S(t) = the index_top_k largest I(t, s) over s <= t, ties to the
+             earlier position; all of s <= t while t < index_top_k
+    attend   o(t, head) = softmax over S(t) of q . k / sqrt(Dh), times v,
+             head `h` reading key/value head `h // (H / KV)`
+    out = o Wo
+
+The selection is exact: the threshold of a row is its `index_top_k`-th
+largest score, found by bisection on the scores' bit patterns (31 passes
+of compare-and-count over the [S, S] scores; a sort of the same rows took
+four times as long on a v5e, PERF.md PR 26), and ties at the threshold are
+broken by position in a branch that runs only when a row has one. S(t)
+is a constant of the backward pass: the indexer's leaves are frozen
+(`SelfAttentionLayer.frozen_param_names`) and nothing differentiates
+through a comparison.
+
+The attention itself is a dense masked pass in blocks of query rows, each
+recomputed in the backward pass (`jax.checkpoint`), so that no [H, S, S]
+tensor is ever kept. A gather of
+`index_top_k` key rows per query would move H/KV times less arithmetic and
+50 times more bytes (PERF.md PR 26).
+
+`jax.named_scope`s `dsa.indexer`, `dsa.select`, `dsa.attend` name the three
+parts in a device trace.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+_NEG = -1e30
+
+
+def rms_norm(x, gamma, eps):
+    """x / rms(x) * gamma over the last axis; statistics in >= float32."""
+    acc = jnp.promote_types(x.dtype, jnp.float32)
+    xf = x.astype(acc)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * gamma.astype(acc)).astype(x.dtype)
+
+
+def layer_norm(x, gamma, beta, eps):
+    acc = jnp.promote_types(x.dtype, jnp.float32)
+    xf = x.astype(acc)
+    mean = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean((xf - mean) ** 2, axis=-1, keepdims=True)
+    y = (xf - mean) * jax.lax.rsqrt(var + eps)
+    return (y * gamma.astype(acc) + beta.astype(acc)).astype(x.dtype)
+
+
+def rope(x, theta: float):
+    """Rotate-half rotary embedding over the last axis of [S, ..., D]
+    (position t on axis 0): pairs (i, i + D/2) turn by t * theta^(-2i/D)."""
+    S, D = x.shape[0], x.shape[-1]
+    acc = jnp.promote_types(x.dtype, jnp.float32)
+    t = jnp.arange(S, dtype=acc)
+    inv = theta ** (-jnp.arange(0, D, 2, dtype=acc) / D)          # [D/2]
+    ang = t[:, None] * inv[None, :]                                # [S, D/2]
+    shape = (S,) + (1,) * (x.ndim - 2) + (D // 2,)
+    cos, sin = jnp.cos(ang).reshape(shape), jnp.sin(ang).reshape(shape)
+    xf = x.astype(acc)
+    x1, x2 = xf[..., : D // 2], xf[..., D // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _row_blocks(S: int, block: int):
+    block = min(block, S)
+    if S % block:
+        raise ValueError(f"sequence length {S} is not a multiple of the "
+                         f"attention row block {block}")
+    return [(i, i + block) for i in range(0, S, block)]
+
+
+def index_scores(qi, ki, w, *, block: int = 512):
+    """I(t, s) for all t, s <= t; -inf above the diagonal. qi: [S, IH, ID],
+    ki: [S, ID], w: [S, IH] -> [S, S] float32 (float64 if the inputs are).
+    Row blocks, so that only [block, IH, S] scores exist at a time."""
+    S, IH, ID = qi.shape
+    acc = jnp.promote_types(qi.dtype, jnp.float32)
+    scale = (IH ** -0.5) * (ID ** -0.5)
+    rows = []
+    for lo, hi in _row_blocks(S, block):
+        s = jnp.einsum("thd,sd->ths", qi[lo:hi], ki[:hi],
+                       preferred_element_type=acc)
+        r = jnp.einsum("ths,th->ts", jax.nn.relu(s),
+                       w[lo:hi].astype(acc)) * scale
+        causal = (jnp.arange(hi)[None, :] <= jnp.arange(lo, hi)[:, None])
+        r = jnp.where(causal, r, -jnp.inf)
+        rows.append(jnp.pad(r, ((0, 0), (0, S - hi)),
+                            constant_values=-jnp.inf))
+    return jnp.concatenate(rows, axis=0)
+
+
+def _sort_keys(scores):
+    """float32 scores -> int32 keys in the same order (-0.0 and 0.0 equal)."""
+    # The keys ARE float32's bit patterns: the index scores' own dtype.
+    u = jax.lax.bitcast_convert_type(
+        (scores + 0.0).astype(jnp.float32),  # tpulint: disable=JX009
+        jnp.int32)
+    return jnp.where(u < 0, u ^ jnp.int32(0x7FFFFFFF), u)
+
+
+def kth_largest_key(keys, k: int):
+    """Per row of int32 `keys` [R, S], the k-th largest (rows are assumed to
+    hold at least k entries that matter): bisection from the sign bit down,
+    one compare-and-count pass over `keys` per bit."""
+    def count_ge(c):
+        return jnp.sum(keys >= c[:, None], axis=1, dtype=jnp.int32)
+
+    nonneg = count_ge(jnp.zeros(keys.shape[0], jnp.int32)) >= k
+    lo = jnp.where(nonneg, jnp.int32(0), jnp.int32(-2 ** 31))
+
+    def body(i, lo):
+        cand = lo + jnp.left_shift(jnp.int32(1), 30 - i)
+        return jnp.where(count_ge(cand) >= k, cand, lo)
+
+    return jax.lax.fori_loop(0, 31, body, lo)
+
+
+def select_top_k(scores, k: int, *, span: int = 2048):
+    """[S, S] index scores (-inf above the diagonal) -> bool mask of S(t):
+    exactly min(t + 1, k) keys a row, the largest scores, ties to the earlier
+    position. Rows below k keep all their keys; the others are searched in
+    spans of rows, each over the keys up to its own end only."""
+    S = scores.shape[0]
+    causal = jnp.arange(S)[None, :] <= jnp.arange(S)[:, None]
+    if S <= k:
+        return causal
+    parts = [causal[:k]]
+    for lo, hi in [(lo, min(lo + span, S)) for lo in range(k, S, span)]:
+        keys = _sort_keys(scores[lo:hi, :hi])
+        thr = kth_largest_key(keys, k)[:, None]
+        above = keys > thr
+        at = keys == thr
+        n_above = jnp.sum(above, axis=1, keepdims=True, dtype=jnp.int32)
+        n_at = jnp.sum(at, axis=1, keepdims=True, dtype=jnp.int32)
+
+        def with_ties(_, above=above, at=at, n_above=n_above):
+            rank = jnp.cumsum(at, axis=1, dtype=jnp.int32)
+            return above | (at & (rank <= k - n_above))
+
+        chosen = jax.lax.cond(jnp.any(n_above + n_at > k), with_ties,
+                              lambda _, above=above, at=at: above | at, None)
+        parts.append(jnp.pad(chosen, ((0, 0), (0, S - hi))))
+    return jnp.concatenate(parts, axis=0)
+
+
+def masked_gqa_attention(q, k, v, keep, *, block: int = 256,
+                         span: int = 2048):
+    """Dense masked attention in row blocks. q: [S, H, Dh]; k, v: [S, KV, Dh];
+    keep: [S, S] bool (row t's keys; implies causality) -> [S, H, Dh].
+    Softmax in >= float32. Blocks of `block` rows, each recomputed in the
+    backward pass; the blocks of one `span` of rows run as a loop over the
+    keys up to the span's end (one compiled body a span: blocks of 256 over
+    exactly their own keys were 13% faster on a v5e and eight times the
+    program, PERF.md PR 26)."""
+    S, H, Dh = q.shape
+    KV = k.shape[1]
+    G = H // KV
+    acc = jnp.promote_types(q.dtype, jnp.float32)
+    scale = Dh ** -0.5
+    qg = jnp.transpose(q.reshape(S, KV, G, Dh), (1, 2, 0, 3))    # [KV,G,S,Dh]
+    kg = jnp.transpose(k, (1, 0, 2))                              # [KV,S,Dh]
+    vg = jnp.transpose(v, (1, 0, 2))
+
+    @jax.checkpoint
+    def rows(qb, kb, vb, mb):
+        s = jnp.einsum("ghtd,gsd->ghts", qb, kb,
+                       preferred_element_type=acc) * scale
+        s = jnp.where(mb[None, None], s, _NEG)
+        m = jnp.max(s, axis=-1, keepdims=True)
+        e = jnp.exp(s - m)
+        den = jnp.sum(e, axis=-1, keepdims=True)
+        o = jnp.einsum("ghts,gsd->ghtd", e.astype(vb.dtype), vb,
+                       preferred_element_type=acc)
+        return (o / den).astype(qb.dtype)
+
+    out = []
+    for lo, hi in _row_blocks(S, span):
+        blocks = _row_blocks(hi - lo, block)
+        n, b = len(blocks), blocks[0][1]
+        qs = jnp.moveaxis(qg[:, :, lo:hi].reshape(KV, G, n, b, Dh), 2, 0)
+        ms = keep[lo:hi, :hi].reshape(n, b, hi)
+        o = jax.lax.map(lambda a: rows(a[0], kg[:, :hi], vg[:, :hi], a[1]),
+                        (qs, ms))                        # [n, KV, G, b, Dh]
+        out.append(jnp.moveaxis(o, 0, 2).reshape(KV, G, hi - lo, Dh))
+    o = jnp.concatenate(out, axis=2)                              # [KV,G,S,Dh]
+    return jnp.transpose(o, (2, 0, 1, 3)).reshape(S, H, Dh)
+
+
+def select_keys(conf, params, h):
+    """h: [S, n_in] -> bool [S, S], the keys each query attends to: the
+    indexer's selection S(t) with `index_top_k`, else the causal triangle
+    (or everything). A constant of the backward pass."""
+    S = h.shape[0]
+    if conf.index_top_k is None:
+        if conf.causal:
+            return jnp.arange(S)[None, :] <= jnp.arange(S)[:, None]
+        return jnp.ones((S, S), bool)
+    IH, ID = conf.index_n_heads, conf.index_head_dim
+    with jax.named_scope("dsa.indexer"):
+        hs = jax.lax.stop_gradient(h)
+        qi = (hs @ params["Wiq"]).reshape(S, IH, ID)
+        ki = layer_norm(hs @ params["Wik"], params["gamma_ik"],
+                        params["beta_ik"], 1e-6)
+        w = hs @ params["Wiw"]
+        if conf.rope_theta is not None:
+            qi, ki = rope(qi, conf.rope_theta), rope(ki, conf.rope_theta)
+        scores = index_scores(qi, ki, w)
+    with jax.named_scope("dsa.select"):
+        return jax.lax.stop_gradient(
+            select_top_k(scores, conf.index_top_k))
+
+
+def _one_sequence(conf, params, h):
+    """h: [S, n_in] -> (out [S, n_out], the keys each query attended to as
+    bool [S, S], their mean number a query or None)."""
+    S = h.shape[0]
+    H = conf.n_heads
+    KV = conf.n_kv_heads or H
+    Dh = conf.head_dim or conf.n_out // H
+
+    q = (h @ params["Wq"]).reshape(S, H, Dh)
+    k = (h @ params["Wk"]).reshape(S, KV, Dh)
+    v = (h @ params["Wv"]).reshape(S, KV, Dh)
+    if conf.qk_norm_eps is not None:
+        q = rms_norm(q, params["gamma_q"], conf.qk_norm_eps)
+        k = rms_norm(k, params["gamma_k"], conf.qk_norm_eps)
+    if conf.rope_theta is not None:
+        q, k = rope(q, conf.rope_theta), rope(k, conf.rope_theta)
+
+    selected = None
+    keep = select_keys(conf, params, h)
+    if conf.index_top_k is not None:
+        selected = jnp.mean(jnp.sum(
+            keep, axis=1, dtype=jnp.promote_types(h.dtype, jnp.float32)))
+    with jax.named_scope("dsa.attend"):
+        o = masked_gqa_attention(q, k, v, keep)
+    return o.reshape(S, H * Dh) @ params["Wo"], keep, selected
+
+
+def extended_attention_apply(conf, params, state, x, mask=None):
+    """x: [B, T, n_in] -> [B, T, n_out]; see the module docstring."""
+    from deeplearning4j_tpu.nn import activations
+
+    if conf.decode_cache_length:
+        raise ValueError(
+            "grouped-query / rotary / sparse attention has no decode cache "
+            "yet (sparse selection inside paged decode: ROADMAP B10)")
+    if mask is not None:
+        raise ValueError("grouped-query / rotary / sparse attention takes "
+                         "no features mask: pad to full length")
+    if conf.index_top_k is not None and not conf.causal:
+        raise ValueError("index_top_k selects among earlier positions: the "
+                         "layer must be causal")
+    if x.shape[0] == 1:
+        out, keep, selected = _one_sequence(conf, params, x[0])
+        out, keep = out[None], keep[None]
+    else:
+        out, keep, selected = jax.vmap(
+            lambda h: _one_sequence(conf, params, h))(x)
+        if selected is not None:
+            selected = jnp.mean(selected)
+    out = activations.resolve(conf.activation)(out)
+    # `_selected_keys` is a by-product: no engine keeps it as state, and
+    # `ComputationGraph.loss_and_gradients(collect=["<layer>.selected_keys"])`
+    # hands it out of the pass that used it.
+    new_state = dict(state, _selected_keys=keep)
+    if selected is not None:
+        new_state["selected_keys_mean"] = selected
+    return out, new_state, mask

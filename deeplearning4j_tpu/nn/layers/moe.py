@@ -36,6 +36,8 @@ def moe_apply(conf, params, state, x, *, rng=None, train=False, mask=None):
     x = layer_input_dropout(conf, x, drop_rng, train)
     lead = x.shape[:-1]
     tokens = x.reshape(-1, x.shape[-1])
+    if conf.dropless:
+        return _dropless_apply(conf, params, state, tokens, lead, mask)
     ffn_params = {
         "gate_w": params["gate_w"],
         "w1": params["w1"], "b1": params["b_1"],
@@ -56,4 +58,34 @@ def moe_apply(conf, params, state, x, *, rng=None, train=False, mask=None):
     out = activations.resolve(conf.activation)(y.reshape(lead + (conf.n_out,)))
     new_state = dict(state)
     new_state["_aux_loss"] = conf.aux_loss_weight * aux
+    return out, new_state, mask
+
+
+def _dropless_apply(conf, params, state, tokens, lead, mask):
+    """Dropless top-k routing (`expert.moe_ffn_dropless`). The layer computes
+    the part of the sum that belongs to the experts it holds
+    (`conf.experts_held`). Under a `ParallelContext` with an expert axis the
+    held tables are split over that axis: each device routes every token
+    over all experts, takes its own experts by its index on the axis, and
+    the parts are summed across the axis."""
+    from deeplearning4j_tpu.parallel import expert as expert_mod
+
+    first, count = conf.held()
+    norm = conf.norm_topk_prob is not False
+    ctx = current_context()
+    kwargs = dict(top_k=conf.top_k, first=first, norm_topk_prob=norm)
+    if (ctx is not None and ctx.expert_axis is not None
+            and ctx.axis_size("expert") > 1):
+        y, aux, stats, idx = expert_mod.moe_ffn_dropless_sharded(
+            params, tokens, ctx.mesh, ctx.expert_axis, **kwargs)
+    else:
+        y, aux, stats, idx = expert_mod.moe_ffn_dropless(params, tokens,
+                                                         **kwargs)
+    out = activations.resolve(conf.activation)(y.reshape(lead + (conf.n_out,)))
+    new_state = dict(state)
+    new_state["_aux_loss"] = conf.aux_loss_weight * aux
+    new_state["pairs_held_share"], new_state["expert_load_max_over_mean"] = stats
+    # A by-product like `_selected_keys` (`nn/layers/dsa.py`): the experts
+    # each token was routed to, for `loss_and_gradients(collect=...)`.
+    new_state["_expert_idx"] = idx.reshape(lead + (conf.top_k,))
     return out, new_state, mask

@@ -60,3 +60,17 @@ def layernorm_apply(conf, params, state, x, *, rng=None, train=False,
     out = _norm_kernel.layernorm_norm_act(x, params["gamma"], params["beta"],
                                           conf.eps, conf.activation)
     return out, state, mask
+
+
+def rmsnorm_apply(conf, params, state, x, *, rng=None, train=False,
+                  mask=None):
+    """RMS norm over the trailing feature axis
+    (`nn/conf/layers.py::RMSNormalization`); plain XLA, so that it fuses
+    into its neighbours (PERF.md PR 25: a custom call here is a barrier)."""
+    from deeplearning4j_tpu.nn import activations
+    from deeplearning4j_tpu.nn.layers import dsa
+    from deeplearning4j_tpu.nn.layers.common import layer_input_dropout
+
+    x = layer_input_dropout(conf, x, rng, train)
+    out = dsa.rms_norm(x, params["gamma"], conf.eps)
+    return activations.resolve(conf.activation)(out), state, mask

@@ -268,7 +268,8 @@ class MultiLayerNetwork:
                 # caller wants rnn hidden state carried (tbptt / rnn_time_step).
                 declared = set(layer.state_shapes())
                 keep = {k: v for k, v in lstate_new.items()
-                        if k in declared or keep_rnn_state}
+                        if not k.startswith("_")  # a by-product, never state
+                        and (k in declared or keep_rnn_state)}
                 if keep:
                     new_state[lk] = keep
             if collect:

@@ -72,6 +72,12 @@ def init_layer_params(conf: Layer, rng: jax.Array, dtype=jnp.float32) -> Dict[st
             params[name] = (jnp.ones(shape, dtype) if name == "gamma"
                             else jnp.zeros(shape, dtype))
             continue
+        if (type(conf).__name__ in ("RMSNormalization", "SelfAttentionLayer")
+                and name.startswith("gamma")):
+            # Norm scales (RMS norm; the attention layer's q/k norms and its
+            # indexer's key norm): ones. Their `beta_*` take the bias path.
+            params[name] = jnp.ones(shape, dtype)
+            continue
         if isinstance(conf, BottleneckBlock) and name.startswith("gamma_"):
             # Per-branch BN scale: ones, like BatchNormalization's default
             # gamma (beta_* lands in the bias path below -> zeros).
@@ -143,7 +149,8 @@ def prep_layer_params(lparams: Dict[str, jnp.ndarray], compute_dtype,
     dequantization: the fused BottleneckBlock keeps int8 weights and
     their `__scale` siblings intact so the Pallas body dequantizes
     in-register — one byte per weight over the wire instead of four.
-    Its XLA fallback applies the exact dequant expression from here."""
+    Its XLA fallback applies the exact dequant expression from here. A
+    layer's `full_precision_param_names()` are handed on as stored."""
     if type(layer).__name__ == "BottleneckBlock":
         out = {}
         for k, a in lparams.items():
@@ -152,9 +159,13 @@ def prep_layer_params(lparams: Dict[str, jnp.ndarray], compute_dtype,
                       and not k.endswith("__scale") else a)
         return out
     out: Dict[str, jnp.ndarray] = {}
+    as_stored = layer.full_precision_param_names() if layer is not None else ()
     for k, a in lparams.items():
         if k.endswith(("__scale", "__lora_a", "__lora_b")):
             continue  # consumed alongside their base tensor
+        if k in as_stored:  # a norm's scale: used at its stored precision
+            out[k] = a
+            continue
         if isinstance(a, dict):  # nested sub-tree (defensive): recurse
             out[k] = prep_layer_params(a, compute_dtype)
             continue

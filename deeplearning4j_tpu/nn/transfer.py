@@ -60,6 +60,12 @@ def frozen_spec(layer_items, params_tree) -> FrozenSpec:
         layer_frozen = bool(getattr(conf, "frozen", None))
         has_lora = bool(getattr(conf, "lora_rank", None) or 0)
         if not layer_frozen and not has_lora:
+            # Leaves a layer never trains (`Layer.frozen_param_names`: the
+            # sparse-attention indexer), the rest of the layer trainable.
+            own = getattr(conf, "frozen_param_names", tuple)()
+            names = frozenset(n for n in own if n in lparams)
+            if names:
+                spec[lk] = names
             continue
         names = set()
         for name in lparams:

@@ -159,6 +159,146 @@ def moe_ffn(params, x, *, capacity_factor: float = 1.25,
     return y
 
 
+def route_top_k(gate_w, x, top_k: int, norm_topk_prob: bool = True):
+    """The router of the dropless path: softmax over all experts' logits in
+    >= float32, the `top_k` largest per token. x: [N, D], gate_w: [D, E] ->
+    `(probs [N, E], gate [N, k], idx [N, k])`, `gate` renormalised to sum
+    to 1 with `norm_topk_prob`."""
+    acc = jnp.promote_types(x.dtype, jnp.float32)
+    logits = x.astype(acc) @ gate_w.astype(acc)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate, idx = jax.lax.top_k(probs, top_k)
+    if norm_topk_prob:
+        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    return probs, gate, idx
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inverse):
+    """`x[perm]` for a permutation `perm` of the rows with `inverse` its
+    inverse: the backward pass is a gather by `inverse` and not the
+    scatter-add a gather transposes to (65,536 rows scattered into a
+    [8192, 2048] f32 took 4.8 ms on a v5e, eight times a step; PERF.md PR
+    26)."""
+    return x[perm]
+
+
+def _permute_rows_fwd(x, perm, inverse):
+    return x[perm], (perm, inverse)
+
+
+def _permute_rows_bwd(res, g):
+    perm, inverse = res
+    return g[inverse], None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def moe_ffn_dropless(params, x, *, top_k: int, first=0,
+                     norm_topk_prob: bool = True):
+    """Dropless top-k routed experts, this holder's part of the sum.
+
+    x: [N, D] tokens. `params["gate_w"]`: [D, E] router over ALL E experts;
+    the expert tables carry a leading [Eh, ...] axis for the Eh experts held
+    here, which are experts `first .. first + Eh - 1` (`first` may be a
+    traced scalar: a device's index on an expert axis). The experts are
+    gated, without biases: `w_down(silu(w_gate x) * w_up x)`.
+
+    Routing: softmax over the E logits in >= float32, the `top_k` largest
+    per token, their values renormalised to sum to 1 with
+    `norm_topk_prob`. No capacity, no dropped token: the (token, expert)
+    pairs are sorted by expert, pairs of experts held elsewhere last, each
+    matrix is one grouped product (`jax.lax.ragged_dot`, which on the TPU
+    skips the rows past the groups: 65,536 rows of which 8,192 are live cost
+    what 10,240 rows do, PERF.md PR 26), and the rows, put back in their
+    tokens' order, are summed over each token's slots weighted by the gate
+    values. The grouped part is recomputed in the backward pass so that no
+    [N * top_k, ...] tensor is kept.
+
+    Returns `(y [N, D_out], aux, stats, idx)`: `aux = E * sum_e f_e * P_e`
+    over all E experts with f_e the pairs routed to e per token and P_e the
+    mean router probability (Qwen3-MoE's `load_balancing_loss_func`);
+    `stats` = `(pairs_held_share, expert_load_max_over_mean)` over the held
+    experts; `idx` [N, top_k] the experts each token was routed to."""
+    N, D = x.shape
+    E = params["gate_w"].shape[1]
+    Eh = params["w_gate"].shape[0]
+    acc = jnp.promote_types(x.dtype, jnp.float32)
+
+    with jax.named_scope("moe.route"):
+        probs, gate, idx = route_top_k(params["gate_w"], x, top_k,
+                                       norm_topk_prob)            # [N, k]
+        counts = jnp.zeros((E,), acc).at[idx.reshape(-1)].add(1.0)
+        aux = E * jnp.sum(counts / N * jnp.mean(probs, axis=0))
+        # Sort the pairs by local expert, those held elsewhere (Eh) last.
+        local = idx.reshape(-1) - first
+        held = (local >= 0) & (local < Eh)
+        local = jnp.where(held, local, Eh).astype(jnp.int32)
+        order = jnp.argsort(local, stable=True)
+        inverse = jnp.argsort(order)
+        group_sizes = jnp.zeros((Eh + 1,), jnp.int32).at[local].add(1)[:Eh]
+        n_held = jnp.sum(group_sizes)
+        live = jnp.arange(N * top_k) < n_held
+        loads = group_sizes.astype(acc)
+        stats = (n_held.astype(acc) / (N * top_k),
+                 jnp.max(loads) / jnp.maximum(jnp.mean(loads), 1e-9))
+
+    @jax.checkpoint
+    def experts(x, gate, tables):
+        # Every pair's row, sorted by expert. Rows past the groups are no
+        # expert's: a grouped product leaves them unwritten (whatever the
+        # buffer held), forward and backward. They enter as zeros, so their
+        # cotangent is dropped on the way back, and they leave as zeros.
+        rows = _permute_rows(jnp.repeat(x, top_k, axis=0), order, inverse)
+        rows = jnp.where(live[:, None], rows, 0)              # [N * k, D]
+        w_gate, w_up, w_down = tables
+        g = jax.lax.ragged_dot(rows, w_gate, group_sizes)
+        u = jax.lax.ragged_dot(rows, w_up, group_sizes)
+        hmid = jnp.where(live[:, None], jax.nn.silu(g) * u, 0)
+        out = jnp.where(live[:, None],
+                        jax.lax.ragged_dot(hmid, w_down, group_sizes), 0)
+        # back to (token, slot) order; a pair of an absent expert is zero
+        out = _permute_rows(out, inverse, order).reshape(N, top_k, -1)
+        return jnp.sum(out.astype(acc) * gate[:, :, None], axis=1)
+
+    with jax.named_scope("moe.experts"):
+        y = experts(x, gate.astype(acc), tuple(
+            params[n].astype(x.dtype) for n in ("w_gate", "w_up", "w_down")))
+    return y.astype(x.dtype), aux, stats, idx
+
+
+def moe_ffn_dropless_sharded(params, x, mesh: Mesh, expert_axis: str, *,
+                             top_k: int, first=0,
+                             norm_topk_prob: bool = True):
+    """`moe_ffn_dropless` with the held expert tables split over
+    `expert_axis`: every device routes every token over all experts, takes
+    its own experts by its index on the axis, and the parts are summed
+    across it. Same returns (the load statistic is the worst device's)."""
+    from jax import shard_map
+
+    n_dev = int(mesh.shape[expert_axis])
+    count = params["w_gate"].shape[0]
+    if count % n_dev:
+        raise ValueError(f"{count} held experts do not split over an "
+                         f"expert axis of {n_dev}")
+
+    def part(tables, tokens):
+        me = jax.lax.axis_index(expert_axis)
+        y, aux, (share, load), idx = moe_ffn_dropless(
+            tables, tokens, top_k=top_k,
+            first=first + me * (count // n_dev),
+            norm_topk_prob=norm_topk_prob)
+        return (jax.lax.psum(y, expert_axis), aux,
+                (jax.lax.psum(share, expert_axis),
+                 jax.lax.pmax(load, expert_axis)), idx)
+
+    specs = {k: (P() if k == "gate_w" else P(expert_axis)) for k in params}
+    return shard_map(part, mesh=mesh, in_specs=(specs, P()),
+                     out_specs=(P(), P(), (P(), P()), P()),
+                     check_vma=False)(params, x)
+
+
 def dense_moe_reference(params, x, *, capacity_factor: float = 1.25,
                         top_k: int = 1):
     """Per-token reference: run every token through ITS expert(s)' FFN

@@ -295,3 +295,51 @@ def test_flash_attention_forward_backward(chip, B, T):
 
     x = chip((B, T, H, D), jnp.bfloat16)
     assert_kernel_compiles(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+
+
+def test_sparse_attention_selection_and_attention_at_published_widths(chip):
+    """`nn/layers/dsa.py` has no Pallas body: the bisection's loop, the
+    tie branch and the recomputed row blocks are XLA's to compile, forward
+    and backward, at Keye-VL-2.0-30B-A3B's head counts (4,096 positions)."""
+    from deeplearning4j_tpu.nn.layers import dsa
+
+    S, H, KV, Dh = 4096, 32, 4, 128
+
+    def loss(q, k, v, scores):
+        keep = dsa.select_top_k(scores, 2048)
+        o = dsa.masked_gqa_attention(q, k, v, keep)
+        return jnp.sum(o.astype(jnp.float32))
+
+    args = (chip((S, H, Dh), jnp.bfloat16), chip((S, KV, Dh), jnp.bfloat16),
+            chip((S, KV, Dh), jnp.bfloat16), chip((S, S), jnp.float32))
+    exe = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*args).compile()
+    text = exe.as_text()
+    assert "tpu_custom_call" not in text
+    assert " while(" in text and " conditional(" in text
+    m = exe.memory_analysis()
+    # no [H, S, S] tensor is kept: 2.1 GB in f32 at these sizes
+    assert m.temp_size_in_bytes < 1.2e9
+
+
+def test_dropless_experts_at_published_widths(chip):
+    """`expert.moe_ffn_dropless`: 8,192 tokens, 128 experts top-8, 16 held;
+    the grouped products compile for the chip as XLA's ragged dot, which the
+    TPU compiler turns into custom calls of its own (not kernels of this
+    repo: `pallas_time_share.fit` counts them all the same)."""
+    from deeplearning4j_tpu.parallel import expert
+
+    N, D, F, E, Eh = 8192, 2048, 768, 128, 16
+
+    def loss(x, gate_w, w_gate, w_up, w_down):
+        y, aux, _, _ = expert.moe_ffn_dropless(
+            {"gate_w": gate_w, "w_gate": w_gate, "w_up": w_up,
+             "w_down": w_down}, x, top_k=8)
+        return jnp.sum(y.astype(jnp.float32)) + aux
+
+    bf = jnp.bfloat16
+    args = (chip((N, D), bf), chip((D, E), bf), chip((Eh, D, F), bf),
+            chip((Eh, D, F), bf), chip((Eh, F, D), bf))
+    exe = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile()
+    assert "ragged-dot" in exe.as_text()
+    assert exe.memory_analysis().temp_size_in_bytes < 2.5e9
