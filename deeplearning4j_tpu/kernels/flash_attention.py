@@ -1,4 +1,15 @@
-"""Pallas flash-attention forward kernel (TPU).
+"""Pallas flash-attention kernels (TPU): three registry names.
+
+`flash_attention` (causal or full, below), `flash_attention_paged` (decode
+against the paged KV pool, further down) and `masked_attention`
+(grouped-query attention under a per-query `[S, S]` key mask, forward and
+backward: the sparse-attention layer's `dsa.attend`, `nn/layers/dsa.py`).
+The last shares the streaming kernels' prefetched lower-triangle sequence
+(`_pair_arrays`) and differs in three ways its own section explains: the
+mask is an operand, the query heads of one KV head share every tile, and
+bf16 operands reach the MXU as bf16. Its XLA fallback, the row blocks of
+`dsa.masked_gqa_attention_xla`, is what `auto` runs off the TPU and what
+the parity tests hold the kernels to.
 
 The reference predates attention entirely; this backs the framework's
 long-context extension (`parallel/sequence.py`). Online-softmax
@@ -734,6 +745,401 @@ def _flash_bwd_stream_bhtd(q, k, v, do, o, lse, causal, scale, block_q,
         name="flash_bwd_dkv_stream",
     )(jnp.asarray(ic), jnp.asarray(jc), k, v, q, do, lse, d_row)
     return dq, dk, dv
+
+
+# ----------------------------------------------------------------- masked
+#
+# Grouped-query attention under a per-query key mask (kernel name
+# ``masked_attention``; `nn/layers/dsa.py::masked_gqa_attention` resolves
+# it). `keep[t, s]` says whether query t attends to key s. Under `causal`
+# the caller vouches that `keep` holds nothing above the diagonal, and the
+# visited (q-block, k-block) pairs are the lower triangle of `_pair_arrays`;
+# without it (a bidirectional layer) they are the whole rectangle. Inside a
+# visited tile the mask alone decides. The mask travels as int8, one
+# `[block_q, block_k]` tile a step.
+#
+# The G = H / KV query heads of one KV head share every step: operands are
+# FOLDED by q-block in XLA (`_fold_heads`: row `(i, g, r)` of `[KV, G*S,
+# Dh]` is position `i*block_q + r` of head `kv*G + g`), so a q block is
+# `[G*block_q, Dh]` rows against one K tile, one V tile and one mask tile,
+# fetched once for the group. Operands reach the MXU in the dtype they
+# arrive in (bf16 under `mixed_bfloat16`); products accumulate in float32,
+# max/exp/sum and the rescales are float32, `p` and `ds` are cast to the
+# operands' dtype for the second product. Masked scores are `_NEG`, as in
+# the XLA body: a row's tiles before its first kept key accumulate weight
+# 1 a key against a running max of `_NEG`, and the first kept key rescales
+# all of that by exp(_NEG - m) = 0 exactly; every row keeps a key.
+#
+# Backward: the two-kernel form above. dq walks the pairs row-major
+# like the forward; dk/dv walk them column-major in the TRANSPOSED
+# orientation (`s^T = k q^T`, keys on sublanes, so `p^T do` and `ds^T q`
+# are plain products and the sum over the group's heads is a loop over
+# its row slices), reading `keep^T` tiles and lse / d_row as lane rows.
+
+
+def _fold_heads(x, KV: int, block_q: int):
+    """[S, H, Dh] -> [KV, G*S, Dh], the G heads of a KV head folded into the
+    rows of each q block."""
+    S, H, Dh = x.shape
+    G = H // KV
+    x = x.reshape(S // block_q, block_q, KV, G, Dh)
+    return jnp.transpose(x, (2, 0, 3, 1, 4)).reshape(KV, G * S, Dh)
+
+
+def _unfold_heads(x, G: int, block_q: int):
+    """Inverse of `_fold_heads`: [KV, G*S, Dh] -> [S, KV*G, Dh]."""
+    KV, GS, Dh = x.shape
+    S = GS // G
+    x = x.reshape(KV, S // block_q, G, block_q, Dh)
+    return jnp.transpose(x, (1, 3, 0, 2, 4)).reshape(S, KV * G, Dh)
+
+
+def _masked_scores(a, b, keep, scale):
+    """Masked scaled scores `a b^T` of one tile in float32. `keep` is the
+    tile's mask, shared by the groups of rows of `a` stacked on it: folded
+    q rows `[G*BQ, Dh]` against `[BQ, BK]`, or in the transposed orientation
+    a k tile against one head's q rows and a `keep^T` tile `[BK, BQ]`."""
+    s = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    return jnp.where(keep, s.reshape(-1, *keep.shape), _NEG).reshape(s.shape)
+
+
+def _lanes(x, n: int):
+    """A lane-replicated `[rows, W]` statistic at width n: whole copies side
+    by side (free on the chip: no cross-lane move), or its first lanes."""
+    W = x.shape[1]
+    if n < W:
+        return x[:, :n]
+    assert n % W == 0, (n, W)
+    return x if n == W else jnp.tile(x, (1, n // W))
+
+
+def _last_k_block(i, block_q, block_k, nk, causal):
+    """The k block that ends q block i's row of `_pair_arrays`' sequence."""
+    if not causal:
+        return nk - 1
+    return jnp.minimum(((i + 1) * block_q - 1) // block_k, nk - 1)
+
+
+def _masked_fwd_kernel(i_ref, j_ref, q_ref, k_ref, v_ref, keep_ref, o_ref,
+                       lse_ref, acc_ref, m_ref, l_ref, *, block_q: int,
+                       block_k: int, nk: int, causal: bool, scale: float):
+    """One streamed step of the online softmax. The running max `m` and sum
+    `l` live lane-replicated (`[rows, 128]`): `l` adds the score tile's
+    128-lane columns elementwise and is summed across lanes once a q block,
+    so a step pays one cross-lane reduction (the max), not two, and no
+    broadcast of a `[rows, 1]` column."""
+    t = pl.program_id(1)
+    i, j = i_ref[t], j_ref[t]
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    v = v_ref[0]
+    s = _masked_scores(q_ref[0], k_ref[0],
+                       keep_ref[...].astype(jnp.int32) != 0, scale)
+    W = m_ref.shape[1]
+    m = m_ref[...]
+    new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - _lanes(new_m, block_k))
+    corr = jnp.exp(m - new_m)
+    l_ref[...] = l_ref[...] * corr + sum(
+        p[:, c:c + W] for c in range(0, block_k, W))
+    acc_ref[...] = acc_ref[...] * _lanes(corr, acc_ref.shape[1]) \
+        + jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+    m_ref[...] = new_m
+
+    @pl.when(j == _last_k_block(i, block_q, block_k, nk, causal))
+    def _():
+        l = jnp.maximum(jnp.sum(l_ref[...], axis=1, keepdims=True), 1e-30)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        lse_ref[0] = m_ref[:, :1] + jnp.log(l)
+
+
+def _masked_dq_kernel(i_ref, j_ref, q_ref, k_ref, v_ref, keep_ref, do_ref,
+                      lse_ref, d_ref, dq_ref, dq_acc, *, block_q: int,
+                      block_k: int, nk: int, causal: bool, scale: float):
+    t = pl.program_id(1)
+    i, j = i_ref[t], j_ref[t]
+
+    @pl.when(j == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    k = k_ref[0]
+    s = _masked_scores(q_ref[0], k, keep_ref[...].astype(jnp.int32) != 0,
+                       scale)
+    p = jnp.exp(s - lse_ref[0])
+    dp = jax.lax.dot_general(do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    ds = p * (dp - d_ref[0])
+    dq_acc[...] += jax.lax.dot_general(
+        ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+    @pl.when(j == _last_k_block(i, block_q, block_k, nk, causal))
+    def _():
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+
+def _masked_dkv_kernel(i_ref, j_ref, k_ref, v_ref, q_ref, do_ref, keep_t_ref,
+                       lse_ref, d_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
+                       block_q: int, block_k: int, nq: int, causal: bool,
+                       scale: float):
+    t = pl.program_id(1)
+    i, j = i_ref[t], j_ref[t]
+
+    @pl.when(i == ((j * block_k) // block_q if causal else 0))
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    k, v = k_ref[0], v_ref[0]
+    keep_t = keep_t_ref[...].astype(jnp.int32) != 0          # [BK, BQ]
+    dk = jnp.zeros(dk_acc.shape, jnp.float32)
+    dv = jnp.zeros(dv_acc.shape, jnp.float32)
+    for g in range(lse_ref.shape[2]):    # the group's heads share k, v, keep
+        rows = slice(g * block_q, (g + 1) * block_q)
+        q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+        p_t = jnp.exp(_masked_scores(k, q, keep_t, scale)
+                      - lse_ref[0, 0, g:g + 1, :])           # [BK, BQ]
+        dv += jax.lax.dot_general(
+            p_t.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dp_t = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+        ds_t = p_t * (dp_t - d_ref[0, 0, g:g + 1, :])
+        dk += jax.lax.dot_general(
+            ds_t.astype(q.dtype), q, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    dk_acc[...] += dk
+    dv_acc[...] += dv
+
+    @pl.when(i == nq - 1)
+    def _():
+        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _masked_specs(block_q, block_k, G, D):
+    """BlockSpecs over the prefetched (i, j) sequence: folded q rows, a k/v
+    tile, a `keep` tile, a `keep^T` tile, a `[.., 1]` column of the folded
+    rows."""
+    rows = G * block_q
+    return dict(
+        q=pl.BlockSpec((1, rows, D), lambda b, t, ii, jj: (b, ii[t], 0)),
+        kv=pl.BlockSpec((1, block_k, D), lambda b, t, ii, jj: (b, jj[t], 0)),
+        keep=pl.BlockSpec((block_q, block_k),
+                          lambda b, t, ii, jj: (ii[t], jj[t])),
+        keep_t=pl.BlockSpec((block_k, block_q),
+                            lambda b, t, ii, jj: (jj[t], ii[t])),
+        col=pl.BlockSpec((1, rows, 1), lambda b, t, ii, jj: (b, ii[t], 0)),
+        row=pl.BlockSpec((1, 1, G, block_q),
+                         lambda b, t, ii, jj: (b, ii[t], 0, 0)))
+
+
+def _masked_fwd(q, k, v, keep8, G, scale, causal, block_q, block_k,
+                interpret):
+    """Folded q `[KV, G*S, D]`, k, v `[KV, S, D]`, keep8 `[S, S]` int8 ->
+    (o folded, lse `[KV, G*S, 1]` float32)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    KV, S, D = k.shape
+    nq, nk = S // block_q, S // block_k
+    ir, jr = _pair_arrays(nq, nk, block_q, block_k, causal, "row")
+    sp = _masked_specs(block_q, block_k, G, D)
+    rows, W = G * block_q, min(128, block_k)   # W: the statistics' lanes
+    return pl.pallas_call(
+        functools.partial(_masked_fwd_kernel, block_q=block_q,
+                          block_k=block_k, nk=nk, causal=causal, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(KV, len(ir)),
+            in_specs=[sp["q"], sp["kv"], sp["kv"], sp["keep"]],
+            out_specs=[sp["q"], sp["col"]],
+            scratch_shapes=[pltpu.VMEM((rows, D), jnp.float32),
+                            pltpu.VMEM((rows, W), jnp.float32),
+                            pltpu.VMEM((rows, W), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct((KV, G * S, 1), jnp.float32)],
+        interpret=interpret,
+        name="masked_attention_fwd",
+    )(jnp.asarray(ir), jnp.asarray(jr), q, k, v, keep8)
+
+
+def _masked_bwd(q, k, v, keep8, do, o, lse, G, scale, causal, block_q,
+                block_k, interpret):
+    """(dq folded, dk, dv) from the folded residuals."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    KV, S, D = k.shape
+    nq, nk = S // block_q, S // block_k
+    rows = G * block_q
+    d_row = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1, keepdims=True)                  # [KV, G*S, 1]
+    sp = _masked_specs(block_q, block_k, G, D)
+
+    ir, jr = _pair_arrays(nq, nk, block_q, block_k, causal, "row")
+    dq = pl.pallas_call(
+        functools.partial(_masked_dq_kernel, block_q=block_q,
+                          block_k=block_k, nk=nk, causal=causal, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(KV, len(ir)),
+            in_specs=[sp["q"], sp["kv"], sp["kv"], sp["keep"], sp["q"],
+                      sp["col"], sp["col"]],
+            out_specs=sp["q"],
+            scratch_shapes=[pltpu.VMEM((rows, D), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=interpret,
+        name="masked_attention_dq",
+    )(jnp.asarray(ir), jnp.asarray(jr), q, k, v, keep8, do, lse, d_row)
+
+    ic, jc = _pair_arrays(nq, nk, block_q, block_k, causal, "col")
+    as_rows = lambda c: c.reshape(KV, nq, G, block_q)
+    dk, dv = pl.pallas_call(
+        functools.partial(_masked_dkv_kernel, block_q=block_q,
+                          block_k=block_k, nq=nq, causal=causal, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(KV, len(ic)),
+            in_specs=[sp["kv"], sp["kv"], sp["q"], sp["q"], sp["keep_t"],
+                      sp["row"], sp["row"]],
+            out_specs=[sp["kv"], sp["kv"]],
+            scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
+                            pltpu.VMEM((block_k, D), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        interpret=interpret,
+        name="masked_attention_dkv",
+    )(jnp.asarray(ic), jnp.asarray(jc), k, v, q, do, keep8.T,
+      as_rows(lse), as_rows(d_row))
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _masked_attention_pallas(q, k, v, keep, causal: bool, block_q: int,
+                             block_k: int, interpret: bool = False):
+    """q: [S, H, Dh]; k, v: [S, KV, Dh]; keep: [S, S] bool, row t's keys,
+    none above the diagonal where `causal` -> [S, H, Dh]. S must be a
+    multiple of both blocks (`masked_attention` asks the registry first)."""
+    return _masked_attention_fwd(q, k, v, keep, causal, block_q, block_k,
+                                 interpret)[0]
+
+
+def _masked_operands(q, k, v, keep, block_q):
+    """What the kernels take: (q folded, k and v `[KV, S, Dh]`, keep as
+    int8), then G and the scale."""
+    KV = k.shape[1]
+    return ((_fold_heads(q, KV, block_q), jnp.swapaxes(k, 0, 1),
+             jnp.swapaxes(v, 0, 1), keep.astype(jnp.int8)),
+            q.shape[1] // KV, q.shape[2] ** -0.5)
+
+
+def _masked_attention_fwd(q, k, v, keep, causal, block_q, block_k, interpret):
+    _require_block_multiple(q.shape[0], block_q, block_k)
+    operands, G, scale = _masked_operands(q, k, v, keep, block_q)
+    o, lse = _masked_fwd(*operands, G, scale, causal, block_q, block_k,
+                         interpret)
+    return _unfold_heads(o, G, block_q), (q, k, v, keep, o, lse)
+
+
+def _masked_attention_bwd(causal, block_q, block_k, interpret, res, g):
+    q, k, v, keep, o, lse = res
+    operands, G, scale = _masked_operands(q, k, v, keep, block_q)
+    dq, dk, dv = _masked_bwd(
+        *operands, _fold_heads(g, k.shape[1], block_q), o, lse, G, scale,
+        causal, block_q, block_k, interpret)
+    return (_unfold_heads(dq, G, block_q), jnp.swapaxes(dk, 0, 1),
+            jnp.swapaxes(dv, 0, 1), None)
+
+
+_masked_attention_pallas.defvjp(_masked_attention_fwd, _masked_attention_bwd)
+
+
+# What the hungriest of the three kernels (dq) may hold in VMEM, by
+# `_masked_vmem_bytes`' count: the 16 MiB scoped default recorded at
+# `_RESIDENT_KV_LIMIT`. The count is set from what the chip's compiler
+# accepts (`tests/test_chip_compile.py`): bf16 and f32 at Dh 128 pass with
+# a `[1024, 1024]` score tile, f32 at Dh 256 passes at 512 keys and is
+# refused at 1,024, a `[4096, 512]` tile is refused.
+_MASKED_VMEM_LIMIT = 16 * 1024 * 1024
+
+
+def _masked_vmem_bytes(G, block_q, block_k, Dh, itemsize):
+    rows, lanes = G * block_q, -(-Dh // 128) * 128
+    blocks = 2 * (3 * rows * lanes * itemsize      # q, do, dq: double buffers
+                  + 2 * block_k * lanes * itemsize            # k, v
+                  + block_q * block_k                         # int8 mask
+                  + 2 * rows * 128 * 4)       # lse, d_row: `[rows, 1]` padded
+    # the accumulator, and two float32 score tiles live at a time
+    return blocks + rows * lanes * 4 + 2 * rows * block_k * 4
+
+
+def masked_blocks(S: int, G: int, Dh: int, itemsize: int):
+    """(block_q, block_k) for a sequence of S positions, G query heads a KV
+    head and heads of Dh, or None where S is off the 128-lane tile or the
+    group too large: `G * block_q` rows of about 1,024 (the MXU streams them
+    against one K tile) against up to 512 keys, fewer where VMEM asks
+    (1,024 keys a step measured no faster on `keye_vl2_30b_a3b`'s layer,
+    PERF.md PR 27)."""
+    fit = lambda sizes: next((b for b in sizes if S % b == 0), None)
+    block_q = fit([b for b in (1024, 512, 256, 128) if G * b <= 1024]
+                  or [128])
+    if block_q is None:
+        return None
+    block_k = fit([b for b in (512, 256, 128)
+                   if _masked_vmem_bytes(G, block_q, b, Dh, itemsize)
+                   <= _MASKED_VMEM_LIMIT])
+    return None if block_k is None else (block_q, block_k)
+
+
+def masked_attention(q, k, v, keep, causal: bool = True):
+    """The Pallas body of `masked_attention` at the blocks `masked_blocks`
+    chooses; the caller has resolved the registry."""
+    block_q, block_k = masked_blocks(q.shape[0], q.shape[1] // k.shape[1],
+                                     q.shape[2], q.dtype.itemsize)
+    return _masked_attention_pallas(q, k, v, keep, causal, block_q, block_k,
+                                    _registry.interpret_mode())
+
+
+def _masked_pallas_available(backend, shapes, dtypes, meta=(), forced=False):
+    """`shapes` is `(S, H, Dh, KV)`."""
+    if backend != "tpu" and not forced:
+        return False, ("auto off-TPU keeps the XLA row-block body (interpret "
+                       "mode is for the forced parity tests)")
+    if dtypes and dtypes[0] not in ("bfloat16", "float32"):
+        return False, (f"dtype {dtypes[0]}: the kernel takes bfloat16 or "
+                       "float32 operands (Mosaic has no 64-bit arithmetic)")
+    if shapes:
+        S, H, Dh, KV = shapes
+        if H % KV:
+            return False, f"H={H} is not a multiple of KV={KV}"
+        if masked_blocks(S, H // KV, Dh, 2 if dtypes == ("bfloat16",)
+                         else 4) is None:
+            return False, (f"S={S}, G={H // KV}, Dh={Dh}: S is not a "
+                           "multiple of a 128-lane block, or the group's "
+                           "blocks outgrow VMEM")
+        if backend == "tpu" and Dh != 64 and Dh % 128:
+            return False, (f"Dh={Dh}: the widths the described-chip compile "
+                           "covers are 64 (half the lanes idle) and "
+                           "multiples of 128")
+    if backend == "tpu":
+        return True, ("TPU masked flash kernel (per-query int8 mask tiles, "
+                      "the group's heads folded into the q rows)")
+    return True, "interpret mode off-TPU (float-close parity tests only)"
+
+
+def _masked_xla_available(backend, shapes, dtypes, meta=(), forced=False):
+    return True, ("XLA row blocks under jax.checkpoint "
+                  "(nn/layers/dsa.py: the parity reference)")
+
+
+_registry.register("masked_attention", [
+    _registry.KernelImpl("pallas", _masked_pallas_available),
+    _registry.KernelImpl("xla", _masked_xla_available),
+])
 
 
 # ------------------------------------------------------------------ paged

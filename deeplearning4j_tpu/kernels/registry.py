@@ -53,8 +53,10 @@ MODES = ("auto", "xla", "pallas")
 # `config_fingerprint()`. Bump it whenever an `is_available` rule changes
 # which body a signature gets, so that no AOT artifact written under the
 # old rules is loaded for the new program. 1 (implicit, no key): PR 10-24. 2: `norm_act` refuses
-# the Pallas body for BatchNorm under `auto` (PR 25).
-SELECTION_RULES = 2
+# the Pallas body for BatchNorm under `auto` (PR 25). 3: `masked_attention`
+# exists, and on a TPU `nn/layers/dsa.py`'s attention resolves its Pallas
+# body where it ran XLA row blocks (PR 27).
+SELECTION_RULES = 3
 
 # Meta key the registry itself adds to a signature traced under a mesh of
 # more than one device (and `--probe --meta mesh_devices=N` passes by hand).
@@ -73,6 +75,10 @@ KERNEL_MODULES = {
     # same module; auto off-TPU resolves to the XLA dense-gather
     # composite, which is bit-identical to the dense stepper.
     "flash_attention_paged": "deeplearning4j_tpu.kernels.flash_attention",
+    # Grouped-query attention under a per-query key mask (PR 27), the
+    # sparse-attention layer's `dsa.attend`: same module again; auto
+    # off-TPU resolves the XLA row blocks of `nn/layers/dsa.py`.
+    "masked_attention": "deeplearning4j_tpu.kernels.flash_attention",
 }
 
 

@@ -25,11 +25,19 @@ is a constant of the backward pass: the indexer's leaves are frozen
 (`SelfAttentionLayer.frozen_param_names`) and nothing differentiates
 through a comparison.
 
-The attention itself is a dense masked pass in blocks of query rows, each
-recomputed in the backward pass (`jax.checkpoint`), so that no [H, S, S]
-tensor is ever kept. A gather of
-`index_top_k` key rows per query would move H/KV times less arithmetic and
-50 times more bytes (PERF.md PR 26).
+The attention itself is a dense causal pass under that mask, and no
+[H, S, S] tensor is ever kept. `masked_gqa_attention` resolves the kernel
+registry's `masked_attention`: on a TPU, at tile-multiple shapes in bf16 or
+f32, one Pallas flash body forward and backward that takes the [S, S]
+selection as an int8 operand and shares each K, V and mask tile among the
+H/KV query heads of a KV head (`kernels/flash_attention.py`, PERF.md PR 27);
+anywhere else (the CPU, float64, an S off the tile, `DL4J_TPU_KERNELS=xla`)
+XLA row blocks, each recomputed in the backward pass (`jax.checkpoint`),
+which are also the kernel's parity reference. Both take the layer's
+`causal`: with it no tile or row block past a row's own position is read,
+without it (a bidirectional layer, whose mask is all ones) every key is.
+A gather of `index_top_k` key rows per query would move H/KV times less
+arithmetic and 50 times more bytes (PERF.md PR 26).
 
 `jax.named_scope`s `dsa.indexer`, `dsa.select`, `dsa.attend` name the three
 parts in a device trace.
@@ -158,15 +166,30 @@ def select_top_k(scores, k: int, *, span: int = 2048):
     return jnp.concatenate(parts, axis=0)
 
 
-def masked_gqa_attention(q, k, v, keep, *, block: int = 256,
-                         span: int = 2048):
-    """Dense masked attention in row blocks. q: [S, H, Dh]; k, v: [S, KV, Dh];
-    keep: [S, S] bool (row t's keys; implies causality) -> [S, H, Dh].
-    Softmax in >= float32. Blocks of `block` rows, each recomputed in the
-    backward pass; the blocks of one `span` of rows run as a loop over the
-    keys up to the span's end (one compiled body a span: blocks of 256 over
-    exactly their own keys were 13% faster on a v5e and eight times the
-    program, PERF.md PR 26)."""
+def masked_gqa_attention(q, k, v, keep, causal: bool = True):
+    """Dense masked attention. q: [S, H, Dh]; k, v: [S, KV, Dh]; keep: [S, S]
+    bool (row t's keys) -> [S, H, Dh]. `causal` says that `keep` holds
+    nothing above the diagonal, so no row block reads the keys past its own
+    end. Softmax in >= float32. The registry's `masked_attention` decides
+    between the Pallas flash body and `masked_gqa_attention_xla`."""
+    from deeplearning4j_tpu.kernels import flash_attention, registry
+
+    res = registry.resolve(
+        "masked_attention", dtypes=(str(q.dtype),),
+        shapes=tuple(int(d) for d in q.shape) + (int(k.shape[1]),))
+    if res.impl == "pallas":
+        return flash_attention.masked_attention(q, k, v, keep, causal)
+    return masked_gqa_attention_xla(q, k, v, keep, causal)
+
+
+def masked_gqa_attention_xla(q, k, v, keep, causal: bool = True, *,
+                             block: int = 256, span: int = 2048):
+    """`masked_gqa_attention` in XLA row blocks: blocks of `block` rows, each
+    recomputed in the backward pass; the blocks of one `span` of rows run as
+    a loop over the keys up to the span's end, or over all of them where
+    not `causal` (one compiled body a span: blocks of 256 over exactly their
+    own keys were 13% faster on a v5e and eight times the program, PERF.md
+    PR 26)."""
     S, H, Dh = q.shape
     KV = k.shape[1]
     G = H // KV
@@ -192,10 +215,12 @@ def masked_gqa_attention(q, k, v, keep, *, block: int = 256,
     for lo, hi in _row_blocks(S, span):
         blocks = _row_blocks(hi - lo, block)
         n, b = len(blocks), blocks[0][1]
+        keys = hi if causal else S
         qs = jnp.moveaxis(qg[:, :, lo:hi].reshape(KV, G, n, b, Dh), 2, 0)
-        ms = keep[lo:hi, :hi].reshape(n, b, hi)
-        o = jax.lax.map(lambda a: rows(a[0], kg[:, :hi], vg[:, :hi], a[1]),
-                        (qs, ms))                        # [n, KV, G, b, Dh]
+        ms = keep[lo:hi, :keys].reshape(n, b, keys)
+        o = jax.lax.map(
+            lambda a: rows(a[0], kg[:, :keys], vg[:, :keys], a[1]),
+            (qs, ms))                                    # [n, KV, G, b, Dh]
         out.append(jnp.moveaxis(o, 0, 2).reshape(KV, G, hi - lo, Dh))
     o = jnp.concatenate(out, axis=2)                              # [KV,G,S,Dh]
     return jnp.transpose(o, (2, 0, 1, 3)).reshape(S, H, Dh)
@@ -248,7 +273,7 @@ def _one_sequence(conf, params, h):
         selected = jnp.mean(jnp.sum(
             keep, axis=1, dtype=jnp.promote_types(h.dtype, jnp.float32)))
     with jax.named_scope("dsa.attend"):
-        o = masked_gqa_attention(q, k, v, keep)
+        o = masked_gqa_attention(q, k, v, keep, conf.causal)
     return o.reshape(S, H * Dh) @ params["Wo"], keep, selected
 
 

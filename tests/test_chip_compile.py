@@ -298,16 +298,18 @@ def test_flash_attention_forward_backward(chip, B, T):
 
 
 def test_sparse_attention_selection_and_attention_at_published_widths(chip):
-    """`nn/layers/dsa.py` has no Pallas body: the bisection's loop, the
-    tie branch and the recomputed row blocks are XLA's to compile, forward
-    and backward, at Keye-VL-2.0-30B-A3B's head counts (4,096 positions)."""
+    """The XLA half of `nn/layers/dsa.py`: the bisection's loop, the tie
+    branch and the recomputed row blocks (the body `masked_attention`
+    resolves off the TPU, and the Pallas body's parity reference) compile
+    forward and backward at Keye-VL-2.0-30B-A3B's head counts (4,096
+    positions)."""
     from deeplearning4j_tpu.nn.layers import dsa
 
     S, H, KV, Dh = 4096, 32, 4, 128
 
     def loss(q, k, v, scores):
         keep = dsa.select_top_k(scores, 2048)
-        o = dsa.masked_gqa_attention(q, k, v, keep)
+        o = dsa.masked_gqa_attention_xla(q, k, v, keep)
         return jnp.sum(o.astype(jnp.float32))
 
     args = (chip((S, H, Dh), jnp.bfloat16), chip((S, KV, Dh), jnp.bfloat16),
@@ -319,6 +321,123 @@ def test_sparse_attention_selection_and_attention_at_published_widths(chip):
     m = exe.memory_analysis()
     # no [H, S, S] tensor is kept: 2.1 GB in f32 at these sizes
     assert m.temp_size_in_bytes < 1.2e9
+
+
+@pytest.mark.parametrize("S,H,KV,Dh,dtype,causal", [
+    (8192, 32, 4, 128, jnp.bfloat16, True),
+    (8192, 32, 4, 128, jnp.float32, True),
+    (8192, 32, 4, 256, jnp.float32, True),
+    (2048, 8, 8, 64, jnp.bfloat16, True),
+    (2048, 16, 1, 256, jnp.bfloat16, True),
+    (4096, 32, 4, 128, jnp.bfloat16, False)],
+    ids=["published-bf16", "published-f32", "f32-Dh256", "G1-Dh64",
+         "G16-Dh256", "bidirectional"])
+def test_masked_attention_forward_backward(chip, S, H, KV, Dh, dtype, causal):
+    """The three kernels of `masked_attention` (forward, dq, dk/dv) at the
+    blocks the wrapper chooses, for every shape the registry answers yes
+    to here; the first is `keye_vl2_30b_a3b.fit_seq8k`'s layer, the last
+    walks every tile of a layer that is not causal."""
+    ok, why = fa._masked_pallas_available(
+        "tpu", (S, H, Dh, KV), (jnp.dtype(dtype).name,))
+    assert ok, why
+    block_q, block_k = fa.masked_blocks(S, H // KV, Dh,
+                                        jnp.dtype(dtype).itemsize)
+
+    def loss(q, k, v, keep):
+        o = fa._masked_attention_pallas(q, k, v, keep, causal, block_q,
+                                        block_k, False)
+        return jnp.sum(o.astype(jnp.float32))
+
+    exe = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        chip((S, H, Dh), dtype), chip((S, KV, Dh), dtype),
+        chip((S, KV, Dh), dtype), chip((S, S), jnp.bool_)).compile()
+    text = exe.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    # no [H, S, S] tensor: the int8 mask, its transpose and the folded
+    # copies of q, o and their cotangents are the largest
+    assert exe.memory_analysis().temp_size_in_bytes < 0.9e9
+
+
+def test_masked_attention_refuses_what_the_compiler_refuses(chip):
+    """What `_masked_vmem_bytes` counts over 16 MiB the compiler refuses: a
+    score tile twice the wrapper's, and float32 heads of 256 at the 1,024
+    keys that heads of 128 take. float64 and an S off the tile never reach
+    the compiler (`tests/test_masked_attention.py` has the rest)."""
+    S, H, KV = 8192, 32, 4
+    for Dh, dtype, block_q, block_k in ((128, jnp.bfloat16, 512, 512),
+                                        (256, jnp.float32, 128, 1024)):
+        assert fa._masked_vmem_bytes(
+            H // KV, block_q, block_k, Dh, jnp.dtype(dtype).itemsize) \
+            > fa._MASKED_VMEM_LIMIT
+
+        def loss(q, k, v, keep):
+            return jnp.sum(fa._masked_attention_pallas(
+                q, k, v, keep, True, block_q, block_k,
+                False).astype(jnp.float32))
+
+        with pytest.raises(Exception, match="vmem"):
+            jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+                chip((S, H, Dh), dtype), chip((S, KV, Dh), dtype),
+                chip((S, KV, Dh), dtype), chip((S, S), jnp.bool_)).compile()
+    Dh = 128
+    for shapes, dtype in (((S, H, Dh, KV), "float64"),
+                          ((S + 8, H, Dh, KV), "bfloat16")):
+        ok, _ = fa._masked_pallas_available("tpu", shapes, (dtype,))
+        assert not ok
+
+
+def test_sparse_attention_layer_runs_three_kernels_under_its_scope(
+        chip, monkeypatch):
+    """`SelfAttentionLayer`'s extended forward at the cell's widths, traced
+    as a TPU process would trace it: `masked_attention` resolves `pallas`
+    (and the dispatch counter says so), and the compiled gradient holds the
+    forward and both backward kernels with `dsa.attend` in their `op_name`,
+    which is how `benchmark/harness/scope_time.py` finds their time."""
+    import re
+
+    from deeplearning4j_tpu import observability as obs
+    from deeplearning4j_tpu.kernels import registry
+    from deeplearning4j_tpu.nn.conf.layers import SelfAttentionLayer
+    from deeplearning4j_tpu.nn.layers import dsa
+
+    # `jax.default_backend()` is the CPU here: steer the registry, and with
+    # it `interpret_mode()`, from the test.
+    monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")
+    monkeypatch.delenv("DL4J_TPU_KERNELS", raising=False)
+    monkeypatch.delenv("DL4J_TPU_KERNEL_MASKED_ATTENTION", raising=False)
+    registry.clear_cache()
+    S, D = 8192, 2048
+    conf = SelfAttentionLayer(
+        n_in=D, n_out=D, n_heads=32, n_kv_heads=4, head_dim=128,
+        rope_theta=1e7, qk_norm_eps=1e-6, causal=True, index_top_k=2048,
+        index_n_heads=16, index_head_dim=64)
+    bf = jnp.bfloat16
+    shapes = conf.param_shapes()
+    trained = [n for n in shapes if n not in conf.frozen_param_names()]
+
+    def loss(train, frozen, x):
+        out, _, _ = dsa.extended_attention_apply(
+            conf, {**train, **frozen}, {}, x)
+        return jnp.sum(out.astype(jnp.float32))
+
+    def count(impl):
+        fam = obs.metrics.get_family("dl4j_kernel_dispatch_total")
+        return sum(c.get() for c in fam.children() if c.labels == {
+            "kernel": "masked_attention", "impl": impl})
+
+    before = count("pallas"), count("xla")
+    exe = jax.jit(jax.grad(loss)).lower(
+        {n: chip(shapes[n], bf) for n in trained},
+        {n: chip(shapes[n], bf) for n in conf.frozen_param_names()},
+        chip((1, S, D), bf)).compile()
+    registry.clear_cache()
+    assert (count("pallas"), count("xla")) == (before[0] + 1, before[1])
+    calls = [m for m in re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"',
+        exe.as_text()) if "masked_attention" in m]
+    assert len(calls) == 3, calls
+    assert all("dsa.attend" in c for c in calls), calls
+    assert sum("transpose(" in c for c in calls) == 2, calls
 
 
 def test_dropless_experts_at_published_widths(chip):

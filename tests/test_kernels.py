@@ -330,10 +330,13 @@ class TestProgramIdentity:
         # The same modes now select another program, so the registry's
         # part of the fingerprint document must differ from the parent's.
         fp = registry.config_fingerprint()
-        assert {k: fp[k] for k in registry.kernel_names()} \
+        assert {k: fp[k] for k in _PARENTS_KERNELS_FINGERPRINT} \
             == _PARENTS_KERNELS_FINGERPRINT
         assert fp != _PARENTS_KERNELS_FINGERPRINT
-        assert fp["selection_rules"] >= 2
+        # 3 since PR 27: `masked_attention` joined the names, and the
+        # sparse-attention layer's step is another program on a TPU.
+        assert fp["selection_rules"] >= 3
+        assert fp["masked_attention"] == "auto"
 
     def test_fingerprint_doc_differs_from_the_parents(self):
         from deeplearning4j_tpu.compilation.store import (
@@ -460,7 +463,8 @@ class TestDispatchMetric:
 # parity test here (or, for flash_attention, in test_flash_attention.py;
 # for bottleneck_block, in test_bottleneck_block.py).
 PARITY_COVERED = {"lstm_cell", "fused_update", "norm_act", "flash_attention",
-                  "flash_attention_paged", "bottleneck_block"}
+                  "flash_attention_paged", "bottleneck_block",
+                  "masked_attention"}      # test_masked_attention.py
 
 
 def test_every_kernel_has_parity_coverage():
