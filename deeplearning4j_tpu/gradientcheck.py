@@ -21,36 +21,14 @@ import numpy as np
 from deeplearning4j_tpu.datasets.dataset import DataSet, MultiDataSet
 
 
-def _score_fn_multilayer(net, ds: DataSet):
-    x = jnp.asarray(ds.features)
-    y = jnp.asarray(ds.labels)
-    fmask = None if ds.features_mask is None else jnp.asarray(ds.features_mask)
-    lmask = None if ds.labels_mask is None else jnp.asarray(ds.labels_mask)
+def _score_fn(net, ds):
+    """The net's loss on one batch as a function of its parameters
+    (deterministic forward: no dropout, running BatchNorm statistics)."""
+    batch = net._batch(net._as_data(ds))
     state = net.state
 
     def score(params):
-        preout, _, _, aux = net._forward_fn(params, state, x, None, False, fmask)
-        loss, _ = net._loss_from_preout(params, preout, y, lmask, aux)
-        return loss
-
-    return score
-
-
-def _score_fn_graph(net, mds: MultiDataSet):
-    inputs = [jnp.asarray(f) for f in mds.features]
-    labels = [jnp.asarray(l) for l in mds.labels]
-    fmasks = None
-    if mds.features_masks is not None and any(m is not None for m in mds.features_masks):
-        fmasks = [None if m is None else jnp.asarray(m) for m in mds.features_masks]
-    lmasks = None
-    if mds.labels_masks is not None and any(m is not None for m in mds.labels_masks):
-        lmasks = [None if m is None else jnp.asarray(m) for m in mds.labels_masks]
-    state = net.state
-
-    def score(params):
-        outs, _, aux, omasks = net._forward_fn(params, state, inputs, None, False, fmasks)
-        loss, _ = net._loss_from_outputs(params, outs, labels, lmasks, aux, omasks)
-        return loss
+        return net._forward_loss(params, state, batch, None, False)[0]
 
     return score
 
@@ -71,14 +49,9 @@ def check_gradients(
 
     `subset`: check only N randomly-chosen parameters (for big nets).
     """
-    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
-
-    if isinstance(net, MultiLayerNetwork):
-        ds = data if isinstance(data, DataSet) else DataSet(*data)
-        score = _score_fn_multilayer(net, ds)
-    else:
-        mds = data if isinstance(data, MultiDataSet) else MultiDataSet.from_dataset(data)
-        score = _score_fn_graph(net, mds)
+    if not isinstance(data, (DataSet, MultiDataSet)):
+        data = DataSet(*data)
+    score = _score_fn(net, data)
 
     params = net.params_tree
     score_jit = jax.jit(score)

@@ -1,9 +1,9 @@
-"""The fit loop's instruments, shared by both engines.
+"""The fit loop's instruments.
 
-`MultiLayerNetwork` ("mln") and `ComputationGraph` ("graph") run the same
-loop: wait for a batch, dispatch it, enqueue the compiled step. `FitObs`
-holds one engine's hot-loop metric series (resolved once at import,
-observability/metrics.py rule 2) and opens the spans of that loop:
+`MultiLayerNetwork` ("mln") and `ComputationGraph` ("graph") run one loop
+(`nn/engine.py`): wait for a batch, dispatch it, enqueue the compiled step.
+`FitObs` holds one network class's hot-loop metric series (resolved once at
+import, observability/metrics.py rule 2) and opens the spans of that loop:
 
     <e>.fit          one epoch                       (the engine's `fit`)
     <e>.input_wait   `next()` of the batch source    (`batches`)

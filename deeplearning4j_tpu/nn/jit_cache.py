@@ -1,9 +1,7 @@
-"""Shared jit-program cache for the two engines.
+"""The engine's jit-program cache.
 
-`MultiLayerNetwork._get_jit` and `ComputationGraph._get_jit` used to carry
-near-identical copies of the cache-key construction + lookup; both now
-delegate here, and the compile-cache store (`compilation/`) hooks in once
-instead of twice.
+`Engine._get_jit` (`nn/engine.py`) delegates the cache-key construction and
+lookup here, where the compile-cache store (`compilation/`) hooks in.
 
 The cache key is ``(kind, sorted static args, context_cache_key(),
 kernels.config_key())``: the active `ParallelContext` selects which

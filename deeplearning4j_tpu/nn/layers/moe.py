@@ -5,7 +5,7 @@ config DSL: `MoELayer` in a `NeuralNetConfiguration` trains through the
 engines with top-1/top-2 routing, capacity dropping, router jitter, and
 the load-balance auxiliary loss folded into the network objective (the
 engine collects the `_aux_loss` state entry each MoE layer emits and adds
-it to the loss — `nn/multilayer.py._loss_from_preout`). Under an active
+it to the loss — each network class's `_forward_fn` and loss). Under an active
 `ParallelContext` with an expert axis, the per-expert einsum batch is
 sharding-constrained to that axis, so the SAME DSL model trains
 expert-parallel with GSPMD-inserted all-to-alls (no reference equivalent;

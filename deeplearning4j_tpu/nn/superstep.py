@@ -1,4 +1,4 @@
-"""Superstep program-body helpers shared by both engines (PERF.md §13).
+"""Superstep program-body helpers of the engine (`nn/engine.py`).
 
 The fused K-iteration train program can iterate two ways — same math, same
 RNG/clock threading, ONE device dispatch either way:
@@ -16,7 +16,7 @@ RNG/clock threading, ONE device dispatch either way:
   float-close, not bit-identical, to the per-batch loop. Hence opt-in.
 
 The choice is a STATIC part of the program (it changes the lowered HLO), so
-the engines pass it into the `_get_jit` cache key alongside `k` — and
+the engine passes it into the `_get_jit` cache key alongside `k` — and
 alongside `kernel_config()`, the kernel-registry selection under which the
 superstep body (LSTM cells, norm+act, the fused optimizer update carried
 through `(params, state, opt_state, clock)`) traces its dispatch seams.
@@ -36,7 +36,7 @@ import jax.numpy as jnp
 
 def kernel_config():
     """The kernel-registry selection this superstep program traces under
-    — passed by both engines as a `_get_jit` static so the fused-vs-
+    — passed by the engine as a `_get_jit` static so the fused-vs-
     fallback choice is explicit program identity (also folded in globally
     by `nn/jit_cache.py`; here it additionally lands in the AOT
     fingerprint's `static` list and the StepProfiler's program key)."""

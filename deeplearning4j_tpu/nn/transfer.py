@@ -4,8 +4,8 @@ Equivalent of the reference's `nn/transferlearning/TransferLearning.java`
 builder + `FrozenLayer` wrapper — recast for pytree engines. A frozen
 layer here is not a wrapper object but a *spec*: `frozen_spec` computes,
 from the layer configs (`Layer.frozen` / `Layer.lora_rank`), the set of
-param leaves excluded from training. Both engines consume the spec the
-same way:
+param leaves excluded from training. The engine (`nn/engine.py`)
+consumes the spec so:
 
 - updater-state init runs over the TRAINABLE subtree only, so frozen
   leaves get no Adam/RMSProp moments (a fully-frozen layer's opt entry
@@ -113,13 +113,10 @@ def merge_tree(trainable, frozen):
 
 
 def _layer_items(net) -> List[Tuple[str, Any]]:
-    """(layer_key, layer conf) pairs for either engine, in canonical
-    order (MLN: index order; graph: topological order of layer vertices)."""
-    if hasattr(net, "layer_vertices"):
-        order = [n for n in net.conf.topological_order()
-                 if n in net.layer_vertices]
-        return [(n, net.layer_vertices[n].layer) for n in order]
-    return list(zip(net.layer_keys, net.layers))
+    """(layer_key, layer conf) pairs in the net's flat-parameter order
+    (MLN: index order; graph: topological order of layer vertices)."""
+    layers = dict(net.named_layers())
+    return [(key, layers[key]) for key in net._param_order()]
 
 
 class TransferLearning:
@@ -207,17 +204,12 @@ class TransferLearning:
 
     # --------------------------------------------------------------- build
 
-    def _conf_items(self, conf) -> Dict[str, Any]:
-        if hasattr(conf, "vertices"):
-            out = {}
-            for name in self._keys:
-                out[name] = conf.vertices[name].layer
-            return out
-        return {self._keys[i]: conf.layers[i] for i in range(len(self._keys))}
-
     def build(self):
-        conf = copy.deepcopy(self._net.conf)
-        citems = self._conf_items(conf)
+        # The new net's layer confs are its (copied) configuration's own:
+        # stamping them here stamps the configuration.
+        new_net = type(self._net)(copy.deepcopy(self._net.conf))
+        conf = new_net.conf
+        citems = dict(new_net.named_layers())
         for key in self._freeze:
             citems[key].frozen = True
         for key, (rank, alpha) in self._lora.items():
@@ -225,7 +217,6 @@ class TransferLearning:
             if alpha is not None:
                 citems[key].lora_alpha = float(alpha)
 
-        new_net = type(self._net)(conf)
         pol = new_net.dtype_policy
         pdt = jnp.float32 if pol.low_precision_params else pol.jnp_param
         rng = jax.random.PRNGKey(conf.global_conf.seed ^ 0x10A)

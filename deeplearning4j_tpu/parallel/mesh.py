@@ -113,14 +113,7 @@ def batch_shardings(mesh: Mesh, tree, axis: str = "data"):
 def _layer_confs(net) -> Dict[str, object]:
     """Param-tree top-level key -> layer conf, for either engine (layer key
     for MultiLayerNetwork, vertex name for ComputationGraph)."""
-    found: Dict[str, object] = {}
-    layers = getattr(net, "layers", None)
-    if layers is not None:
-        for lk, layer in zip(net.layer_keys, layers):
-            found[lk] = layer
-    for name, v in (getattr(net, "layer_vertices", None) or {}).items():
-        found[name] = v.layer
-    return found
+    return dict(net.named_layers())
 
 
 #: Layer conf class names whose params stay replicated on purpose: small
@@ -276,16 +269,8 @@ def kv_page_sharding(mesh: Mesh, ndim: int,
 def _moe_layers(net) -> Dict[str, object]:
     """Param-tree keys of MoELayer configs in either engine (layer key for
     MultiLayerNetwork, vertex name for ComputationGraph)."""
-    found: Dict[str, object] = {}
-    layers = getattr(net, "layers", None)
-    if layers is not None:
-        for lk, layer in zip(net.layer_keys, layers):
-            if type(layer).__name__ == "MoELayer":
-                found[lk] = layer
-    for name, v in (getattr(net, "layer_vertices", None) or {}).items():
-        if type(v.layer).__name__ == "MoELayer":
-            found[name] = v.layer
-    return found
+    return {key: layer for key, layer in net.named_layers()
+            if type(layer).__name__ == "MoELayer"}
 
 
 def shard_params(net, mesh: Mesh, model_axis: Optional[str] = None,

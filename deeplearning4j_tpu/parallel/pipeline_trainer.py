@@ -13,7 +13,7 @@ the mesh's `pipe` axis. The trainer
 - builds ONE jitted train step: head layers (replicated) -> the GPipe
   scan+ppermute schedule (`pipeline.pipeline_apply`, backward generated by
   autodiff through the ring) -> tail layers -> loss -> the engine's own
-  per-layer updater stack (`MultiLayerNetwork._apply_updates`), so
+  per-layer updater stack (`Engine._apply_updates`, nn/engine.py), so
   schedules, gradient normalization, bias-LR, and L1/L2 semantics are
   byte-identical to unpipelined training;
 - is numerically equivalent to plain `net.fit` on the same data (GPipe

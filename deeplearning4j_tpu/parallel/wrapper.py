@@ -138,12 +138,8 @@ class ParallelWrapper:
         """Whether the net's output layer(s) emit per-timestep labels —
         disambiguates 2-D INTEGER labels ([b, t] sparse ids vs [b, c]
         integer one-hot) when padding."""
-        layers = getattr(self.net, "layers", None)
-        if layers is not None:
-            return type(layers[-1]).__name__ == "RnnOutputLayer"
-        lv = getattr(self.net, "layer_vertices", {})
-        return any(type(v.layer).__name__ == "RnnOutputLayer"
-                   for v in lv.values())
+        return any(type(layer).__name__ == "RnnOutputLayer"
+                   for _, layer in self.net.named_layers())
 
     def _shard(self, a):
         if a is None:

@@ -8,7 +8,7 @@ info and device memory, and routes the record through a
 `StatsStorageRouter` (`api/storage.py`). Where the reference pulls
 gradients off the host model object, here gradient/update magnitudes are
 computed INSIDE the jitted train step (only scalars leave the device —
-`MultiLayerNetwork._train_step(collect_stats=True)`); histograms are taken
+`Engine._train_step(collect_stats=True)`, nn/engine.py); histograms are taken
 from the params pytree on the sampled iterations only.
 """
 
@@ -177,9 +177,7 @@ class ConvolutionalListener(IterationListener):
             return
         acts = model.feed_forward(self.probe)
         grids: Dict[str, Any] = {}
-        names = getattr(model, "layer_keys", None) or [
-            f"layer_{i}" for i in range(len(acts))]
-        for name, a in zip(names, acts):
+        for (name, _), a in zip(model.named_layers(), acts):
             a = np.asarray(a, dtype="float32")
             if a.ndim != 4:  # NHWC conv activations only
                 continue
