@@ -222,8 +222,9 @@ def train_kernel_parity(seed: int) -> dict:
     """The train path's two Pallas bodies against their XLA references on
     a small input, on this device, through the layers' own seams: the
     interpret-mode parity tests say nothing about what Mosaic compiles.
-    `fused_update` is in the step under `auto`; `norm_act`'s BatchNorm
-    body only when forced, which is how `under_each_impl` drives it."""
+    `fused_update` is in the step under `auto` for small leaves, as these
+    are; `norm_act`'s BatchNorm body only when forced, which is how
+    `under_each_impl` drives it."""
     import jax
     import jax.numpy as jnp
 
@@ -268,6 +269,7 @@ def phase_train(args, size: Sizes):
     from deeplearning4j_tpu.datasets.iterators import (
         DeviceCacheDataSetIterator)
     from deeplearning4j_tpu.datasets.staging import transfer_cast
+    from deeplearning4j_tpu.kernels import fused_update, registry
     from deeplearning4j_tpu.models.resnet import resnet50
     from deeplearning4j_tpu.nn.graph import ComputationGraph
     from deeplearning4j_tpu.observability import estimate_step_cost
@@ -329,9 +331,13 @@ def phase_train(args, size: Sizes):
     # compiled step agree, kernel by kernel? A Pallas body is a
     # `tpu_custom_call` named after its `pallas_call(name=...)` in the
     # compiled program; off-chip it is interpreted, and there is none.
-    # Under `auto` on the chip: 107 `fused_update` calls and no `norm_act`
-    # one (every BatchNorm resolves to `xla`, with the reason in its row;
-    # 153 with 46 `norm_act` before PR 25, 0 under `DL4J_TPU_KERNELS=xla`).
+    # Under `auto` on the chip: no `norm_act` call (every BatchNorm resolves
+    # to `xla`, with the reason in its row; 46 before PR 25) and one
+    # `fused_update` call for each of the step's 107 updater dispatches
+    # whose leaves are all under a grid block, 72 of them (BatchNorm pairs,
+    # small 1x1 convolutions); the 35 with a larger leaf resolve to `xla`
+    # with the ravel for a reason (107 before PR 29; all 107 with
+    # `DL4J_TPU_KERNEL_FUSED_UPDATE=pallas`, 0 under `DL4J_TPU_KERNELS=xla`).
     # A step loaded from an AOT store written under other rules would show
     # here as a kernel's calls without its `pallas` row. The registry
     # resolves while a program is traced, and a step that came from the
@@ -364,6 +370,20 @@ def phase_train(args, size: Sizes):
     check(sum(calls_by_kernel.values()) == custom_calls,
           f"{custom_calls} tpu_custom_call(s) in the step, of which "
           f"{calls_by_kernel} carry a registry kernel's name")
+    # One updater dispatch a layer with parameters; the rule counts those
+    # whose largest leaf is under `fused_update`'s limit.
+    dispatches = [[l.size for l in jax.tree_util.tree_leaves(state)]
+                  for state in net.opt_state.values()]
+    dispatches = [sizes for sizes in dispatches if sizes]
+    small = sum(max(sizes) < fused_update._RAVEL_LIMIT
+                for sizes in dispatches)
+    if jax.devices()[0].platform == "tpu":
+        want = {"auto": small, "pallas": len(dispatches), "xla": 0}[
+            registry.mode_for("fused_update")[0]]
+        check(calls_by_kernel["fused_update"] == want,
+              f"{calls_by_kernel['fused_update']} fused_update call(s) in "
+              f"the step, the rule gives {want} ({small} of "
+              f"{len(dispatches)} dispatches hold small leaves only)")
     emit("train", model=f"resnet50 {image}x{image}x{classes}",
          batch=size.batch, policy="mixed_bfloat16",
          steps_after_warmup=steps, losses=[round(l, 5) for l in losses],
@@ -374,6 +394,7 @@ def phase_train(args, size: Sizes):
          step_bytes_accessed=cost["bytes"], kernels=rows,
          tpu_custom_calls_in_step=custom_calls,
          tpu_custom_calls_by_kernel=calls_by_kernel,
+         updater_dispatches={"all": len(dispatches), "small_leaves": small},
          kernel_parity=train_kernel_parity(args.seed))
 
 

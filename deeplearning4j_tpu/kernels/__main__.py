@@ -15,12 +15,16 @@ instead: every candidate's availability and refusal reason is printed
 SHAPES is a comma-separated int tuple (the kernel's registry signature
 order), DTYPES a comma-separated dtype list, and repeatable
 ``--meta key=value`` pairs fill the meta tuple (``true``/``false``
-parse to booleans, digits to ints). With ``--backend tpu`` the answers are
-the ones a TPU process would get, from any machine::
+parse to booleans, digits to ints). A signature of several shapes
+(`fused_update`'s: one a leaf) separates them with ``;`` (a single leaf
+ends in one). With ``--backend tpu`` the answers are the ones a TPU
+process would get, from any machine::
 
     python -m deeplearning4j_tpu.kernels --backend tpu --probe \
         flash_attention_paged 8,1,8,64,129,64,16 float32 \
         --meta mesh_devices=4
+    python -m deeplearning4j_tpu.kernels --backend tpu --probe \
+        fused_update "2048,128;16,2048,768" float32 --meta kind=adam
 """
 
 from __future__ import annotations
@@ -42,11 +46,20 @@ def _parse_meta(pairs):
     return tuple(meta)
 
 
+def _parse_shapes(text):
+    def ints(part):
+        return tuple(int(d) for d in part.split(",") if d)
+
+    if ";" in text:  # one shape a leaf: `fused_update`'s signature
+        return tuple(ints(part) for part in text.split(";") if part)
+    return ints(text)
+
+
 def _probe(args) -> int:
     from deeplearning4j_tpu.kernels import registry
 
     kernel, shapes_s, dtypes_s = args.probe
-    shapes = tuple(int(d) for d in shapes_s.split(",")) if shapes_s else ()
+    shapes = _parse_shapes(shapes_s)
     dtypes = tuple(d for d in dtypes_s.split(",") if d)
     meta = _parse_meta(args.meta)
     selected, rows = registry.probe(kernel, backend=args.backend,

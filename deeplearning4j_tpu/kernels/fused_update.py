@@ -1,22 +1,35 @@
-"""Fused optimizer update: one elementwise kernel over stacked leaves.
+"""Fused optimizer update: one elementwise kernel over a layer's small leaves.
 
-`ops/updaters.py` applies Adam/Nesterov/RMSProp with one
-`jax.tree_util.tree_map` per state field — per-leaf ops that XLA mostly
-fuses, but each leaf is its own kernel launch chain and small leaves
-(biases, norm scales) never saturate a lane. The Pallas path ravels the
-gradient/state pytrees into single flat vectors (`ravel_pytree`), pads to
-an (8, 128) tile multiple, and runs ONE elementwise kernel producing the
-new state vectors and the delta vector, which is then unraveled back to
-the param tree — the superstep carry (`nn/superstep.py`) threads through
-this exact seam, so all K fused iterations share one update kernel per
-step.
+The engine applies Adam/Nesterov/RMSProp once per LAYER
+(`Engine._apply_updates`: ResNet-50's step makes 107 dispatches), and each
+dispatch reaches this seam with that layer's gradient and state trees. Two
+bodies compute the same update:
 
-The XLA fallbacks below are the LITERAL pre-registry `ops/updaters.py`
-bodies moved here verbatim (bit-exactness contract): same tree_maps, same
-bias-correction branch, so `DL4J_TPU_KERNELS=xla` (and auto off-TPU)
-trains bit-identically to the pre-PR engines. Hyperparameters stay
-Python floats baked into the trace; `lr`/`step` may be traced scalars and
-are passed into the kernel as a (3,) SMEM operand.
+- The XLA bodies below are the LITERAL pre-registry `ops/updaters.py`
+  code moved here verbatim (bit-exactness contract): one `tree_map` per
+  state field, the same bias-correction branch, so `DL4J_TPU_KERNELS=xla`
+  (and auto off-TPU) trains bit-identically to the pre-PR engines. XLA
+  fuses each leaf's chain with the gradient's cast before it and the
+  engine's `p - delta` after it, in place on the donated buffers.
+- The Pallas body ravels the gradient/state trees into single flat vectors
+  (`ravel_pytree`), pads to an (8, 128) tile multiple, runs ONE elementwise
+  kernel producing the new state vectors and the delta vector, and
+  unravels them back to the tree. It is for dispatches of SMALL leaves
+  (a BatchNorm's gamma and beta, a 1x1 convolution), which alone never
+  fill a lane: on ResNet-50's 72 such dispatches it is 0.2% of the step
+  faster than XLA's per-leaf fusions (PERF.md §6, PR 29).
+
+Under `auto` on a TPU the leaves' sizes decide (`_RAVEL_LIMIT`): a leaf of a
+grid block or more fills the lanes by itself, and the ravel, the tile
+reshape and the unravel around the custom call are whole passes over HBM
+that XLA must materialise: for `keye_vl2_30b_a3b`'s 465 M parameters about
+50 GB a step around a 16 ms kernel, invisible to every `*_time_share`
+(PERF.md §6, PR 29). `DL4J_TPU_KERNEL_FUSED_UPDATE=pallas` still forces the
+body (parity tests, `chip_smoke.py`). The superstep carry
+(`nn/superstep.py`) threads through this exact seam.
+
+Hyperparameters stay Python floats baked into the trace; `lr`/`step` may
+be traced scalars and are passed into the kernel as a (3,) SMEM operand.
 
 Scope: `adam`, `nesterovs`, `rmsprop` (the issue's set). Other updaters
 never enter the seam. Mixed-dtype or non-float32 trees fall back.
@@ -25,6 +38,7 @@ never enter the seam. Mixed-dtype or non-float32 trees fall back.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +52,18 @@ _TILE = 8 * 128
 # block, so Adam's 3 inputs + 3 outputs, double-buffered, hold 6 MiB of
 # the chip's 16 MiB default scoped VMEM.
 _BLOCK_ROWS = 1024
+# Under `auto` the body is for dispatches of small leaves. Once a leaf
+# holds a whole grid block a per-leaf XLA fusion fills the lanes as well,
+# and runs in place on the donated buffers; the ravel here does not. A
+# constant of the kernel, decided on the chip (PERF.md §6, PR 29).
+_RAVEL_LIMIT = _BLOCK_ROWS * 128
+_RAVEL_AUTO_REFUSAL = (
+    "the largest leaf holds {largest} elements (>= one grid block of "
+    f"{_RAVEL_LIMIT}): the body wants every leaf raveled into one flat "
+    "vector, and for a leaf this size the ravel, the tile reshape and the "
+    "unravel are whole passes over HBM that XLA's per-leaf fusion (cast, "
+    "moments, delta, p - delta, in place) never makes "
+    "(DL4J_TPU_KERNEL_FUSED_UPDATE=pallas forces it; PERF.md PR 29)")
 
 
 def _pallas_available(backend, shapes, dtypes, meta=(), forced=False):
@@ -60,6 +86,9 @@ def _pallas_available(backend, shapes, dtypes, meta=(), forced=False):
         return False, (f"Pallas fused update needs the TPU backend, have "
                        f"{backend} (DL4J_TPU_KERNEL_FUSED_UPDATE=pallas "
                        "forces interpret mode)")
+    largest = max((math.prod(s) for s in shapes), default=0)
+    if largest >= _RAVEL_LIMIT:
+        return False, _RAVEL_AUTO_REFUSAL.format(largest=largest)
     return True, "TPU fused elementwise update over stacked flat leaves"
 
 

@@ -55,8 +55,10 @@ MODES = ("auto", "xla", "pallas")
 # old rules is loaded for the new program. 1 (implicit, no key): PR 10-24. 2: `norm_act` refuses
 # the Pallas body for BatchNorm under `auto` (PR 25). 3: `masked_attention`
 # exists, and on a TPU `nn/layers/dsa.py`'s attention resolves its Pallas
-# body where it ran XLA row blocks (PR 27).
-SELECTION_RULES = 3
+# body where it ran XLA row blocks (PR 27). 4: `fused_update` refuses the
+# Pallas body under `auto` for a dispatch with a leaf of a grid block or
+# more, so a step with large layers no longer ravels them (PR 29).
+SELECTION_RULES = 4
 
 # Meta key the registry itself adds to a signature traced under a mesh of
 # more than one device (and `--probe --meta mesh_devices=N` passes by hand).

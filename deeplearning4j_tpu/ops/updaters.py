@@ -63,8 +63,10 @@ def nesterovs(momentum: float = 0.9) -> GradientUpdater:
     The update body lives behind the fused-update dispatch seam
     (`kernels/fused_update.py`): the XLA fallback there is this updater's
     pre-registry tree_map code verbatim (ND4J semantics: applied update =
-    -(mu*vPrev) + (1+mu)*v, negated because the caller subtracts deltas);
-    on TPU the registry may fuse all leaves into one elementwise kernel."""
+    -(mu*vPrev) + (1+mu)*v, negated because the caller subtracts deltas).
+    The engine calls `update` once per layer; on a TPU the registry fuses a
+    layer's leaves into one elementwise kernel when all of them are small,
+    and leaves large ones to XLA's per-leaf fusions (PERF.md §6, PR 29)."""
 
     def init(params):
         return {"v": _zeros_like_tree(params)}
@@ -81,8 +83,10 @@ def adam(beta1: float = 0.9, beta2: float = 0.999, eps: float = 1e-8) -> Gradien
         return {"m": _zeros_like_tree(params), "v": _zeros_like_tree(params)}
 
     def update(state, grads, lr, step):
-        # Fused-update dispatch seam (kernels/fused_update.py); the XLA
-        # fallback is the pre-registry per-leaf code verbatim.
+        # Fused-update dispatch seam (kernels/fused_update.py), once per
+        # layer: the XLA body is the pre-registry per-leaf code verbatim and
+        # what `auto` takes for large leaves; the Pallas body is for a
+        # dispatch of small ones (PERF.md §6, PR 29).
         return _fused.dispatch("adam", state, grads, lr, step,
                                (beta1, beta2, eps))
 
@@ -138,8 +142,7 @@ def rmsprop(decay: float = 0.95, eps: float = 1e-8) -> GradientUpdater:
         return {"g2": _zeros_like_tree(params)}
 
     def update(state, grads, lr, step):
-        # Fused-update dispatch seam (kernels/fused_update.py); the XLA
-        # fallback is the pre-registry per-leaf code verbatim.
+        # Fused-update dispatch seam, as in `adam` above.
         return _fused.dispatch("rmsprop", state, grads, lr, step,
                                (decay, eps))
 

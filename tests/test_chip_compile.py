@@ -20,6 +20,7 @@ file so that one worker runs them.
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -82,9 +83,13 @@ def test_fused_update_resnet50(chip, kind, hyper):
     assert_kernel_compiles(
         lambda *a: call(*a),
         *([chip((rows, 128))] * n_tiled + [chip((3,))]))
-    ok, why = fu._pallas_available(
-        "tpu", ((RESNET50_PARAMS,),), ("float32",), meta=(("kind", kind),))
+    # The body still compiles, so forcing it works; `auto` declines it for
+    # a dispatch with a leaf of a grid block or more (the ravel, PR 29).
+    sig = ("tpu", ((RESNET50_PARAMS,),), ("float32",))
+    ok, why = fu._pallas_available(*sig, meta=(("kind", kind),), forced=True)
     assert ok, why
+    ok, why = fu._pallas_available(*sig, meta=(("kind", kind),))
+    assert not ok and "raveled into one flat vector" in why, why
 
 
 def test_norm_act_batchnorm_relu_bf16_resnet50(chip):
@@ -153,6 +158,57 @@ def test_auto_leaves_no_custom_call_between_conv_and_batchnorm(
         text, forced_bytes, took = compiled()
         assert took == {"pallas"}
         assert "norm_act_batchnorm" in text and "tpu_custom_call" in text
+        assert auto_bytes < forced_bytes
+    finally:
+        registry.clear_cache()
+
+
+def test_auto_ravels_no_large_leaf_into_a_flat_vector(chip, monkeypatch):
+    """One layer's Adam update at `keye_vl2_30b_a3b`'s MoE signature (75.8 M
+    elements, three leaves of 25 M), through the updater's own seam plus the
+    engine's `p - delta`, over donated buffers, for the described chip as a
+    TPU process would trace it: under `auto` the compiled program holds no
+    `tpu_custom_call` and no flat vector of the whole layer, forced it holds
+    the body, the vector, and moves more bytes (a count, not a timing)."""
+    from deeplearning4j_tpu.kernels import registry
+    from deeplearning4j_tpu.ops import updaters
+
+    monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")
+    shapes = {"gate_w": (2048, 128), "w_down": (16, 768, 2048),
+              "w_gate": (16, 2048, 768), "w_up": (16, 2048, 768)}
+    tree = {k: chip(s) for k, s in shapes.items()}
+    grads = {k: chip(s, jnp.bfloat16) for k, s in shapes.items()}
+    flat = f"f32[{sum(math.prod(s) for s in shapes.values())}]"
+    assert flat == "f32[75759616]"
+    adam = updaters.adam(0.9, 0.95, 1e-8)
+
+    def step(params, state, g, lr, t):
+        g = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), g)
+        state, deltas = adam.update(state, g, lr, t)
+        return {k: params[k] - deltas[k] for k in params}, state
+
+    def compiled():
+        registry.clear_cache()
+        # A new function object each time: `jit` would not trace `step`
+        # itself again, and the registry is asked while tracing.
+        exe = jax.jit(functools.partial(step), donate_argnums=(0, 1)).lower(
+            tree, {"m": tree, "v": tree}, grads, chip(()), chip(())).compile()
+        cost = exe.cost_analysis()
+        cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+        took = {r.impl for r in registry.resolved()
+                if r.kernel == "fused_update"}
+        return exe.as_text(), float(cost["bytes accessed"]), took
+
+    try:
+        text, auto_bytes, took = compiled()
+        assert took == {"xla"}
+        assert "tpu_custom_call" not in text
+        assert flat not in text
+        monkeypatch.setenv("DL4J_TPU_KERNEL_FUSED_UPDATE", "pallas")
+        text, forced_bytes, took = compiled()
+        assert took == {"pallas"}
+        assert "fused_update_adam" in text and "tpu_custom_call" in text
+        assert flat in text
         assert auto_bytes < forced_bytes
     finally:
         registry.clear_cache()

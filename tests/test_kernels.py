@@ -313,6 +313,101 @@ class TestNormActAutoSelection:
 
 
 # --------------------------------------------------------------------------
+# `auto` and the updater seam: the leaves' sizes decide (PR 29)
+
+# One dispatch is one layer's trainable leaves, in `tree_leaves` order.
+# `keye_vl2_30b_a3b`: an MoE layer (75.8 M elements), an attention layer,
+# a norm's scale; ResNet-50: a 3x3 convolution, a 1x1 under one grid
+# block, a BatchNorm's gamma and beta.
+_UPDATE_SIGNATURES = {
+    "keye-ffn": (((2048, 128), (16, 768, 2048), (16, 2048, 768),
+                  (16, 2048, 768)), "xla"),
+    "keye-attn": (((2048, 512), (4096, 2048), (2048, 4096), (2048, 512),
+                   (128,), (128,)), "xla"),
+    "keye-norm": (((2048,),), "pallas"),
+    "resnet-conv3x3": (((3, 3, 512, 512),), "xla"),
+    "resnet-conv1x1": (((1, 1, 256, 64),), "pallas"),
+    "resnet-batchnorm": (((256,), (256,)), "pallas"),
+}
+_UPDATE_KINDS = {"adam": (0.9, 0.95, 1e-8), "nesterovs": (0.9,)}
+
+
+def _update_sig(kind, shapes):
+    return dict(backend="tpu", shapes=shapes,
+                dtypes=("float32",) * len(shapes),
+                meta=(("kind", kind), ("hyper", _UPDATE_KINDS[kind])))
+
+
+class TestFusedUpdateAutoSelection:
+    @pytest.mark.parametrize("kind", sorted(_UPDATE_KINDS))
+    @pytest.mark.parametrize("name", sorted(_UPDATE_SIGNATURES))
+    def test_auto_by_leaf_size_at_the_cells_signatures(self, monkeypatch,
+                                                       name, kind):
+        shapes, want = _UPDATE_SIGNATURES[name]
+        res = registry.resolve("fused_update", **_update_sig(kind, shapes))
+        assert res.impl == want, res
+        if want == "xla":
+            # The winner's reason carries why Pallas said no.
+            assert "pallas unavailable" in res.reason, res
+            assert "raveled into one flat vector" in res.reason, res
+        # The knob still drives the body (parity tests, smoke).
+        monkeypatch.setenv("DL4J_TPU_KERNEL_FUSED_UPDATE", "pallas")
+        registry.clear_cache()
+        forced = registry.resolve("fused_update", **_update_sig(kind, shapes))
+        assert forced.impl == "pallas", forced
+        assert "forced via DL4J_TPU_KERNEL_FUSED_UPDATE" in forced.reason
+
+    @pytest.mark.parametrize("kind", sorted(_UPDATE_KINDS))
+    def test_probe_reports_the_refusal(self, kind):
+        shapes, _ = _UPDATE_SIGNATURES["keye-ffn"]
+        selected, rows = registry.probe("fused_update",
+                                        **_update_sig(kind, shapes))
+        assert selected == "xla"
+        by_impl = {r["impl"]: r for r in rows}
+        assert not by_impl["pallas"]["available"]
+        assert "25165824 elements" in by_impl["pallas"]["reason"]
+        assert by_impl["xla"]["available"]
+
+    def test_the_limit_is_one_grid_block(self):
+        limit = fused_update._RAVEL_LIMIT
+        for n, want in ((limit - 1, True), (limit, False)):
+            ok, why = fused_update._pallas_available(
+                "tpu", ((7,), (n,)), ("float32",) * 2,
+                meta=(("kind", "adam"),))
+            assert ok is want, why
+
+    @pytest.mark.parametrize("kind", sorted(_UPDATE_KINDS))
+    def test_large_leaves_trace_no_pallas_call_on_tpu(self, monkeypatch,
+                                                      kind):
+        # What the engine gets: under `auto` with a TPU for a backend a
+        # dispatch of large leaves traces the per-leaf XLA expression and
+        # nothing else; one of small leaves still traces the body.
+        monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")
+        hyper = _UPDATE_KINDS[kind]
+        fields = ("m", "v") if kind == "adam" else ("v",)
+
+        def jaxpr(fn, shapes):
+            tree = {str(i): jax.ShapeDtypeStruct(s, jnp.float32)
+                    for i, s in enumerate(shapes)}
+            return str(jax.make_jaxpr(fn)({f: tree for f in fields}, tree))
+
+        def seam(st, g):
+            return fused_update.dispatch(kind, st, g, 0.01, 3, hyper)
+
+        def reference(st, g):
+            xla = {"adam": fused_update.adam_xla,
+                   "nesterovs": fused_update.nesterovs_xla}[kind]
+            return xla(st, g, 0.01, 3, *hyper)
+
+        large = ((2048, 128), (4, 512, 768))
+        assert jaxpr(seam, large) == jaxpr(reference, large)
+        assert "pallas_call" in jaxpr(seam, ((256,), (256,)))
+        took = [r.impl for r in registry.resolved()
+                if r.kernel == "fused_update"]
+        assert sorted(took) == ["pallas", "xla"], took
+
+
+# --------------------------------------------------------------------------
 # Program identity: jit-cache keys and the AOT fingerprint
 
 
@@ -334,8 +429,9 @@ class TestProgramIdentity:
             == _PARENTS_KERNELS_FINGERPRINT
         assert fp != _PARENTS_KERNELS_FINGERPRINT
         # 3 since PR 27: `masked_attention` joined the names, and the
-        # sparse-attention layer's step is another program on a TPU.
-        assert fp["selection_rules"] >= 3
+        # sparse-attention layer's step is another program on a TPU. 4
+        # since PR 29: a step with large layers no longer ravels them.
+        assert fp["selection_rules"] >= 4
         assert fp["masked_attention"] == "auto"
 
     def test_fingerprint_doc_differs_from_the_parents(self):
@@ -436,6 +532,16 @@ class TestCLI:
         for r in rows:
             assert set(r) >= {"kernel", "mode", "mode_source", "impl",
                               "reason"}
+
+    @pytest.mark.parametrize("shapes,selected", [
+        ("2048,128;16,2048,768", "xla"), ("256;256", "pallas"),
+        ("3,3,512,512;", "xla")])
+    def test_probe_takes_one_shape_a_leaf(self, shapes, selected):
+        proc = self._run("--backend", "tpu", "--json", "--probe",
+                         "fused_update", shapes, "float32",
+                         "--meta", "kind=adam")
+        assert proc.returncode == 0, proc.stderr
+        assert json.loads(proc.stdout)["selected"] == selected
 
 
 # --------------------------------------------------------------------------
