@@ -1,0 +1,196 @@
+"""Mellum2-12B-A2.5B-Instruct for `fit`, one chip's share: the network, its
+staged data, and the check against the plain reference.
+
+`build(sizes, seed, chips)` returns what the `fit` and `fit_ref` drivers
+need. Everything that is a size comes from the JSON beside this file. The
+reference (`benchmark/reference/swa_moe_lm.py`) is given the same share: the
+held experts and the held slice of the vocabulary.
+"""
+
+from __future__ import annotations
+
+source = ("https://huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct/blob/"
+          "main/config.json")
+
+# What the on-chip check compares, each limit set between two readings on the
+# chip (my chip runs, PR 30, calls 38, 39 and 43, all under the cell's own
+# traffic; PERF.md section 4): the worst the program gave over its sound
+# seeds, fifteen at this window's step count (90 to 98 steps before the
+# check) and two at twice it (162: `--seconds 102`; the readings rise with
+# the steps taken, as `keye_vl2_30b_a3b`'s do), and what it gives under a
+# planted fault run through the harness, which has to come out as not
+# correct: every matrix rounded to float8_e4m3fn (the nearest precision
+# below the configuration's bf16), the window halved, YaRN replaced by plain
+# RoPE on the full layer, `attention_factor` dropped, the state left
+# unchanged. Sound | fp8, window halved, no YaRN, no factor (the faults'
+# readings of call 39, then of call 43 on the committed files).
+LIMITS = {
+    # near-ties at the router's top_k-th place flip under bf16: share of a
+    # token's experts that program and reference agree on, worst layer.
+    # 0.9954 to 0.9960 (0.9956 at 162 steps) | 0.9658 (its best layer
+    # 0.9700), 0.8786, 0.9631 and 0.9743 (the full layer's experts alone);
+    # 0.9650, 0.8817, 0.9614, 0.9760
+    "routing_agreement_min": 0.985,
+    # loss, the program's (bf16 compute) against the reference's (float32),
+    # with its own routing and given the program's: |difference| /
+    # reference. 1.1e-6 to 2.6e-5, 2.5e-5 at 162 steps (the bf16 loss stands
+    # ~1.5e-4 above the float32 one while the loss falls: the ratio rises
+    # with the steps, PERF.md section 4) | 1.0e-3, 1.9e-2, 7.1e-3, 6.2e-3;
+    # 1.1e-3, 1.8e-2, 8.0e-3, 5.6e-3
+    "loss_rel": 2e-4,
+    # logits of 256 positions and every compared gradient, the reference
+    # given the program's routing: ||program - reference|| / ||reference||.
+    # Logits 0.0073 to 0.0074, 0.0079 at 162 steps | 0.0734, 0.272, 0.0797,
+    # 0.0554; 0.0732, 0.270, 0.0818, 0.0547. The worst gradient (`attn0.Wo`
+    # every time) 0.025 to 0.060, 0.044 and 0.0715 at 162 steps | 0.837, 3.57,
+    # 0.835, 0.642; 0.750, 3.23, 0.838, 0.650 (on `attn0.Wo` or `attn3.Wq`)
+    "logits_rel": 0.02,
+    "grad_rel": 0.20,
+    # the change one compiled train step makes to a leaf against the
+    # reference's Adam step from the same state, worst leaf (`attn0.Wo`):
+    # 0.0076 to 0.0100, 0.0180 and 0.0212 at 162 steps | state unchanged 1
+    # on all 14 leaves. The room is above the reading: fresh seeds and later
+    # checks read higher.
+    "update_rel": 0.06,
+}
+
+# The faults this model's check can plant in its own layers, beside
+# `fit_check.FAULTS` (fp8, the state left unchanged).
+FAULTS = ("window_half", "no_yarn", "no_attention_factor")
+
+
+def layer_types(sizes: dict) -> list:
+    """The kinds of the layers held: the first `num_hidden_layers` of the
+    published pattern (one period)."""
+    return list(sizes["layer_types"][:int(sizes["num_hidden_layers"])])
+
+
+def attention_kinds(sizes: dict) -> dict:
+    """`{kind: {"window", "rope"}}` from `sliding_window` and
+    `rope_parameters`, as the reference's `cfg["attention"]` takes them."""
+    kinds = {}
+    for kind, rope in sizes["rope_parameters"].items():
+        rope = dict(rope, theta=float(rope["rope_theta"]))
+        del rope["rope_theta"]
+        kinds[kind] = {
+            "window": int(sizes["sliding_window"])
+            if kind == "sliding_attention" else None, "rope": rope}
+    return kinds
+
+
+def model_cfg(sizes: dict) -> dict:
+    """The reference's `cfg` from the configuration's sizes."""
+    return {
+        "n_heads": int(sizes["num_attention_heads"]),
+        "n_kv_heads": int(sizes["num_key_value_heads"]),
+        "head_dim": int(sizes["head_dim"]),
+        "rms_eps": float(sizes["rms_norm_eps"]),
+        "layer_types": layer_types(sizes),
+        "attention": attention_kinds(sizes),
+        "n_experts": int(sizes["num_experts"]),
+        "top_k": int(sizes["num_experts_per_tok"]),
+        "first_expert": int(sizes["held"]["first_expert"]),
+        "norm_topk_prob": bool(sizes["norm_topk_prob"]),
+        "aux_coef": float(sizes["aux_loss_coef"]),
+    }
+
+
+def attention_types(sizes: dict) -> dict:
+    """What each kind of layer sets on the program's `SelfAttentionLayer`."""
+    out = {}
+    for kind, a in attention_kinds(sizes).items():
+        rope = dict(a["rope"])
+        theta = rope.pop("theta")
+        out[kind] = {
+            "sliding_window": a["window"], "rope_theta": theta,
+            "rope_scaling": None if rope["rope_type"] == "default" else rope}
+    return out
+
+
+def make_conf(sizes: dict, seed: int, **over):
+    """The program's configuration (`zoo.sparse_moe_lm`) at these sizes."""
+    from deeplearning4j_tpu.models import zoo
+
+    cfg = model_cfg(sizes)
+    kw = dict(
+        t=int(sizes["seq_len"]), d_model=int(sizes["hidden_size"]),
+        n_blocks=int(sizes["num_hidden_layers"]), n_heads=cfg["n_heads"],
+        n_kv_heads=cfg["n_kv_heads"], head_dim=cfg["head_dim"],
+        n_experts=cfg["n_experts"], top_k=cfg["top_k"],
+        expert_hidden=int(sizes["moe_intermediate_size"]),
+        experts_held=(cfg["first_expert"], int(sizes["held"]["experts"])),
+        layer_types=cfg["layer_types"], attention_types=attention_types(sizes),
+        rms_eps=cfg["rms_eps"], norm_topk_prob=cfg["norm_topk_prob"],
+        aux_loss_weight=cfg["aux_coef"], lr=float(sizes["learning_rate"]),
+        adam_mean_decay=float(sizes["adam_mean_decay"]),
+        adam_var_decay=float(sizes["adam_var_decay"]),
+        seed=seed % (2 ** 31 - 1), dtype_policy=dict(sizes["dtype_policy"]))
+    kw.update(over)
+    return zoo.sparse_moe_lm(int(sizes["held"]["ids"]), **kw)
+
+
+def _plant(fault, layers: dict, kinds: list):
+    """Change the program's attention layers as a first-pass `fault` asks;
+    returns what undoes it."""
+    held = {name: (layer.sliding_window, layer.rope_scaling)
+            for name, layer in layers.items()}
+    for (name, layer), kind in zip(layers.items(), kinds):
+        if fault == "window_half" and kind == "sliding_attention":
+            layer.sliding_window //= 2
+        elif fault == "no_yarn" and layer.rope_scaling is not None:
+            layer.rope_scaling = None
+        elif fault == "no_attention_factor" and layer.rope_scaling is not None:
+            layer.rope_scaling = dict(layer.rope_scaling,
+                                      attention_factor=1.0)
+
+    def undo():
+        for name, layer in layers.items():
+            layer.sliding_window, layer.rope_scaling = held[name]
+    return undo
+
+
+def reference_check(net, sizes: dict, batch, *, positions: int = 256,
+                    fault=None) -> dict:
+    """`fit_check.two_pass_check` for this model: the routing E(t) is the
+    one set compared as a limit of its own; the compared leaves are those
+    of the first layer of each kind (a sliding layer and the full layer).
+    Beside `fit_check.FAULTS`, the first pass can be given, on the program's
+    side only: "window_half" (the sliding layers' window halved), "no_yarn"
+    (plain RoPE on the full layer), "no_attention_factor" (YaRN's factor on
+    cos and sin dropped)."""
+    from benchmark.harness import cells, fit_check
+
+    cfg = model_cfg(sizes)
+    kinds = cfg["layer_types"]
+    return fit_check.two_pass_check(
+        net, batch, ref=cells.load_module("reference",
+                                          sizes["check"]["reference"]),
+        cfg=cfg, sizes=sizes, limits=LIMITS,
+        leaves=fit_check.compared_leaves(kinds.index(k) for k in set(kinds)),
+        rparams_of=lambda tree: fit_check.reference_params(tree, len(kinds)),
+        plant=lambda fault: _plant(
+            fault, {f"attn{i}": net.layer_vertices[f"attn{i}"].layer
+                    for i in range(len(kinds))}, kinds),
+        faults=FAULTS, positions=positions, fault=fault)
+
+
+def build(sizes: dict, seed: int, chips: int) -> dict:
+    from benchmark.harness import cells, fit_check
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+
+    if chips != 1:
+        raise ValueError("this configuration is one chip's share of eight: "
+                         "the cell takes 1 chip")
+    net = ComputationGraph(make_conf(sizes, seed)).init()
+    ref = cells.load_module("reference", sizes["check"]["reference"])
+    cfg = model_cfg(sizes)
+    n_layers = int(sizes["num_hidden_layers"])
+    # What `fit_mfu` counts multiply-adds from: the reference's forward in
+    # the form whose products are the ones the mathematics needs (the band's
+    # pairs and the held experts' pairs only).
+    return fit_check.lm_cell(
+        net, sizes, seed,
+        forward=lambda params, ids: ref.forward_needed(
+            fit_check.reference_params(params, n_layers), ids, cfg),
+        check=lambda batch, fault: reference_check(net, sizes, batch,
+                                                   fault=fault))

@@ -1,0 +1,9 @@
+"""kernels: share of the device's busy time in the attention of the
+sliding-window layers (operations traced under the scope `attn.sliding`,
+forward and backward), in percent."""
+
+
+def read(context):
+    from benchmark.harness import scope_time
+
+    return scope_time.scope_share_percent(context, "attn.sliding")

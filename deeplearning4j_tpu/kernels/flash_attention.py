@@ -1,14 +1,19 @@
-"""Pallas flash-attention kernels (TPU): three registry names.
+"""Pallas flash-attention kernels (TPU): four registry names.
 
 `flash_attention` (causal or full, below), `flash_attention_paged` (decode
-against the paged KV pool, further down) and `masked_attention`
+against the paged KV pool, further down), `masked_attention`
 (grouped-query attention under a per-query `[S, S]` key mask, forward and
-backward: the sparse-attention layer's `dsa.attend`, `nn/layers/dsa.py`).
-The last shares the streaming kernels' prefetched lower-triangle sequence
-(`_pair_arrays`) and differs in three ways its own section explains: the
-mask is an operand, the query heads of one KV head share every tile, and
-bf16 operands reach the MXU as bf16. Its XLA fallback, the row blocks of
-`dsa.masked_gqa_attention_xla`, is what `auto` runs off the TPU and what
+backward: the sparse-attention layer's `dsa.attend`, `nn/layers/dsa.py`)
+and `banded_attention` (the same three kernels with no mask operand: what an
+extended attention layer without an indexer runs, `attn.full` and
+`attn.sliding`; PR 30). The masked family shares the streaming kernels'
+prefetched lower-triangle sequence (`_pair_arrays`, which also knows a
+sliding window: only the tiles that meet the band `t - window < s <= t`)
+and differs in three ways its own section explains: the mask is an operand
+or is built inside the tile from iotas, the query heads of one KV head
+share every tile, and bf16 operands reach the MXU as bf16. Their XLA
+fallbacks, the row blocks of `dsa.masked_gqa_attention_xla` and
+`dsa.banded_gqa_attention_xla`, are what `auto` runs off the TPU and what
 the parity tests hold the kernels to.
 
 The reference predates attention entirely; this backs the framework's
@@ -122,24 +127,31 @@ def _flash_kernel_resident(q_ref, k_ref, v_ref, o_ref, *, block_k: int,
 
 @functools.lru_cache(maxsize=64)
 def _pair_arrays(nq: int, nk: int, block_q: int, block_k: int, causal: bool,
-                 order: str):
+                 order: str, window: Optional[int] = None):
     """The streamed (q-block i, k-block j) visit sequence, scalar-prefetched
     into the kernels. Causal sequences cover ONLY the lower triangle —
-    above-diagonal blocks are never DMA'd. `order="row"` (i-major: forward,
-    dq — scratch accumulates along j) or `"col"` (j-major: dk/dv — scratch
-    accumulates along i)."""
+    above-diagonal blocks are never DMA'd; with `window` (row t reads keys
+    t - window < s <= t) only the tiles that meet that band.
+    `order="row"` (i-major: forward, dq — scratch accumulates along j) or
+    `"col"` (j-major: dk/dv — scratch accumulates along i)."""
     import numpy as np
 
+    if window is not None and not causal:
+        raise ValueError("a window of earlier keys needs causal=True")
     pairs = []
     if order == "row":
         for i in range(nq):
             jm = min(nk - 1, ((i + 1) * block_q - 1) // block_k) \
                 if causal else nk - 1
-            pairs += [(i, j) for j in range(jm + 1)]
+            j0 = 0 if window is None else \
+                max(0, i * block_q - window + 1) // block_k
+            pairs += [(i, j) for j in range(j0, jm + 1)]
     else:
         for j in range(nk):
             i0 = (j * block_k) // block_q if causal else 0
-            pairs += [(i, j) for i in range(i0, nq)]
+            im = nq - 1 if window is None else \
+                min(nq - 1, ((j + 1) * block_k + window - 2) // block_q)
+            pairs += [(i, j) for i in range(i0, im + 1)]
     i_idx = np.asarray([p[0] for p in pairs], np.int32)
     j_idx = np.asarray([p[1] for p in pairs], np.int32)
     return i_idx, j_idx
@@ -751,11 +763,16 @@ def _flash_bwd_stream_bhtd(q, k, v, do, o, lse, causal, scale, block_q,
 #
 # Grouped-query attention under a per-query key mask (kernel name
 # ``masked_attention``; `nn/layers/dsa.py::masked_gqa_attention` resolves
-# it). `keep[t, s]` says whether query t attends to key s. Under `causal`
-# the caller vouches that `keep` holds nothing above the diagonal, and the
-# visited (q-block, k-block) pairs are the lower triangle of `_pair_arrays`;
-# without it (a bidirectional layer) they are the whole rectangle. Inside a
-# visited tile the mask alone decides. The mask travels as int8, one
+# it), and the same kernels with no mask operand (``banded_attention``;
+# `dsa.banded_gqa_attention`): the keys a query reads are then the causal
+# triangle, or the band `t - window < s <= t` of a sliding-window layer,
+# or everything (a bidirectional layer), and a tile's mask is built inside
+# the kernel from two iotas. `keep[t, s]` says whether query t attends to
+# key s. Under `causal` the caller vouches that `keep` holds nothing above
+# the diagonal, and the visited (q-block, k-block) pairs are the lower
+# triangle of `_pair_arrays`, with `window` only its tiles that meet the
+# band; without `causal` they are the whole rectangle. Inside a visited
+# tile the mask alone decides. A mask operand travels as int8, one
 # `[block_q, block_k]` tile a step.
 #
 # The G = H / KV query heads of one KV head share every step: operands are
@@ -794,13 +811,36 @@ def _unfold_heads(x, G: int, block_q: int):
     return jnp.transpose(x, (1, 3, 0, 2, 4)).reshape(S, KV * G, Dh)
 
 
+def _tile_keep(keep_ref, i, j, block_q, block_k, causal, window,
+               transposed=False):
+    """The bool mask of tile (i, j): the operand's tile, or with no operand
+    the causal band from the tile's place (its row r is query `i*block_q +
+    r`, its column c key `j*block_k + c`: kept where key <= query, and key >
+    query - window under a window); None where every pair is kept.
+    `transposed`: `[BK, BQ]`."""
+    if keep_ref is not None:
+        return keep_ref[...].astype(jnp.int32) != 0
+    if not causal:
+        return None
+    shape = (block_k, block_q) if transposed else (block_q, block_k)
+    rows = i * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, shape, 1 if transposed else 0)
+    cols = j * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, shape, 0 if transposed else 1)
+    keep = cols <= rows
+    return keep if window is None else keep & (cols > rows - window)
+
+
 def _masked_scores(a, b, keep, scale):
     """Masked scaled scores `a b^T` of one tile in float32. `keep` is the
-    tile's mask, shared by the groups of rows of `a` stacked on it: folded
-    q rows `[G*BQ, Dh]` against `[BQ, BK]`, or in the transposed orientation
-    a k tile against one head's q rows and a `keep^T` tile `[BK, BQ]`."""
+    tile's mask (None: no pair is masked), shared by the groups of rows of
+    `a` stacked on it: folded q rows `[G*BQ, Dh]` against `[BQ, BK]`, or in
+    the transposed orientation a k tile against one head's q rows and a
+    `keep^T` tile `[BK, BQ]`."""
     s = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
+    if keep is None:
+        return s
     return jnp.where(keep, s.reshape(-1, *keep.shape), _NEG).reshape(s.shape)
 
 
@@ -814,6 +854,13 @@ def _lanes(x, n: int):
     return x if n == W else jnp.tile(x, (1, n // W))
 
 
+def _first_k_block(i, block_q, block_k, window):
+    """The k block that starts q block i's row of `_pair_arrays`' sequence."""
+    if window is None:
+        return 0
+    return jnp.maximum(i * block_q - window + 1, 0) // block_k
+
+
 def _last_k_block(i, block_q, block_k, nk, causal):
     """The k block that ends q block i's row of `_pair_arrays`' sequence."""
     if not causal:
@@ -821,18 +868,33 @@ def _last_k_block(i, block_q, block_k, nk, causal):
     return jnp.minimum(((i + 1) * block_q - 1) // block_k, nk - 1)
 
 
-def _masked_fwd_kernel(i_ref, j_ref, q_ref, k_ref, v_ref, keep_ref, o_ref,
-                       lse_ref, acc_ref, m_ref, l_ref, *, block_q: int,
-                       block_k: int, nk: int, causal: bool, scale: float):
+def _last_q_block(j, block_q, block_k, nq, window):
+    """The q block that ends k block j's column of the sequence."""
+    if window is None:
+        return nq - 1
+    return jnp.minimum(((j + 1) * block_k + window - 2) // block_q, nq - 1)
+
+
+def _keep_first(refs, has_keep: bool):
+    """(the mask's ref or None, the other refs): a kernel's mask, where the
+    call has one, is the first ref after the operands every call has."""
+    return (refs[0], refs[1:]) if has_keep else (None, refs)
+
+
+def _masked_fwd_kernel(i_ref, j_ref, q_ref, k_ref, v_ref, *refs, block_q: int,
+                       block_k: int, nk: int, causal: bool, scale: float,
+                       window=None, has_keep: bool = True):
     """One streamed step of the online softmax. The running max `m` and sum
     `l` live lane-replicated (`[rows, 128]`): `l` adds the score tile's
     128-lane columns elementwise and is summed across lanes once a q block,
     so a step pays one cross-lane reduction (the max), not two, and no
     broadcast of a `[rows, 1]` column."""
+    keep_ref, (o_ref, lse_ref, acc_ref, m_ref, l_ref) = _keep_first(
+        refs, has_keep)
     t = pl.program_id(1)
     i, j = i_ref[t], j_ref[t]
 
-    @pl.when(j == 0)
+    @pl.when(j == _first_k_block(i, block_q, block_k, window))
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG)
@@ -840,7 +902,8 @@ def _masked_fwd_kernel(i_ref, j_ref, q_ref, k_ref, v_ref, keep_ref, o_ref,
 
     v = v_ref[0]
     s = _masked_scores(q_ref[0], k_ref[0],
-                       keep_ref[...].astype(jnp.int32) != 0, scale)
+                       _tile_keep(keep_ref, i, j, block_q, block_k, causal,
+                                  window), scale)
     W = m_ref.shape[1]
     m = m_ref[...]
     new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
@@ -860,19 +923,22 @@ def _masked_fwd_kernel(i_ref, j_ref, q_ref, k_ref, v_ref, keep_ref, o_ref,
         lse_ref[0] = m_ref[:, :1] + jnp.log(l)
 
 
-def _masked_dq_kernel(i_ref, j_ref, q_ref, k_ref, v_ref, keep_ref, do_ref,
-                      lse_ref, d_ref, dq_ref, dq_acc, *, block_q: int,
-                      block_k: int, nk: int, causal: bool, scale: float):
+def _masked_dq_kernel(i_ref, j_ref, q_ref, k_ref, v_ref, *refs, block_q: int,
+                      block_k: int, nk: int, causal: bool, scale: float,
+                      window=None, has_keep: bool = True):
+    keep_ref, (do_ref, lse_ref, d_ref, dq_ref, dq_acc) = _keep_first(
+        refs, has_keep)
     t = pl.program_id(1)
     i, j = i_ref[t], j_ref[t]
 
-    @pl.when(j == 0)
+    @pl.when(j == _first_k_block(i, block_q, block_k, window))
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
     k = k_ref[0]
-    s = _masked_scores(q_ref[0], k, keep_ref[...].astype(jnp.int32) != 0,
-                       scale)
+    s = _masked_scores(q_ref[0], k,
+                       _tile_keep(keep_ref, i, j, block_q, block_k, causal,
+                                  window), scale)
     p = jnp.exp(s - lse_ref[0])
     dp = jax.lax.dot_general(do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
@@ -886,10 +952,11 @@ def _masked_dq_kernel(i_ref, j_ref, q_ref, k_ref, v_ref, keep_ref, do_ref,
         dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
-def _masked_dkv_kernel(i_ref, j_ref, k_ref, v_ref, q_ref, do_ref, keep_t_ref,
-                       lse_ref, d_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
+def _masked_dkv_kernel(i_ref, j_ref, k_ref, v_ref, q_ref, do_ref, *refs,
                        block_q: int, block_k: int, nq: int, causal: bool,
-                       scale: float):
+                       scale: float, window=None, has_keep: bool = True):
+    keep_t_ref, (lse_ref, d_ref, dk_ref, dv_ref, dk_acc, dv_acc) = \
+        _keep_first(refs, has_keep)
     t = pl.program_id(1)
     i, j = i_ref[t], j_ref[t]
 
@@ -899,7 +966,8 @@ def _masked_dkv_kernel(i_ref, j_ref, k_ref, v_ref, q_ref, do_ref, keep_t_ref,
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     k, v = k_ref[0], v_ref[0]
-    keep_t = keep_t_ref[...].astype(jnp.int32) != 0          # [BK, BQ]
+    keep_t = _tile_keep(keep_t_ref, i, j, block_q, block_k, causal, window,
+                        transposed=True)                     # [BK, BQ]
     dk = jnp.zeros(dk_acc.shape, jnp.float32)
     dv = jnp.zeros(dv_acc.shape, jnp.float32)
     for g in range(lse_ref.shape[2]):    # the group's heads share k, v, keep
@@ -919,7 +987,7 @@ def _masked_dkv_kernel(i_ref, j_ref, k_ref, v_ref, q_ref, do_ref, keep_t_ref,
     dk_acc[...] += dk
     dv_acc[...] += dv
 
-    @pl.when(i == nq - 1)
+    @pl.when(i == _last_q_block(j, block_q, block_k, nq, window))
     def _():
         dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -942,23 +1010,34 @@ def _masked_specs(block_q, block_k, G, D):
                          lambda b, t, ii, jj: (b, ii[t], 0, 0)))
 
 
+def _mask_operand(keep8, spec):
+    """(the mask's in_specs, its operands, the call's family name): one of
+    each with a mask (`masked_attention`), none without
+    (`banded_attention`)."""
+    if keep8 is not None:
+        return [spec], [keep8], "masked_attention"
+    return [], [], "banded_attention"
+
+
 def _masked_fwd(q, k, v, keep8, G, scale, causal, block_q, block_k,
-                interpret):
-    """Folded q `[KV, G*S, D]`, k, v `[KV, S, D]`, keep8 `[S, S]` int8 ->
-    (o folded, lse `[KV, G*S, 1]` float32)."""
+                interpret, window=None):
+    """Folded q `[KV, G*S, D]`, k, v `[KV, S, D]`, keep8 `[S, S]` int8 or
+    None -> (o folded, lse `[KV, G*S, 1]` float32)."""
     from jax.experimental.pallas import tpu as pltpu
 
     KV, S, D = k.shape
     nq, nk = S // block_q, S // block_k
-    ir, jr = _pair_arrays(nq, nk, block_q, block_k, causal, "row")
+    ir, jr = _pair_arrays(nq, nk, block_q, block_k, causal, "row", window)
     sp = _masked_specs(block_q, block_k, G, D)
     rows, W = G * block_q, min(128, block_k)   # W: the statistics' lanes
+    keep_spec, keep_arg, family = _mask_operand(keep8, sp["keep"])
     return pl.pallas_call(
         functools.partial(_masked_fwd_kernel, block_q=block_q,
-                          block_k=block_k, nk=nk, causal=causal, scale=scale),
+                          block_k=block_k, nk=nk, causal=causal, scale=scale,
+                          window=window, has_keep=keep8 is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(KV, len(ir)),
-            in_specs=[sp["q"], sp["kv"], sp["kv"], sp["keep"]],
+            in_specs=[sp["q"], sp["kv"], sp["kv"]] + keep_spec,
             out_specs=[sp["q"], sp["col"]],
             scratch_shapes=[pltpu.VMEM((rows, D), jnp.float32),
                             pltpu.VMEM((rows, W), jnp.float32),
@@ -966,12 +1045,12 @@ def _masked_fwd(q, k, v, keep8, G, scale, causal, block_q, block_k,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct((KV, G * S, 1), jnp.float32)],
         interpret=interpret,
-        name="masked_attention_fwd",
-    )(jnp.asarray(ir), jnp.asarray(jr), q, k, v, keep8)
+        name=f"{family}_fwd",
+    )(jnp.asarray(ir), jnp.asarray(jr), q, k, v, *keep_arg)
 
 
 def _masked_bwd(q, k, v, keep8, do, o, lse, G, scale, causal, block_q,
-                block_k, interpret):
+                block_k, interpret, window=None):
     """(dq folded, dk, dv) from the folded residuals."""
     from jax.experimental.pallas import tpu as pltpu
 
@@ -982,75 +1061,86 @@ def _masked_bwd(q, k, v, keep8, do, o, lse, G, scale, causal, block_q,
                     axis=-1, keepdims=True)                  # [KV, G*S, 1]
     sp = _masked_specs(block_q, block_k, G, D)
 
-    ir, jr = _pair_arrays(nq, nk, block_q, block_k, causal, "row")
+    ir, jr = _pair_arrays(nq, nk, block_q, block_k, causal, "row", window)
+    keep_spec, keep_arg, family = _mask_operand(keep8, sp["keep"])
     dq = pl.pallas_call(
         functools.partial(_masked_dq_kernel, block_q=block_q,
-                          block_k=block_k, nk=nk, causal=causal, scale=scale),
+                          block_k=block_k, nk=nk, causal=causal, scale=scale,
+                          window=window, has_keep=keep8 is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(KV, len(ir)),
-            in_specs=[sp["q"], sp["kv"], sp["kv"], sp["keep"], sp["q"],
-                      sp["col"], sp["col"]],
+            in_specs=[sp["q"], sp["kv"], sp["kv"]] + keep_spec
+            + [sp["q"], sp["col"], sp["col"]],
             out_specs=sp["q"],
             scratch_shapes=[pltpu.VMEM((rows, D), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-        name="masked_attention_dq",
-    )(jnp.asarray(ir), jnp.asarray(jr), q, k, v, keep8, do, lse, d_row)
+        name=f"{family}_dq",
+    )(jnp.asarray(ir), jnp.asarray(jr), q, k, v, *keep_arg, do, lse, d_row)
 
-    ic, jc = _pair_arrays(nq, nk, block_q, block_k, causal, "col")
+    ic, jc = _pair_arrays(nq, nk, block_q, block_k, causal, "col", window)
     as_rows = lambda c: c.reshape(KV, nq, G, block_q)
+    keep_spec, keep_arg, family = _mask_operand(
+        None if keep8 is None else keep8.T, sp["keep_t"])
     dk, dv = pl.pallas_call(
         functools.partial(_masked_dkv_kernel, block_q=block_q,
-                          block_k=block_k, nq=nq, causal=causal, scale=scale),
+                          block_k=block_k, nq=nq, causal=causal, scale=scale,
+                          window=window, has_keep=keep8 is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(KV, len(ic)),
-            in_specs=[sp["kv"], sp["kv"], sp["q"], sp["q"], sp["keep_t"],
-                      sp["row"], sp["row"]],
+            in_specs=[sp["kv"], sp["kv"], sp["q"], sp["q"]] + keep_spec
+            + [sp["row"], sp["row"]],
             out_specs=[sp["kv"], sp["kv"]],
             scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                             pltpu.VMEM((block_k, D), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         interpret=interpret,
-        name="masked_attention_dkv",
-    )(jnp.asarray(ic), jnp.asarray(jc), k, v, q, do, keep8.T,
+        name=f"{family}_dkv",
+    )(jnp.asarray(ic), jnp.asarray(jc), k, v, q, do, *keep_arg,
       as_rows(lse), as_rows(d_row))
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def _masked_attention_pallas(q, k, v, keep, causal: bool, block_q: int,
-                             block_k: int, interpret: bool = False):
+                             block_k: int, interpret: bool = False,
+                             window: Optional[int] = None):
     """q: [S, H, Dh]; k, v: [S, KV, Dh]; keep: [S, S] bool, row t's keys,
-    none above the diagonal where `causal` -> [S, H, Dh]. S must be a
-    multiple of both blocks (`masked_attention` asks the registry first)."""
+    none above the diagonal where `causal`; or None, and row t's keys are
+    then s <= t under `causal` (t - window < s <= t with `window`), every
+    key without -> [S, H, Dh]. S must be a multiple of both blocks (the
+    callers ask the registry first)."""
     return _masked_attention_fwd(q, k, v, keep, causal, block_q, block_k,
-                                 interpret)[0]
+                                 interpret, window)[0]
 
 
 def _masked_operands(q, k, v, keep, block_q):
     """What the kernels take: (q folded, k and v `[KV, S, Dh]`, keep as
-    int8), then G and the scale."""
+    int8 or None), then G and the scale."""
     KV = k.shape[1]
     return ((_fold_heads(q, KV, block_q), jnp.swapaxes(k, 0, 1),
-             jnp.swapaxes(v, 0, 1), keep.astype(jnp.int8)),
+             jnp.swapaxes(v, 0, 1),
+             None if keep is None else keep.astype(jnp.int8)),
             q.shape[1] // KV, q.shape[2] ** -0.5)
 
 
-def _masked_attention_fwd(q, k, v, keep, causal, block_q, block_k, interpret):
+def _masked_attention_fwd(q, k, v, keep, causal, block_q, block_k, interpret,
+                          window):
     _require_block_multiple(q.shape[0], block_q, block_k)
     operands, G, scale = _masked_operands(q, k, v, keep, block_q)
     o, lse = _masked_fwd(*operands, G, scale, causal, block_q, block_k,
-                         interpret)
+                         interpret, window)
     return _unfold_heads(o, G, block_q), (q, k, v, keep, o, lse)
 
 
-def _masked_attention_bwd(causal, block_q, block_k, interpret, res, g):
+def _masked_attention_bwd(causal, block_q, block_k, interpret, window, res,
+                          g):
     q, k, v, keep, o, lse = res
     operands, G, scale = _masked_operands(q, k, v, keep, block_q)
     dq, dk, dv = _masked_bwd(
         *operands, _fold_heads(g, k.shape[1], block_q), o, lse, G, scale,
-        causal, block_q, block_k, interpret)
+        causal, block_q, block_k, interpret, window)
     return (_unfold_heads(dq, G, block_q), jnp.swapaxes(dk, 0, 1),
             jnp.swapaxes(dv, 0, 1), None)
 
@@ -1104,6 +1194,42 @@ def masked_attention(q, k, v, keep, causal: bool = True):
                                     _registry.interpret_mode())
 
 
+def banded_attention(q, k, v, window: Optional[int] = None,
+                     causal: bool = True):
+    """The Pallas body of `banded_attention`: the same kernels and blocks
+    with no mask operand; the caller has resolved the registry."""
+    block_q, block_k = masked_blocks(q.shape[0], q.shape[1] // k.shape[1],
+                                     q.shape[2], q.dtype.itemsize)
+    return _masked_attention_pallas(q, k, v, None, causal, block_q, block_k,
+                                    _registry.interpret_mode(),
+                                    band_window(q.shape[0], window))
+
+
+def band_window(S: int, window: Optional[int]):
+    """`window` as the kernels take it: None where it reaches every earlier
+    key of a sequence of S anyway."""
+    return None if window is None or window >= S else int(window)
+
+
+def band_pairs(S: int, window: Optional[int] = None, causal: bool = True):
+    """(query, key) pairs a layer's attention reads at S positions: row t
+    reads min(t + 1, window) keys under `causal`, all S without."""
+    if not causal:
+        return S * S
+    w = min(window or S, S)
+    return w * (w + 1) // 2 + (S - w) * w
+
+
+def band_fill_share(S: int, G: int, Dh: int, itemsize: int,
+                    window: Optional[int] = None, causal: bool = True):
+    """Pairs inside the band over pairs in the tiles the Pallas body visits
+    at `masked_blocks`' blocks (1.0: no tile holds a pair it masks)."""
+    block_q, block_k = masked_blocks(S, G, Dh, itemsize)
+    visited, _ = _pair_arrays(S // block_q, S // block_k, block_q, block_k,
+                              causal, "row", band_window(S, window))
+    return band_pairs(S, window, causal) / (len(visited) * block_q * block_k)
+
+
 def _masked_pallas_available(backend, shapes, dtypes, meta=(), forced=False):
     """`shapes` is `(S, H, Dh, KV)`."""
     if backend != "tpu" and not forced:
@@ -1136,8 +1262,22 @@ def _masked_xla_available(backend, shapes, dtypes, meta=(), forced=False):
                   "(nn/layers/dsa.py: the parity reference)")
 
 
+def _banded_pallas_available(backend, shapes, dtypes, meta=(), forced=False):
+    """`shapes` is `(S, H, Dh, KV)`: what `masked_attention` takes, the
+    kernels being the same."""
+    ok, why = _masked_pallas_available(backend, shapes, dtypes, meta, forced)
+    if ok and backend == "tpu":
+        why = ("TPU masked flash kernel with no mask operand (the causal "
+               "band from iotas, only the tiles that meet it visited)")
+    return ok, why
+
+
 _registry.register("masked_attention", [
     _registry.KernelImpl("pallas", _masked_pallas_available),
+    _registry.KernelImpl("xla", _masked_xla_available),
+])
+_registry.register("banded_attention", [
+    _registry.KernelImpl("pallas", _banded_pallas_available),
     _registry.KernelImpl("xla", _masked_xla_available),
 ])
 

@@ -57,8 +57,10 @@ MODES = ("auto", "xla", "pallas")
 # exists, and on a TPU `nn/layers/dsa.py`'s attention resolves its Pallas
 # body where it ran XLA row blocks (PR 27). 4: `fused_update` refuses the
 # Pallas body under `auto` for a dispatch with a leaf of a grid block or
-# more, so a step with large layers no longer ravels them (PR 29).
-SELECTION_RULES = 4
+# more, so a step with large layers no longer ravels them (PR 29). 5:
+# `banded_attention` exists, and an extended attention layer without an
+# indexer resolves it where it resolved `masked_attention` (PR 30).
+SELECTION_RULES = 5
 
 # Meta key the registry itself adds to a signature traced under a mesh of
 # more than one device (and `--probe --meta mesh_devices=N` passes by hand).
@@ -81,6 +83,11 @@ KERNEL_MODULES = {
     # sparse-attention layer's `dsa.attend`: same module again; auto
     # off-TPU resolves the XLA row blocks of `nn/layers/dsa.py`.
     "masked_attention": "deeplearning4j_tpu.kernels.flash_attention",
+    # The same kernels with no mask operand (PR 30): a causal, windowed or
+    # bidirectional layer without an indexer (`attn.full`, `attn.sliding`);
+    # the tile's mask comes from iotas and only tiles that meet the band
+    # are visited.
+    "banded_attention": "deeplearning4j_tpu.kernels.flash_attention",
 }
 
 

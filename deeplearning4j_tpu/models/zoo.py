@@ -260,10 +260,12 @@ def sparse_moe_lm(vocab_size: int, *, t: int, d_model: int, n_blocks: int,
                   rms_eps: float = 1e-6, norm_topk_prob: bool = True,
                   aux_loss_weight: float = 1e-3, lr: float = 1e-5,
                   adam_mean_decay: float = 0.9, adam_var_decay: float = 0.95,
-                  seed: int = 123, dtype_policy=None):
-    """Decoder-only sparse-attention mixture-of-experts language model
-    (the Qwen3-MoE block with DeepSeek sparse attention's indexer, as
-    Keye-VL-2.0-30B-A3B's language model has it), built from DSL layers and
+                  seed: int = 123, dtype_policy=None, layer_types=None,
+                  attention_types=None):
+    """Decoder-only mixture-of-experts language model (the Qwen3-MoE block;
+    with DeepSeek sparse attention's indexer as Keye-VL-2.0-30B-A3B's
+    language model has it, or with sliding-window and full layers in a
+    pattern as Mellum2-12B-A2.5B has them), built from DSL layers and
     trained by `ComputationGraph.fit` on integer ids `[B, T]` with integer
     next-token labels `[B, T]`:
 
@@ -274,14 +276,26 @@ def sparse_moe_lm(vocab_size: int, *, t: int, d_model: int, n_blocks: int,
     heads, no biases, RMS norm on each q and k head, rotate-half RoPE; with
     `index_top_k` an indexer of `index_n_heads` x `index_head_dim` keeps
     that many keys per query, and its leaves are frozen (`nn/layers/dsa.py`).
+    `layer_types`: the kind of each block's attention, a list as long as
+    `n_blocks` or one period of it (`["sliding_attention"] * 3 +
+    ["full_attention"]`), and `attention_types` what each kind changes of
+    the `SelfAttentionLayer` above (`{"sliding_attention":
+    {"sliding_window": 1024}, "full_attention": {"rope_scaling": {...}}}`:
+    `sliding_window`, `rope_theta`, `rope_scaling`); without them every
+    block is alike. A layer without an indexer runs the registry's
+    `banded_attention`: the causal triangle or the window's band, the mask
+    from iotas inside the kernel and no `[S, S]` array anywhere.
     MoE: `n_experts` gated SiLU experts of `expert_hidden`, `top_k` per
     token, dropless; `experts_held = (first, count)` keeps only those
     experts' weights here and computes their part of the sum
     (`parallel/expert.py::moe_ffn_dropless`). `vocab_size` is the number of
     embedding and head rows held (a slice of the model's vocabulary).
 
-    Device-trace scopes: `dsa.indexer`, `dsa.select`, `dsa.attend`,
+    Device-trace scopes: `dsa.indexer`, `dsa.select`, `dsa.attend` (under an
+    indexer), `attn.sliding`, `attn.full` (without), `attn.rope`,
     `moe.route`, `moe.experts`, `lm.head`."""
+    import dataclasses
+
     from deeplearning4j_tpu.nn.conf.distributions import NormalDistribution
     from deeplearning4j_tpu.nn.conf.layers import (
         EmbeddingLayer, MoELayer, RMSNormalization, SelfAttentionLayer,
@@ -315,11 +329,17 @@ def sparse_moe_lm(vocab_size: int, *, t: int, d_model: int, n_blocks: int,
         top_k=top_k, dropless=True,
         norm_topk_prob=norm_topk_prob, aux_loss_weight=aux_loss_weight,
         experts_held=None if experts_held is None else tuple(experts_held))
+    kinds = list(layer_types or [None])
+    if n_blocks % len(kinds):
+        raise ValueError(f"layer_types has {len(kinds)} entries: n_blocks "
+                         f"({n_blocks}) is not a whole number of periods")
     prev = "emb"
-    for i in range(n_blocks):
+    for i, kind in enumerate(kinds * (n_blocks // len(kinds))):
         prev = _add_transformer_block(
             gb, prev, i, d_model, n_heads, causal=True,
-            norm=lambda: RMSNormalization(eps=rms_eps), attn=attn, ffn=ffn)
+            norm=lambda: RMSNormalization(eps=rms_eps), ffn=ffn,
+            attn=attn if kind is None else dataclasses.replace(
+                attn, **(attention_types or {}).get(kind, {})))
     gb.add_layer("ln_out", RMSNormalization(eps=rms_eps), prev)
     gb.add_layer("out", RnnOutputLayer(n_out=vocab_size, has_bias=False,
                                        activation="softmax",

@@ -764,13 +764,30 @@ class SelfAttentionLayer(BaseRecurrentLayer):
     index_top_k: Optional[int] = None
     index_n_heads: Optional[int] = None
     index_head_dim: Optional[int] = None
+    # Sliding-window attention: query t reads the keys t - sliding_window <
+    # s <= t (`sliding_window` keys, its own among them: the `transformers`
+    # convention). `rope_scaling`: None, or YaRN's parameters under their
+    # `transformers` names (`rope_type` "yarn", `factor`,
+    # `original_max_position_embeddings`, `beta_fast`, `beta_slow`,
+    # `attention_factor`; `dsa.rope_frequencies` has the equations). A layer
+    # without `index_top_k` runs the registry's `banded_attention`: no
+    # `[S, S]` mask is built, and with a window only the tiles that meet
+    # the band are visited.
+    sliding_window: Optional[int] = None
+    rope_scaling: Optional[dict] = None
 
     INDEXER_PARAMS = ("Wiq", "Wik", "Wiw", "gamma_ik", "beta_ik")
 
     def is_extended(self) -> bool:
         return any(v is not None for v in (
             self.n_kv_heads, self.head_dim, self.rope_theta,
-            self.qk_norm_eps, self.index_top_k))
+            self.qk_norm_eps, self.index_top_k, self.sliding_window,
+            self.rope_scaling))
+
+    def attention_scope(self) -> str:
+        """The `jax.named_scope` around the attention of an extended layer
+        without an indexer, by its kind."""
+        return "attn.full" if self.sliding_window is None else "attn.sliding"
 
     def param_shapes(self):
         if self.is_extended():
@@ -811,9 +828,13 @@ class SelfAttentionLayer(BaseRecurrentLayer):
 
     def state_shapes(self):
         # Mean number of keys a query attends to, of the last forward pass
-        # (`dl4j_dsa_selected_keys_mean`, read where the score is read).
-        return {"selected_keys_mean": ()} if self.index_top_k is not None \
-            else {}
+        # (`dl4j_dsa_selected_keys_mean`, read where the score is read);
+        # without an indexer, the share of the pairs in the tiles the Pallas
+        # body visits that lie inside the causal band, 0 from the XLA body
+        # (`dl4j_attn_band_fill_share`).
+        if self.index_top_k is not None:
+            return {"selected_keys_mean": ()}
+        return {"band_fill_share": ()} if self.is_extended() else {}
 
 
 @register_layer

@@ -37,6 +37,12 @@ LAYER_STATS = {
         "dl4j_dsa_selected_keys_mean",
         "Mean number of keys a query of a sparse attention layer attends "
         "to (last step read)"),
+    "band_fill_share": (
+        "dl4j_attn_band_fill_share",
+        "Pairs inside an attention layer's causal band (or window) over "
+        "pairs in the tiles its Pallas kernel visits: static per layer, "
+        "sequence length and block choice; 1.0 is no wasted tile, 0 the XLA "
+        "body (no tiles)"),
 }
 
 

@@ -1,17 +1,22 @@
-"""Grouped-query attention with rotary positions, QK-norm and a learned
-sparse selection of keys (DeepSeek sparse attention: an indexer beside the
-attention scores every earlier position and each query attends to its
-`index_top_k` best keys only).
+"""Grouped-query attention with rotary positions, QK-norm and one of three
+choices of the keys a query reads: a learned sparse selection (DeepSeek
+sparse attention: an indexer beside the attention scores every earlier
+position and each query attends to its `index_top_k` best keys only), a
+sliding window of the `sliding_window` latest keys, or all of them.
 
 `extended_attention_apply` is `SelfAttentionLayer`'s forward when any of
 its extended fields is set (`nn/conf/layers.py`). One sequence [S, n_in]:
 
     q = h Wq -> [S, H, Dh]    k = h Wk, v = h Wv -> [S, KV, Dh]
-    q, k <- RMSNorm over Dh (gamma_q, gamma_k), then rotate-half RoPE
+    q, k <- RMSNorm over Dh (gamma_q, gamma_k), then rotate-half RoPE at
+             `rope_theta`, its frequencies blended and its cos and sin
+             scaled under a `rope_scaling` (YaRN: `rope_frequencies`)
     indexer  I(t, s) = sum_j w(t, j) IH^-1/2 relu(qi(t, j) . ki(s)) ID^-1/2
              with qi = h Wiq, ki = LayerNorm(h Wik), w = h Wiw, RoPE on qi, ki
     select   S(t) = the index_top_k largest I(t, s) over s <= t, ties to the
-             earlier position; all of s <= t while t < index_top_k
+             earlier position; all of s <= t while t < index_top_k.
+             Without an indexer: S(t) = {s: t - sliding_window < s <= t}, or
+             every s <= t, or every s (`causal=False`)
     attend   o(t, head) = softmax over S(t) of q . k / sqrt(Dh), times v,
              head `h` reading key/value head `h // (H / KV)`
     out = o Wo
@@ -25,8 +30,8 @@ is a constant of the backward pass: the indexer's leaves are frozen
 (`SelfAttentionLayer.frozen_param_names`) and nothing differentiates
 through a comparison.
 
-The attention itself is a dense causal pass under that mask, and no
-[H, S, S] tensor is ever kept. `masked_gqa_attention` resolves the kernel
+Under an indexer the attention is a dense causal pass under that mask, and
+no [H, S, S] tensor is ever kept. `masked_gqa_attention` resolves the kernel
 registry's `masked_attention`: on a TPU, at tile-multiple shapes in bf16 or
 f32, one Pallas flash body forward and backward that takes the [S, S]
 selection as an int8 operand and shares each K, V and mask tile among the
@@ -39,8 +44,19 @@ without it (a bidirectional layer, whose mask is all ones) every key is.
 A gather of `index_top_k` key rows per query would move H/KV times less
 arithmetic and 50 times more bytes (PERF.md PR 26).
 
-`jax.named_scope`s `dsa.indexer`, `dsa.select`, `dsa.attend` name the three
-parts in a device trace.
+A layer WITHOUT an indexer neither builds nor returns an [S, S] array (PR
+30). `banded_gqa_attention` resolves the registry's `banded_attention`: the
+same Pallas kernels with no mask operand, the tile's mask built from two
+iotas and, under a window, only the tiles that meet the band visited; or
+XLA row blocks that read the keys `[lo - window, hi)` of a block of rows
+`[lo, hi)` only. The layer's declared state is then `band_fill_share`, the
+share of the pairs in the tiles the Pallas body visits that the band holds
+(static; 0 where the XLA body ran: it visits no tiles).
+
+`jax.named_scope`s name the parts in a device trace: `dsa.indexer`,
+`dsa.select`, `dsa.attend` under an indexer; `attn.sliding` or `attn.full`
+around the attention of a layer without one; `attn.rope` around the rotary
+step of either.
 """
 
 from __future__ import annotations
@@ -68,16 +84,56 @@ def layer_norm(x, gamma, beta, eps):
     return (y * gamma.astype(acc) + beta.astype(acc)).astype(x.dtype)
 
 
-def rope(x, theta: float):
+def rope_frequencies(D: int, theta: float, scaling, dtype):
+    """(inverse frequencies [D/2], the factor on cos and sin) of a rotary
+    embedding over heads of D. `scaling` None: theta^(-2i/D) and 1. YaRN
+    (Peng et al. 2023, as `transformers` computes it; keys `factor`,
+    `original_max_position_embeddings`, `beta_fast`, `beta_slow`,
+    `attention_factor`): with c(r) = D ln(L0 / (2 pi r)) /
+    (2 ln theta), low = floor(c(beta_fast)), high = ceil(c(beta_slow)),
+    both clipped to [0, D - 1], ramp_i = clip((i - low) / (high - low), 0,
+    1), frequency i is theta^(-2i/D) (1 - ramp_i) + theta^(-2i/D) / factor
+    ramp_i: the fast dimensions keep their frequency, the slow ones are
+    interpolated; cos and sin are multiplied by `attention_factor`
+    (default 0.1 ln factor + 1)."""
+    import math
+
+    inv = theta ** (-jnp.arange(0, D, 2, dtype=dtype) / D)          # [D/2]
+    if scaling is None:
+        return inv, 1.0
+    if scaling.get("rope_type", "yarn") != "yarn":
+        raise ValueError(f"rope_scaling {scaling.get('rope_type')!r}: only "
+                         "YaRN is implemented")
+    factor = scaling["factor"]
+    L0 = scaling["original_max_position_embeddings"]
+
+    def c(rotations):
+        return D * math.log(L0 / (2 * math.pi * rotations)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(c(scaling.get("beta_fast", 32))), 0)
+    high = min(math.ceil(c(scaling.get("beta_slow", 1))), D - 1)
+    ramp = jnp.clip((jnp.arange(D // 2, dtype=dtype) - low)
+                    / (high - low if high != low else 0.001), 0.0, 1.0)
+    mscale = scaling.get("attention_factor")
+    if mscale is None:
+        mscale = 0.1 * math.log(factor) + 1.0 if factor > 1 else 1.0
+    return inv * (1 - ramp) + inv / factor * ramp, mscale
+
+
+def rope(x, theta: float, scaling=None):
     """Rotate-half rotary embedding over the last axis of [S, ..., D]
-    (position t on axis 0): pairs (i, i + D/2) turn by t * theta^(-2i/D)."""
+    (position t on axis 0): pairs (i, i + D/2) turn by t * theta^(-2i/D),
+    or by `rope_frequencies`' under a `scaling`."""
     S, D = x.shape[0], x.shape[-1]
     acc = jnp.promote_types(x.dtype, jnp.float32)
     t = jnp.arange(S, dtype=acc)
-    inv = theta ** (-jnp.arange(0, D, 2, dtype=acc) / D)          # [D/2]
+    inv, mscale = rope_frequencies(D, theta, scaling, acc)
     ang = t[:, None] * inv[None, :]                                # [S, D/2]
     shape = (S,) + (1,) * (x.ndim - 2) + (D // 2,)
     cos, sin = jnp.cos(ang).reshape(shape), jnp.sin(ang).reshape(shape)
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     xf = x.astype(acc)
     x1, x2 = xf[..., : D // 2], xf[..., D // 2:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
@@ -182,59 +238,150 @@ def masked_gqa_attention(q, k, v, keep, causal: bool = True):
     return masked_gqa_attention_xla(q, k, v, keep, causal)
 
 
-def masked_gqa_attention_xla(q, k, v, keep, causal: bool = True, *,
-                             block: int = 256, span: int = 2048):
-    """`masked_gqa_attention` in XLA row blocks: blocks of `block` rows, each
-    recomputed in the backward pass; the blocks of one `span` of rows run as
-    a loop over the keys up to the span's end, or over all of them where
-    not `causal` (one compiled body a span: blocks of 256 over exactly their
-    own keys were 13% faster on a v5e and eight times the program, PERF.md
-    PR 26)."""
+def _grouped(q, k, v):
+    """[S, H, Dh], [S, KV, Dh] x 2 -> q as [KV, G, S, Dh], k and v as
+    [KV, S, Dh]."""
     S, H, Dh = q.shape
     KV = k.shape[1]
-    G = H // KV
-    acc = jnp.promote_types(q.dtype, jnp.float32)
-    scale = Dh ** -0.5
-    qg = jnp.transpose(q.reshape(S, KV, G, Dh), (1, 2, 0, 3))    # [KV,G,S,Dh]
-    kg = jnp.transpose(k, (1, 0, 2))                              # [KV,S,Dh]
-    vg = jnp.transpose(v, (1, 0, 2))
+    return (jnp.transpose(q.reshape(S, KV, H // KV, Dh), (1, 2, 0, 3)),
+            jnp.transpose(k, (1, 0, 2)), jnp.transpose(v, (1, 0, 2)))
 
-    @jax.checkpoint
-    def rows(qb, kb, vb, mb):
-        s = jnp.einsum("ghtd,gsd->ghts", qb, kb,
-                       preferred_element_type=acc) * scale
+
+def _ungrouped(o):
+    """[KV, G, S, Dh] -> [S, H, Dh]."""
+    KV, G, S, Dh = o.shape
+    return jnp.transpose(o, (2, 0, 1, 3)).reshape(S, KV * G, Dh)
+
+
+@jax.checkpoint
+def _rows_attention(qb, kb, vb, mb):
+    """One block of rows over the keys it is given, recomputed in the
+    backward pass. qb: [KV, G, b, Dh]; kb, vb: [KV, n, Dh]; mb: [b, n] bool
+    or None (every key) -> [KV, G, b, Dh]."""
+    acc = jnp.promote_types(qb.dtype, jnp.float32)
+    s = jnp.einsum("ghtd,gsd->ghts", qb, kb,
+                   preferred_element_type=acc) * qb.shape[-1] ** -0.5
+    if mb is not None:
         s = jnp.where(mb[None, None], s, _NEG)
-        m = jnp.max(s, axis=-1, keepdims=True)
-        e = jnp.exp(s - m)
-        den = jnp.sum(e, axis=-1, keepdims=True)
-        o = jnp.einsum("ghts,gsd->ghtd", e.astype(vb.dtype), vb,
-                       preferred_element_type=acc)
-        return (o / den).astype(qb.dtype)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    e = jnp.exp(s - m)
+    den = jnp.sum(e, axis=-1, keepdims=True)
+    o = jnp.einsum("ghts,gsd->ghtd", e.astype(vb.dtype), vb,
+                   preferred_element_type=acc)
+    return (o / den).astype(qb.dtype)
 
+
+def _blocks_of_rows(qg, lo, hi, block):
+    """Rows lo..hi of `qg` [KV, G, S, Dh] as [n, KV, G, b, Dh] blocks."""
+    KV, G, _, Dh = qg.shape
+    blocks = _row_blocks(hi - lo, block)
+    n, b = len(blocks), blocks[0][1]
+    return jnp.moveaxis(qg[:, :, lo:hi].reshape(KV, G, n, b, Dh), 2, 0), n, b
+
+
+def _span_attention(q, k, v, causal, block, span, per_block, mask_of):
+    """Attention in blocks of `block` rows, each recomputed in the backward
+    pass; the blocks of one `span` of rows run as a loop over the keys up to
+    the span's end, or over all of them where not `causal` (one compiled
+    body a span: blocks of 256 over exactly their own keys were 13% faster
+    on a v5e and eight times the program, PERF.md PR 26).
+    `per_block(lo, hi, n, b, keys)` gives what the loop carries for each of
+    a span's n blocks, `mask_of(that, b, keys)` a block's `[b, keys]` mask."""
+    S = q.shape[0]
+    qg, kg, vg = _grouped(q, k, v)
     out = []
     for lo, hi in _row_blocks(S, span):
-        blocks = _row_blocks(hi - lo, block)
-        n, b = len(blocks), blocks[0][1]
         keys = hi if causal else S
-        qs = jnp.moveaxis(qg[:, :, lo:hi].reshape(KV, G, n, b, Dh), 2, 0)
-        ms = keep[lo:hi, :keys].reshape(n, b, keys)
+        qs, n, b = _blocks_of_rows(qg, lo, hi, block)
         o = jax.lax.map(
-            lambda a: rows(a[0], kg[:, :keys], vg[:, :keys], a[1]),
-            (qs, ms))                                    # [n, KV, G, b, Dh]
-        out.append(jnp.moveaxis(o, 0, 2).reshape(KV, G, hi - lo, Dh))
-    o = jnp.concatenate(out, axis=2)                              # [KV,G,S,Dh]
-    return jnp.transpose(o, (2, 0, 1, 3)).reshape(S, H, Dh)
+            lambda a, b=b, keys=keys: _rows_attention(
+                a[0], kg[:, :keys], vg[:, :keys], mask_of(a[1], b, keys)),
+            (qs, per_block(lo, hi, n, b, keys)))         # [n, KV, G, b, Dh]
+        out.append(jnp.moveaxis(o, 0, 2).reshape(*qg.shape[:2], hi - lo, -1))
+    return _ungrouped(jnp.concatenate(out, axis=2))
+
+
+def masked_gqa_attention_xla(q, k, v, keep, causal: bool = True, *,
+                             block: int = 256, span: int = 2048):
+    """`masked_gqa_attention` in XLA row blocks (`_span_attention`), a
+    block's mask its rows of `keep`."""
+    return _span_attention(
+        q, k, v, causal, block, span,
+        lambda lo, hi, n, b, keys: keep[lo:hi, :keys].reshape(n, b, keys),
+        lambda rows, b, keys: rows)
+
+
+def banded_gqa_attention(q, k, v, window=None, causal: bool = True):
+    """Attention over the causal triangle, over the band `t - window < s <=
+    t` of a sliding-window layer, or over every key (`causal=False`), with
+    no `[S, S]` array anywhere. q: [S, H, Dh]; k, v: [S, KV, Dh] ->
+    ([S, H, Dh], the share of the pairs in the tiles the Pallas body visits
+    that lie inside the band: a float, static; 0.0 from the XLA body, which
+    visits no tiles). The registry's `banded_attention` decides
+    between the Pallas flash body (`kernels/flash_attention.py`: the masked
+    kernels with the tile's mask from iotas, only the tiles that meet the
+    band visited) and `banded_gqa_attention_xla`."""
+    from deeplearning4j_tpu.kernels import flash_attention, registry
+
+    if window is not None and not causal:
+        raise ValueError("sliding_window reads earlier keys: the layer must "
+                         "be causal")
+    res = registry.resolve(
+        "banded_attention", dtypes=(str(q.dtype),),
+        shapes=tuple(int(d) for d in q.shape) + (int(k.shape[1]),))
+    S, H, Dh = q.shape
+    if res.impl == "pallas":
+        return (flash_attention.banded_attention(q, k, v, window, causal),
+                flash_attention.band_fill_share(
+                    S, H // k.shape[1], Dh, q.dtype.itemsize, window, causal))
+    return banded_gqa_attention_xla(q, k, v, window, causal), 0.0
+
+
+def _window_reach(window: int, block: int) -> int:
+    """Keys before a block's first row that its rows read under a window:
+    window - 1, rounded up to whole blocks."""
+    return -(-(window - 1) // block) * block
+
+
+def banded_gqa_attention_xla(q, k, v, window=None, causal: bool = True, *,
+                             block: int = 256, span: int = 2048):
+    """`banded_gqa_attention` in XLA row blocks, each recomputed in the
+    backward pass, the mask of a block built from its rows' and keys'
+    positions. Under a window shorter than the sequence a block of rows
+    `[lo, hi)` reads only the keys `[lo - W, hi)`, W the window rounded up
+    to whole blocks (one compiled body, the keys padded by W in front);
+    else `_span_attention`, as `masked_gqa_attention_xla`."""
+    S = q.shape[0]
+    if window is None or window >= S:
+        return _span_attention(
+            q, k, v, causal, block, span,
+            lambda lo, hi, n, b, keys: lo + jnp.arange(n) * b,
+            lambda r0, b, keys: (jnp.arange(keys)[None, :]
+                                 <= r0 + jnp.arange(b)[:, None])
+            if causal else None)
+    qg, kg, vg = _grouped(q, k, v)
+    qs, n, b = _blocks_of_rows(qg, 0, S, block)
+    W = _window_reach(window, b)
+    pad = lambda a: jnp.pad(a, ((0, 0), (W, 0), (0, 0)))
+    kp, vp = pad(kg), pad(vg)
+
+    def one(a):
+        qb, r0 = a
+        rows = r0 + jnp.arange(b)[:, None]
+        cols = r0 - W + jnp.arange(W + b)[None, :]
+        keep = (cols >= 0) & (cols <= rows) & (cols > rows - window)
+        keys = lambda x: jax.lax.dynamic_slice_in_dim(x, r0, W + b, 1)
+        return _rows_attention(qb, keys(kp), keys(vp), keep)
+
+    o = jax.lax.map(one, (qs, jnp.arange(n) * b))        # [n, KV, G, b, Dh]
+    return _ungrouped(jnp.moveaxis(o, 0, 2).reshape(qg.shape))
 
 
 def select_keys(conf, params, h):
-    """h: [S, n_in] -> bool [S, S], the keys each query attends to: the
-    indexer's selection S(t) with `index_top_k`, else the causal triangle
-    (or everything). A constant of the backward pass."""
+    """h: [S, n_in] -> bool [S, S], the keys each query of a layer with
+    `index_top_k` attends to: the indexer's selection S(t). A constant of
+    the backward pass."""
     S = h.shape[0]
-    if conf.index_top_k is None:
-        if conf.causal:
-            return jnp.arange(S)[None, :] <= jnp.arange(S)[:, None]
-        return jnp.ones((S, S), bool)
     IH, ID = conf.index_n_heads, conf.index_head_dim
     with jax.named_scope("dsa.indexer"):
         hs = jax.lax.stop_gradient(h)
@@ -252,7 +399,10 @@ def select_keys(conf, params, h):
 
 def _one_sequence(conf, params, h):
     """h: [S, n_in] -> (out [S, n_out], the keys each query attended to as
-    bool [S, S], their mean number a query or None)."""
+    bool [S, S], their mean number a query) under an indexer; without one
+    (out, None, the share of the pairs in the tiles its attention's Pallas
+    body visits that lie inside the causal band: static per layer, sequence
+    length and block choice; 0 from the XLA body)."""
     S = h.shape[0]
     H = conf.n_heads
     KV = conf.n_kv_heads or H
@@ -265,16 +415,23 @@ def _one_sequence(conf, params, h):
         q = rms_norm(q, params["gamma_q"], conf.qk_norm_eps)
         k = rms_norm(k, params["gamma_k"], conf.qk_norm_eps)
     if conf.rope_theta is not None:
-        q, k = rope(q, conf.rope_theta), rope(k, conf.rope_theta)
+        with jax.named_scope("attn.rope"):
+            q = rope(q, conf.rope_theta, conf.rope_scaling)
+            k = rope(k, conf.rope_theta, conf.rope_scaling)
 
-    selected = None
-    keep = select_keys(conf, params, h)
-    if conf.index_top_k is not None:
-        selected = jnp.mean(jnp.sum(
-            keep, axis=1, dtype=jnp.promote_types(h.dtype, jnp.float32)))
-    with jax.named_scope("dsa.attend"):
-        o = masked_gqa_attention(q, k, v, keep, conf.causal)
-    return o.reshape(S, H * Dh) @ params["Wo"], keep, selected
+    acc = jnp.promote_types(h.dtype, jnp.float32)
+    if conf.index_top_k is None:
+        keep = None
+        with jax.named_scope(conf.attention_scope()):
+            o, fill = banded_gqa_attention(q, k, v, conf.sliding_window,
+                                           conf.causal)
+        stat = jnp.asarray(fill, acc)
+    else:
+        keep = select_keys(conf, params, h)
+        stat = jnp.mean(jnp.sum(keep, axis=1, dtype=acc))
+        with jax.named_scope("dsa.attend"):
+            o = masked_gqa_attention(q, k, v, keep, conf.causal)
+    return o.reshape(S, H * Dh) @ params["Wo"], keep, stat
 
 
 def extended_attention_apply(conf, params, state, x, mask=None):
@@ -291,19 +448,22 @@ def extended_attention_apply(conf, params, state, x, mask=None):
     if conf.index_top_k is not None and not conf.causal:
         raise ValueError("index_top_k selects among earlier positions: the "
                          "layer must be causal")
+    if conf.index_top_k is not None and conf.sliding_window is not None:
+        raise ValueError("a layer selects its keys by index_top_k or by "
+                         "sliding_window, not both")
     if x.shape[0] == 1:
-        out, keep, selected = _one_sequence(conf, params, x[0])
-        out, keep = out[None], keep[None]
+        out, keep, stat = _one_sequence(conf, params, x[0])
+        out = out[None]
+        keep = None if keep is None else keep[None]
     else:
-        out, keep, selected = jax.vmap(
+        out, keep, stat = jax.vmap(
             lambda h: _one_sequence(conf, params, h))(x)
-        if selected is not None:
-            selected = jnp.mean(selected)
+        stat = jnp.mean(stat)
     out = activations.resolve(conf.activation)(out)
+    if keep is None:
+        return out, dict(state, band_fill_share=stat), mask
     # `_selected_keys` is a by-product: no engine keeps it as state, and
     # `ComputationGraph.loss_and_gradients(collect=["<layer>.selected_keys"])`
     # hands it out of the pass that used it.
-    new_state = dict(state, _selected_keys=keep)
-    if selected is not None:
-        new_state["selected_keys_mean"] = selected
-    return out, new_state, mask
+    return out, dict(state, _selected_keys=keep,
+                     selected_keys_mean=stat), mask

@@ -496,6 +496,100 @@ def test_sparse_attention_layer_runs_three_kernels_under_its_scope(
     assert sum("transpose(" in c for c in calls) == 2, calls
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("window", [1024, None], ids=["window-1024", "full"])
+def test_banded_attention_forward_backward(chip, window, dtype):
+    """The three kernels of `banded_attention` (the masked kernels with no
+    mask operand: forward, dq, dk/dv) at `mellum2_12b_a2_5b.fit_seq16k`'s
+    shapes, a sliding layer's visit list and the full layer's; no `[S, S]`
+    array is an operand or a temporary."""
+    S, H, KV, Dh = 16384, 32, 4, 128
+    ok, why = fa._banded_pallas_available(
+        "tpu", (S, H, Dh, KV), (jnp.dtype(dtype).name,))
+    assert ok, why
+    block_q, block_k = fa.masked_blocks(S, H // KV, Dh,
+                                        jnp.dtype(dtype).itemsize)
+
+    def loss(q, k, v):
+        o = fa._masked_attention_pallas(q, k, v, None, True, block_q,
+                                        block_k, False, window)
+        return jnp.sum(o.astype(jnp.float32))
+
+    exe = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        chip((S, H, Dh), dtype), chip((S, KV, Dh), dtype),
+        chip((S, KV, Dh), dtype)).compile()
+    text = exe.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for name in ("banded_attention_fwd", "banded_attention_dq",
+                 "banded_attention_dkv"):
+        assert name in text
+    assert "masked_attention_dq" not in text
+    assert f"[{S},{S}]" not in text
+    # the folded copies of q, o and their cotangents, and lse and d_row as
+    # `[KV, G*S, 1]` float32 columns, which the chip pads to 128 lanes (0.27
+    # GB each); an int8 [S, S] mask and its transpose would be 0.54 GB more
+    assert exe.memory_analysis().temp_size_in_bytes \
+        < 3 * S * H * Dh * jnp.dtype(dtype).itemsize + 0.6e9
+
+
+@pytest.mark.parametrize("kind,window,scaling", [
+    ("attn.sliding", 1024, None),
+    ("attn.full", None, {"rope_type": "yarn", "factor": 16,
+                         "original_max_position_embeddings": 8192,
+                         "beta_fast": 32, "beta_slow": 1,
+                         "attention_factor": 1.2772588722239782})])
+def test_windowed_and_full_layers_run_three_kernels_under_their_scope(
+        chip, monkeypatch, kind, window, scaling):
+    """`SelfAttentionLayer` without an indexer at the cell's widths, traced
+    as a TPU process would trace it: `banded_attention` resolves `pallas`,
+    the compiled gradient holds the forward and both backward kernels with
+    the layer kind's scope in their `op_name` (how `scope_time.py` finds
+    their time), the rotary step carries `attn.rope`, and nothing is
+    `[S, S]`."""
+    import re
+
+    from deeplearning4j_tpu import observability as obs
+    from deeplearning4j_tpu.kernels import registry
+    from deeplearning4j_tpu.nn.conf.layers import SelfAttentionLayer
+    from deeplearning4j_tpu.nn.layers import dsa
+
+    monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")
+    monkeypatch.delenv("DL4J_TPU_KERNELS", raising=False)
+    monkeypatch.delenv("DL4J_TPU_KERNEL_BANDED_ATTENTION", raising=False)
+    registry.clear_cache()
+    S, D = 16384, 2304
+    conf = SelfAttentionLayer(
+        n_in=D, n_out=D, n_heads=32, n_kv_heads=4, head_dim=128,
+        rope_theta=5e5, qk_norm_eps=1e-6, causal=True, sliding_window=window,
+        rope_scaling=scaling)
+    bf = jnp.bfloat16
+
+    def loss(params, x):
+        out, state, _ = dsa.extended_attention_apply(conf, params, {}, x)
+        assert set(state) == {"band_fill_share"}
+        return jnp.sum(out.astype(jnp.float32))
+
+    def count(impl):
+        fam = obs.metrics.get_family("dl4j_kernel_dispatch_total")
+        return sum(c.get() for c in fam.children() if c.labels == {
+            "kernel": "banded_attention", "impl": impl})
+
+    before = count("pallas"), count("xla")
+    exe = jax.jit(jax.grad(loss)).lower(
+        {n: chip(shape, bf) for n, shape in conf.param_shapes().items()},
+        chip((1, S, D), bf)).compile()
+    registry.clear_cache()
+    assert (count("pallas"), count("xla")) == (before[0] + 1, before[1])
+    text = exe.as_text()
+    calls = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
+    assert len(calls) == 3, calls
+    assert all(kind in c and "banded_attention" in c for c in calls), calls
+    assert sum("transpose(" in c for c in calls) == 2, calls
+    assert "attn.rope" in text and f"[{S},{S}]" not in text
+
+
 def test_dropless_experts_at_published_widths(chip):
     """`expert.moe_ffn_dropless`: 8,192 tokens, 128 experts top-8, 16 held;
     the grouped products compile for the chip as XLA's ragged dot, which the

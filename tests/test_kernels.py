@@ -570,7 +570,8 @@ class TestDispatchMetric:
 # for bottleneck_block, in test_bottleneck_block.py).
 PARITY_COVERED = {"lstm_cell", "fused_update", "norm_act", "flash_attention",
                   "flash_attention_paged", "bottleneck_block",
-                  "masked_attention"}      # test_masked_attention.py
+                  "masked_attention",      # test_masked_attention.py
+                  "banded_attention"}      # test_banded_attention.py
 
 
 def test_every_kernel_has_parity_coverage():

@@ -146,10 +146,10 @@ def test_a_layer_that_is_not_causal_reads_the_keys_after_a_row(rng, kind, g,
 
 
 def test_bidirectional_layer_forced_to_the_pallas_body(rng, monkeypatch):
-    """`SelfAttentionLayer(n_kv_heads=..., causal=False)` hands the kernel
-    an all-ones `keep` and its `causal`: the forced Pallas body gives the
-    XLA body's full attention, output and gradient, and position 0 sees the
-    last position's value."""
+    """`SelfAttentionLayer(n_kv_heads=..., causal=False)` has no indexer, so
+    it resolves `banded_attention` and hands the kernel its `causal` and no
+    mask: the forced Pallas body gives the XLA body's full attention, output
+    and gradient, and position 0 sees the last position's value."""
     from deeplearning4j_tpu.nn.conf.layers import SelfAttentionLayer
 
     conf = SelfAttentionLayer(n_in=32, n_out=32, n_heads=4, n_kv_heads=2,
@@ -162,13 +162,15 @@ def test_bidirectional_layer_forced_to_the_pallas_body(rng, monkeypatch):
         return dsa.extended_attention_apply(conf, params, {}, x)[0]
 
     def both(impl):
-        monkeypatch.setenv("DL4J_TPU_KERNEL_MASKED_ATTENTION", impl)
+        monkeypatch.setenv("DL4J_TPU_KERNEL_BANDED_ATTENTION", impl)
         registry.clear_cache()
         return run(x), jax.grad(lambda x: jnp.sum(run(x) ** 2))(x)
 
-    before = _dispatches("pallas")
+    before = _dispatches("pallas", "banded_attention")
+    masked = _dispatches("pallas") + _dispatches("xla")
     got, want = both("pallas"), both("xla")
-    assert _dispatches("pallas") == before + 2
+    assert _dispatches("pallas", "banded_attention") == before + 2
+    assert _dispatches("pallas") + _dispatches("xla") == masked
     for a, b in zip(got, want):
         _close(a, b, "float32")
     assert not np.allclose(np.asarray(run(x.at[0, -1].add(1.0))[0, 0]),
@@ -211,10 +213,10 @@ def test_tiles_wholly_masked_for_some_rows_carry_no_weight(rng):
     assert np.asarray(dk).any() and np.asarray(dv).any()
 
 
-def _dispatches(impl):
+def _dispatches(impl, kernel="masked_attention"):
     fam = obs.metrics.get_family("dl4j_kernel_dispatch_total")
     return sum(c.get() for c in fam.children()
-               if c.labels == {"kernel": "masked_attention", "impl": impl})
+               if c.labels == {"kernel": kernel, "impl": impl})
 
 
 def test_auto_off_the_tpu_runs_the_xla_body_bit_for_bit(rng, monkeypatch):
