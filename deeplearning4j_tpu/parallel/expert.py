@@ -18,6 +18,7 @@ through the top-k selection.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -173,26 +174,114 @@ def route_top_k(gate_w, x, top_k: int, norm_topk_prob: bool = True):
     return probs, gate, idx
 
 
-@jax.custom_vjp
-def _permute_rows(x, perm, inverse):
-    """`x[perm]` for a permutation `perm` of the rows with `inverse` its
-    inverse: the backward pass is a gather by `inverse` and not the
-    scatter-add a gather transposes to (65,536 rows scattered into a
-    [8192, 2048] f32 took 4.8 ms on a v5e, eight times a step; PERF.md PR
-    26)."""
-    return x[perm]
+def _rows_of_pairs(src, token):
+    """`src[token]`: each sorted pair's row of the `[N, D]` source, by the
+    pair's token, with no `jnp.repeat(src, top_k)` in front. A chip's tokens
+    fit its fast memory (16,384 rows of 2,304 in bfloat16 are 75 MB), and
+    XLA's gather from there runs at copy speed: 1.0 ms for 131,072 rows,
+    where the same rows gathered out of the repeated `[N * top_k, D]` array
+    took 5.9 ms, from a float32 source of twice the bytes 5.8 ms, and chunk
+    after chunk over a live prefix of 48% in a loop 2.7 ms (PERF.md PR 31).
+    So the gather is whole: the rows of pairs held elsewhere come along,
+    finite and never read."""
+    return src[token]
 
 
-def _permute_rows_fwd(x, perm, inverse):
-    return x[perm], (perm, inverse)
+def _permute_scalars(v, inverse):
+    """`v[perm]` for a permutation `perm` given its `inverse`, as a sort of
+    `v` keyed by `inverse`: 0.27 ms for 131,072 float32 scalars on a v5e
+    where XLA's gather of them took 1.2 ms (PERF.md PR 31)."""
+    return jax.lax.sort((inverse, v), num_keys=1)[1]
 
 
-def _permute_rows_bwd(res, g):
-    perm, inverse = res
-    return g[inverse], None, None
+_GROUPS_CONTRACTED = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
 
 
-_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+def _table_gradient(rows, ct, group_sizes):
+    """[M, A], [M, B] -> [G, A, B]: each group's rows contracted, what
+    `jax.lax.ragged_dot`'s own transpose makes of a table's cotangent."""
+    return jax.lax.ragged_dot_general(rows, ct, group_sizes,
+                                      _GROUPS_CONTRACTED)
+
+
+def _grouped_ffn(x, tables, token, group_sizes):
+    """The held experts over the sorted pairs: the rows, the two inner
+    products and the output, each `[N * top_k, ...]` in expert order. A
+    grouped product writes the rows of its groups, the live prefix, and
+    leaves the rest of its result as the buffer was."""
+    w_gate, w_up, w_down = tables
+    rows = _rows_of_pairs(x, token)
+    g = jax.lax.ragged_dot(rows, w_gate, group_sizes)
+    u = jax.lax.ragged_dot(rows, w_up, group_sizes)
+    out = jax.lax.ragged_dot(jax.nn.silu(g) * u, w_down, group_sizes)
+    return rows, g, u, out
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_experts(top_k, x, gate, tables, order, inverse, group_sizes):
+    """`moe_ffn_dropless`'s expert block: x [N, D], gate [N, top_k] in the
+    accumulator dtype, the three held tables in x's dtype, and the sort of
+    the pairs (`order`, its `inverse`, the held experts' `group_sizes`) ->
+    y [N, D_out] in x's dtype. Its backward pass is written by hand
+    (`_held_experts_bwd`)."""
+    return _held_experts_fwd(top_k, x, gate, tables, order, inverse,
+                             group_sizes)[0]
+
+
+def _held_experts_fwd(top_k, x, gate, tables, order, inverse, group_sizes):
+    N = x.shape[0]
+    n_held = jnp.sum(group_sizes)
+    *_, out = _grouped_ffn(x, tables, order // top_k, group_sizes)
+    # Back to (token, slot) order, whole: live pairs are no prefix there. A
+    # pair held elsewhere reads a row nobody wrote and is masked here, in
+    # the pass that sums a token's slots.
+    held = (inverse < n_held).reshape(N, top_k, 1)
+    out = out[inverse].reshape(N, top_k, -1).astype(gate.dtype)
+    y = jnp.sum(jnp.where(held, out * gate[:, :, None], 0), axis=1)
+    # no [N * top_k, ...] tensor among the residuals
+    return y.astype(x.dtype), (x, gate, tables, order, inverse, group_sizes)
+
+
+def _held_experts_bwd(top_k, res, dy):
+    x, gate, tables, order, inverse, group_sizes = res
+    N, acc = x.shape[0], gate.dtype
+    n_held = jnp.sum(group_sizes)
+    token = order // top_k
+    held = (inverse < n_held).reshape(N, top_k)
+    # dy in expert order first: with `out` beside it the gate's gradient
+    # needs no second trip of `out` to token order.
+    dy_rows = _rows_of_pairs(dy, token)
+    # The recomputation starts from operands XLA cannot tell from the forward
+    # pass's and that exist only once `dy_rows` does (what `jax.checkpoint`
+    # does for its own): merged with the forward pass's, its [N * top_k, ...]
+    # arrays live from one pass to the other, 3.3 GB more temporaries in the
+    # step of `mellum2_12b_a2_5b.fit_seq16k` (PERF.md PR 31).
+    x, tables, dy_rows = jax.lax.optimization_barrier((x, tables, dy_rows))
+    w_gate, w_up, w_down = (jnp.swapaxes(w, 1, 2) for w in tables)
+    rows, g, u, out = _grouped_ffn(x, tables, token, group_sizes)
+    hmid, silu_mul_vjp = jax.vjp(lambda g, u: jax.nn.silu(g) * u, g, u)
+    dy_rows = dy_rows.astype(acc)
+    dgate = jnp.sum(out.astype(acc) * dy_rows, axis=-1)
+    dgate = jnp.where(
+        held, _permute_scalars(dgate, order).reshape(N, top_k), 0)
+    gate = _permute_scalars(gate.reshape(-1), inverse)
+    dout = (dy_rows * gate[:, None]).astype(out.dtype)
+
+    dhmid = jax.lax.ragged_dot(dout, w_down, group_sizes)
+    dg, du = silu_mul_vjp(dhmid)
+    drows = (jax.lax.ragged_dot(dg, w_gate, group_sizes)
+             + jax.lax.ragged_dot(du, w_up, group_sizes))
+    dtables = (_table_gradient(rows, dg, group_sizes),
+               _table_gradient(rows, du, group_sizes),
+               _table_gradient(hmid, dout, group_sizes))
+    drows = drows[inverse].reshape(N, top_k, -1)
+    dx = jnp.sum(jnp.where(held[:, :, None], drows, 0), axis=1, dtype=acc)
+    return dx.astype(x.dtype), dgate, dtables, None, None, None
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 def moe_ffn_dropless(params, x, *, top_k: int, first=0,
@@ -208,13 +297,35 @@ def moe_ffn_dropless(params, x, *, top_k: int, first=0,
     Routing: softmax over the E logits in >= float32, the `top_k` largest
     per token, their values renormalised to sum to 1 with
     `norm_topk_prob`. No capacity, no dropped token: the (token, expert)
-    pairs are sorted by expert, pairs of experts held elsewhere last, each
-    matrix is one grouped product (`jax.lax.ragged_dot`, which on the TPU
-    skips the rows past the groups: 65,536 rows of which 8,192 are live cost
-    what 10,240 rows do, PERF.md PR 26), and the rows, put back in their
-    tokens' order, are summed over each token's slots weighted by the gate
-    values. The grouped part is recomputed in the backward pass so that no
-    [N * top_k, ...] tensor is kept.
+    pairs are sorted by expert, pairs of experts held elsewhere last, so
+    the pairs held here are the first `n_held` (a traced count) of the
+    sorted axis: the live prefix. What is defined of the `[N * top_k, ...]`
+    arrays in expert order, stage by stage:
+
+    - the rows are gathered whole from `x` by token index
+      (`_rows_of_pairs`), no repeat of `x` in front: every row is some
+      token's, those past the prefix no held expert's;
+    - each matrix is one grouped product (`jax.lax.ragged_dot`), which on
+      the TPU skips the rows past the groups and leaves them unwritten:
+      65,536 rows of which 8,192 are live cost what 10,240 rows do (PERF.md
+      PR 26). Its result is defined on the live prefix only, and so is
+      everything computed from it; with the groups contracted (the tables'
+      gradients) it reads no row past them either, NaN there or not
+      (shown on the chip, PERF.md PR 31);
+    - nothing zeroes a dead row in expert order. A dead pair is masked where
+      it is consumed in token order, by a `where` on its held bit inside the
+      pass that exists anyway (never a multiply: a dead row may hold NaN):
+      the weighted sum over a token's slots forward, the sum over slots that
+      makes `dx` and the `N * top_k` scalars of the gate's gradient
+      backward.
+
+    The two gathers back to token order (the output forward, the rows'
+    cotangent backward) read an `[N * top_k, D]` source, which fits no fast
+    memory, and cost by the row. The backward pass is written by hand
+    (`_held_experts_bwd`): it keeps `x`, the gate values, the tables and the
+    sort's integer vectors, no `[N * top_k, ...]` tensor, recomputes the
+    grouped part, and takes the gate's gradient in expert order from the
+    recomputed output and the gathered cotangent.
 
     Returns `(y [N, D_out], aux, stats, idx)`: `aux = E * sum_e f_e * P_e`
     over all E experts with f_e the pairs routed to e per token and P_e the
@@ -239,33 +350,15 @@ def moe_ffn_dropless(params, x, *, top_k: int, first=0,
         inverse = jnp.argsort(order)
         group_sizes = jnp.zeros((Eh + 1,), jnp.int32).at[local].add(1)[:Eh]
         n_held = jnp.sum(group_sizes)
-        live = jnp.arange(N * top_k) < n_held
         loads = group_sizes.astype(acc)
         stats = (n_held.astype(acc) / (N * top_k),
                  jnp.max(loads) / jnp.maximum(jnp.mean(loads), 1e-9))
 
-    @jax.checkpoint
-    def experts(x, gate, tables):
-        # Every pair's row, sorted by expert. Rows past the groups are no
-        # expert's: a grouped product leaves them unwritten (whatever the
-        # buffer held), forward and backward. They enter as zeros, so their
-        # cotangent is dropped on the way back, and they leave as zeros.
-        rows = _permute_rows(jnp.repeat(x, top_k, axis=0), order, inverse)
-        rows = jnp.where(live[:, None], rows, 0)              # [N * k, D]
-        w_gate, w_up, w_down = tables
-        g = jax.lax.ragged_dot(rows, w_gate, group_sizes)
-        u = jax.lax.ragged_dot(rows, w_up, group_sizes)
-        hmid = jnp.where(live[:, None], jax.nn.silu(g) * u, 0)
-        out = jnp.where(live[:, None],
-                        jax.lax.ragged_dot(hmid, w_down, group_sizes), 0)
-        # back to (token, slot) order; a pair of an absent expert is zero
-        out = _permute_rows(out, inverse, order).reshape(N, top_k, -1)
-        return jnp.sum(out.astype(acc) * gate[:, :, None], axis=1)
-
     with jax.named_scope("moe.experts"):
-        y = experts(x, gate.astype(acc), tuple(
-            params[n].astype(x.dtype) for n in ("w_gate", "w_up", "w_down")))
-    return y.astype(x.dtype), aux, stats, idx
+        y = _held_experts(top_k, x, gate.astype(acc), tuple(
+            params[n].astype(x.dtype) for n in ("w_gate", "w_up", "w_down")),
+            order, inverse, group_sizes)
+    return y, aux, stats, idx
 
 
 def moe_ffn_dropless_sharded(params, x, mesh: Mesh, expert_axis: str, *,

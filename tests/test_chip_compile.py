@@ -21,6 +21,7 @@ file so that one worker runs them.
 
 import functools
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -449,7 +450,6 @@ def test_sparse_attention_layer_runs_three_kernels_under_its_scope(
     (and the dispatch counter says so), and the compiled gradient holds the
     forward and both backward kernels with `dsa.attend` in their `op_name`,
     which is how `benchmark/harness/scope_time.py` finds their time."""
-    import re
 
     from deeplearning4j_tpu import observability as obs
     from deeplearning4j_tpu.kernels import registry
@@ -547,7 +547,6 @@ def test_windowed_and_full_layers_run_three_kernels_under_their_scope(
     the layer kind's scope in their `op_name` (how `scope_time.py` finds
     their time), the rotary step carries `attn.rope`, and nothing is
     `[S, S]`."""
-    import re
 
     from deeplearning4j_tpu import observability as obs
     from deeplearning4j_tpu.kernels import registry
@@ -590,14 +589,20 @@ def test_windowed_and_full_layers_run_three_kernels_under_their_scope(
     assert "attn.rope" in text and f"[{S},{S}]" not in text
 
 
-def test_dropless_experts_at_published_widths(chip):
-    """`expert.moe_ffn_dropless`: 8,192 tokens, 128 experts top-8, 16 held;
+@pytest.mark.parametrize("N,D,F,E,Eh,temporaries", [
+    (8192, 2048, 768, 128, 16, 2.5e9),      # keye_vl2_30b_a3b.fit_seq8k
+    (16384, 2304, 896, 64, 8, 3.0e9),       # mellum2_12b_a2_5b.fit_seq16k
+], ids=["keye_vl2_30b_a3b", "mellum2_12b_a2_5b"])
+def test_dropless_experts_at_published_widths(chip, N, D, F, E, Eh,
+                                              temporaries):
+    """`expert.moe_ffn_dropless` at both language models' points, top-8;
     the grouped products compile for the chip as XLA's ragged dot, which the
     TPU compiler turns into custom calls of its own (not kernels of this
-    repo: `pallas_time_share.fit` counts them all the same)."""
+    repo: `pallas_time_share.fit` counts them all the same). No pass is left
+    whose result is a select over a whole `[N * top_k, D]` or
+    `[N * top_k, F]` array: dead pairs are masked inside the sums over a
+    token's slots, `[N, top_k, D]` in, `[N, D]` out (PR 31)."""
     from deeplearning4j_tpu.parallel import expert
-
-    N, D, F, E, Eh = 8192, 2048, 768, 128, 16
 
     def loss(x, gate_w, w_gate, w_up, w_down):
         y, aux, _, _ = expert.moe_ffn_dropless(
@@ -610,5 +615,9 @@ def test_dropless_experts_at_published_widths(chip):
             chip((Eh, D, F), bf), chip((Eh, F, D), bf))
     exe = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         *args).compile()
-    assert "ragged-dot" in exe.as_text()
-    assert exe.memory_analysis().temp_size_in_bytes < 2.5e9
+    text = exe.as_text()
+    assert "ragged-dot" in text
+    assert exe.memory_analysis().temp_size_in_bytes < temporaries
+    selects = re.findall(
+        rf"= \w+\[{N * 8},(?:{D}|{F})\]\S* select\(", text)
+    assert not selects, selects

@@ -300,6 +300,128 @@ def test_dropless_experts_match_a_loop_over_the_held_pairs():
     assert _rel(y, want) <= 1e-5
 
 
+# Which experts of 8 a holder keeps, and what that makes of the sorted axis
+# (N * top_k = 48 pairs; a tile of the chip is 8 rows): no pair held (expert
+# 0, which the router's bias keeps every token away from), every pair, a
+# live prefix that ends inside a tile, and one shorter than a tile.
+HELD_CASES = {"none": (0, 1), "all": (0, 8), "ragged_prefix": (1, 3),
+              "under_a_tile": (5, 1)}
+
+
+def _value_and_grads(fn, *args):
+    (_, y), grads = jax.value_and_grad(fn, argnums=tuple(range(len(args))),
+                                       has_aux=True)(*args)
+    return (y,) + tuple(grads)
+
+
+def _held_case(case):
+    """Tables, tokens and a cotangent for one case: `run(first)` gives the
+    program's y and its gradients with respect to x, the router and the
+    three held tables; `loop(x, gate_w, w_gate, w_up, w_down)` over all 8
+    experts' tables is the float32 loop over the held pairs they are
+    compared with, -> (sum(y * r), y)."""
+    rng = np.random.default_rng(12)
+    E, D, F, N, K = 8, 16, 12, 24, 2
+    first, count = HELD_CASES[case]
+    p = _moe_tables(rng, E, D, F)
+    x = rng.normal(size=(N, D)).astype(np.float32)
+    x[:, 0] = 1.0
+    p["gate_w"][0, 0] = -100.0          # no token's top 2 holds expert 0
+    p = {k: jnp.asarray(v) for k, v in p.items()}
+    x, r = jnp.asarray(x), jnp.asarray(rng.normal(size=(N, D)), jnp.float32)
+    _, _, idx = expert_mod.route_top_k(p["gate_w"], x, K)
+    pairs = [(n, s, int(e)) for n, row in enumerate(np.asarray(idx))
+             for s, e in enumerate(row) if first <= e < first + count]
+
+    def loop(x, gate_w, w_gate, w_up, w_down):
+        _, gate, _ = expert_mod.route_top_k(gate_w, x, K)
+        y = jnp.zeros((N, D), jnp.float32)
+        for n, s, e in pairs:
+            hid = jax.nn.silu(x[n] @ w_gate[e]) * (x[n] @ w_up[e])
+            y = y.at[n].add(gate[n, s] * (hid @ w_down[e]))
+        return jnp.sum(y * r), y
+
+    held = slice(first, first + count)
+    args = (x, p["gate_w"], p["w_gate"], p["w_up"], p["w_down"])
+
+    def run(first):
+        def program(x, gate_w, w_gate, w_up, w_down):
+            y, _, _, _ = expert_mod.moe_ffn_dropless(
+                {"gate_w": gate_w, "w_gate": w_gate, "w_up": w_up,
+                 "w_down": w_down}, x, top_k=K, first=first)
+            return jnp.sum(y * r), y
+        return _value_and_grads(program, *args[:2],
+                                *(t[held] for t in args[2:]))
+
+    return dict(pairs=pairs, n_pairs=N * K, held=held, first=first, args=args,
+                loop=loop, run=run)
+
+
+def _check_held_case(case, got, c):
+    """y, dx, dgate_w and the held tables' gradients against the loop's."""
+    want = list(_value_and_grads(c["loop"], *c["args"]))
+    want[3:] = [g[c["held"]] for g in want[3:]]
+    n_held = len(c["pairs"])
+    assert {"none": n_held == 0, "all": n_held == c["n_pairs"],
+            "ragged_prefix": n_held > 8 and n_held % 8,
+            "under_a_tile": 0 < n_held < 8}[case], n_held
+    for name, a, b in zip(("y", "dx", "dgate_w", "dw_gate", "dw_up",
+                           "dw_down"), got, want):
+        assert np.all(np.isfinite(np.asarray(a))), name
+        if case == "none":
+            assert not np.any(np.asarray(a)), name
+        else:
+            assert _rel(a, b) <= 2e-5, (name, _rel(a, b))
+
+
+@pytest.mark.parametrize("jitted", [False, True],
+                         ids=["eager", "jit_first_traced"])
+@pytest.mark.parametrize("case", list(HELD_CASES))
+def test_dropless_value_and_gradients_match_a_loop_over_the_held_pairs(
+        case, jitted):
+    """`moe_ffn_dropless` and its hand-written backward pass: y and the
+    gradients with respect to x, the router and the three held tables, with
+    `first` a Python int and traced under `jax.jit`."""
+    c = _held_case(case)
+    if jitted:
+        got = jax.jit(c["run"])(jnp.int32(c["first"]))
+    else:
+        got = c["run"](c["first"])
+    _check_held_case(case, got, c)
+
+
+@pytest.mark.parametrize("case", list(HELD_CASES))
+def test_dropless_dead_rows_may_hold_anything(case, monkeypatch):
+    """Rows of a sorted array past the live prefix are nobody's. On the chip
+    a grouped product leaves them unwritten, and with the groups contracted
+    reads none of them (PERF.md PR 31); here every gather into expert order
+    and every grouped product hands back NaN there, and y and every
+    gradient stay finite and equal to the run without."""
+    c = _held_case(case)
+    clean = c["run"](c["first"])
+    poisoned = []
+
+    def dead_rows_nan(fn):
+        def wrapped(*args):
+            rows = fn(*args)
+            poisoned.append(rows.shape)
+            dead = jnp.arange(rows.shape[0]) >= len(c["pairs"])
+            return jnp.where(dead[:, None], jnp.nan, rows)
+        return wrapped
+
+    monkeypatch.setattr(expert_mod, "_rows_of_pairs",
+                        dead_rows_nan(expert_mod._rows_of_pairs))
+    monkeypatch.setattr(jax.lax, "ragged_dot",
+                        dead_rows_nan(jax.lax.ragged_dot))
+    got = c["run"](c["first"])
+    # three gathers into expert order, nine grouped products with rows out
+    assert len(poisoned) == 12 and all(
+        shape[0] == c["n_pairs"] for shape in poisoned)
+    for a, b in zip(got, clean):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    _check_held_case(case, got, c)
+
+
 def test_gradient_check_of_the_new_layers():
     sizes = dict(CELL.sizes, dtype_policy={"name": "float64"}, seq_len=16,
                  sa_config=dict(CELL.sizes["sa_config"], topk=6))
