@@ -1,4 +1,4 @@
-"""Pallas flash-attention kernels (TPU): four registry names.
+"""Pallas flash-attention kernels (TPU): five registry names.
 
 `flash_attention` (causal or full, below), `flash_attention_paged` (decode
 against the paged KV pool, further down), `masked_attention`
@@ -6,8 +6,11 @@ against the paged KV pool, further down), `masked_attention`
 backward: the sparse-attention layer's `dsa.attend`, `nn/layers/dsa.py`)
 and `banded_attention` (the same three kernels with no mask operand: what an
 extended attention layer without an indexer runs, `attn.full` and
-`attn.sliding`; PR 30). The masked family shares the streaming kernels'
-prefetched lower-triangle sequence (`_pair_arrays`, which also knows a
+`attn.sliding`; PR 30), and `latent_attention` (those kernels again with
+one more static case: the score tile is the sum of two products of different
+widths, the second against a rotary key that all heads share; multi-head
+latent attention's `mla.attend`). The masked family shares the streaming
+kernels' prefetched lower-triangle sequence (`_pair_arrays`, which also knows a
 sliding window: only the tiles that meet the band `t - window < s <= t`)
 and differs in three ways its own section explains: the mask is an operand
 or is built inside the tile from iotas, the query heads of one KV head
@@ -831,14 +834,20 @@ def _tile_keep(keep_ref, i, j, block_q, block_k, causal, window,
     return keep if window is None else keep & (cols > rows - window)
 
 
-def _masked_scores(a, b, keep, scale):
+def _masked_scores(a, b, keep, scale, rope=None):
     """Masked scaled scores `a b^T` of one tile in float32. `keep` is the
     tile's mask (None: no pair is masked), shared by the groups of rows of
     `a` stacked on it: folded q rows `[G*BQ, Dh]` against `[BQ, BK]`, or in
     the transposed orientation a k tile against one head's q rows and a
-    `keep^T` tile `[BK, BQ]`."""
+    `keep^T` tile `[BK, BQ]`. `rope` (`latent_attention`): a second pair of
+    operands of another width whose product is added into the tile before
+    the scale, `a b^T + a_r b_r^T`."""
     s = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+                            preferred_element_type=jnp.float32)
+    if rope is not None:
+        s = s + jax.lax.dot_general(*rope, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+    s = s * scale
     if keep is None:
         return s
     return jnp.where(keep, s.reshape(-1, *keep.shape), _NEG).reshape(s.shape)
@@ -881,16 +890,25 @@ def _keep_first(refs, has_keep: bool):
     return (refs[0], refs[1:]) if has_keep else (None, refs)
 
 
+def _rope_first(refs, has_rope: bool):
+    """((the rotary query rows' ref, the shared rotary key tile's ref) or
+    None, the other refs): `latent_attention`'s second pair of operands
+    comes where a mask would, after the operands every call has."""
+    return (refs[:2], refs[2:]) if has_rope else (None, refs)
+
+
 def _masked_fwd_kernel(i_ref, j_ref, q_ref, k_ref, v_ref, *refs, block_q: int,
                        block_k: int, nk: int, causal: bool, scale: float,
-                       window=None, has_keep: bool = True):
+                       window=None, has_keep: bool = True,
+                       has_rope: bool = False):
     """One streamed step of the online softmax. The running max `m` and sum
     `l` live lane-replicated (`[rows, 128]`): `l` adds the score tile's
     128-lane columns elementwise and is summed across lanes once a q block,
     so a step pays one cross-lane reduction (the max), not two, and no
     broadcast of a `[rows, 1]` column."""
-    keep_ref, (o_ref, lse_ref, acc_ref, m_ref, l_ref) = _keep_first(
-        refs, has_keep)
+    keep_ref, refs = _keep_first(refs, has_keep)
+    rope, (o_ref, lse_ref, acc_ref, m_ref, l_ref) = _rope_first(
+        refs, has_rope)
     t = pl.program_id(1)
     i, j = i_ref[t], j_ref[t]
 
@@ -903,7 +921,8 @@ def _masked_fwd_kernel(i_ref, j_ref, q_ref, k_ref, v_ref, *refs, block_q: int,
     v = v_ref[0]
     s = _masked_scores(q_ref[0], k_ref[0],
                        _tile_keep(keep_ref, i, j, block_q, block_k, causal,
-                                  window), scale)
+                                  window), scale,
+                       rope and (rope[0][0], rope[1][0]))
     W = m_ref.shape[1]
     m = m_ref[...]
     new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
@@ -925,20 +944,28 @@ def _masked_fwd_kernel(i_ref, j_ref, q_ref, k_ref, v_ref, *refs, block_q: int,
 
 def _masked_dq_kernel(i_ref, j_ref, q_ref, k_ref, v_ref, *refs, block_q: int,
                       block_k: int, nk: int, causal: bool, scale: float,
-                      window=None, has_keep: bool = True):
-    keep_ref, (do_ref, lse_ref, d_ref, dq_ref, dq_acc) = _keep_first(
-        refs, has_keep)
+                      window=None, has_keep: bool = True,
+                      has_rope: bool = False):
+    keep_ref, refs = _keep_first(refs, has_keep)
+    rope, refs = _rope_first(refs, has_rope)
+    if has_rope:   # a second output and a second accumulator, each last
+        do_ref, lse_ref, d_ref, dq_ref, dqr_ref, dq_acc, dqr_acc = refs
+    else:
+        do_ref, lse_ref, d_ref, dq_ref, dq_acc = refs
     t = pl.program_id(1)
     i, j = i_ref[t], j_ref[t]
 
     @pl.when(j == _first_k_block(i, block_q, block_k, window))
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
+        if has_rope:
+            dqr_acc[...] = jnp.zeros_like(dqr_acc)
 
     k = k_ref[0]
     s = _masked_scores(q_ref[0], k,
                        _tile_keep(keep_ref, i, j, block_q, block_k, causal,
-                                  window), scale)
+                                  window), scale,
+                       rope and (rope[0][0], rope[1][0]))
     p = jnp.exp(s - lse_ref[0])
     dp = jax.lax.dot_general(do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
@@ -946,17 +973,31 @@ def _masked_dq_kernel(i_ref, j_ref, q_ref, k_ref, v_ref, *refs, block_q: int,
     dq_acc[...] += jax.lax.dot_general(
         ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
+    if has_rope:
+        k_r = rope[1][0]
+        dqr_acc[...] += jax.lax.dot_general(
+            ds.astype(k_r.dtype), k_r, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     @pl.when(j == _last_k_block(i, block_q, block_k, nk, causal))
     def _():
         dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+        if has_rope:
+            dqr_ref[0] = (dqr_acc[...] * scale).astype(dqr_ref.dtype)
 
 
 def _masked_dkv_kernel(i_ref, j_ref, k_ref, v_ref, q_ref, do_ref, *refs,
                        block_q: int, block_k: int, nq: int, causal: bool,
-                       scale: float, window=None, has_keep: bool = True):
-    keep_t_ref, (lse_ref, d_ref, dk_ref, dv_ref, dk_acc, dv_acc) = \
-        _keep_first(refs, has_keep)
+                       scale: float, window=None, has_keep: bool = True,
+                       has_rope: bool = False):
+    keep_t_ref, refs = _keep_first(refs, has_keep)
+    rope, refs = _rope_first(refs, has_rope)
+    if has_rope:   # a third output and a third accumulator, each last
+        (lse_ref, d_ref, dk_ref, dv_ref, dkr_ref, dk_acc, dv_acc,
+         dkr_acc) = refs
+        qr_ref, kr_ref = rope
+    else:
+        lse_ref, d_ref, dk_ref, dv_ref, dk_acc, dv_acc = refs
     t = pl.program_id(1)
     i, j = i_ref[t], j_ref[t]
 
@@ -964,6 +1005,8 @@ def _masked_dkv_kernel(i_ref, j_ref, k_ref, v_ref, q_ref, do_ref, *refs,
     def _():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
+        if has_rope:
+            dkr_acc[...] = jnp.zeros_like(dkr_acc)
 
     k, v = k_ref[0], v_ref[0]
     keep_t = _tile_keep(keep_t_ref, i, j, block_q, block_k, causal, window,
@@ -973,7 +1016,9 @@ def _masked_dkv_kernel(i_ref, j_ref, k_ref, v_ref, q_ref, do_ref, *refs,
     for g in range(lse_ref.shape[2]):    # the group's heads share k, v, keep
         rows = slice(g * block_q, (g + 1) * block_q)
         q, do = q_ref[0, rows, :], do_ref[0, rows, :]
-        p_t = jnp.exp(_masked_scores(k, q, keep_t, scale)
+        q_r = qr_ref[0, rows, :] if has_rope else None
+        p_t = jnp.exp(_masked_scores(k, q, keep_t, scale,
+                                     has_rope and (kr_ref[0], q_r) or None)
                       - lse_ref[0, 0, g:g + 1, :])           # [BK, BQ]
         dv += jax.lax.dot_general(
             p_t.astype(do.dtype), do, (((1,), (0,)), ((), ())),
@@ -984,6 +1029,10 @@ def _masked_dkv_kernel(i_ref, j_ref, k_ref, v_ref, q_ref, do_ref, *refs,
         dk += jax.lax.dot_general(
             ds_t.astype(q.dtype), q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if has_rope:
+            dkr_acc[...] += jax.lax.dot_general(
+                ds_t.astype(q_r.dtype), q_r, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
     dk_acc[...] += dk
     dv_acc[...] += dv
 
@@ -991,14 +1040,24 @@ def _masked_dkv_kernel(i_ref, j_ref, k_ref, v_ref, q_ref, do_ref, *refs,
     def _():
         dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+        if has_rope:
+            dkr_ref[0] = (dkr_acc[...] * scale).astype(dkr_ref.dtype)
 
 
-def _masked_specs(block_q, block_k, G, D):
+def _masked_specs(block_q, block_k, G, D, Dr=None):
     """BlockSpecs over the prefetched (i, j) sequence: folded q rows, a k/v
     tile, a `keep` tile, a `keep^T` tile, a `[.., 1]` column of the folded
-    rows."""
+    rows; with `Dr` (`latent_attention`) the rotary query rows, the rotary
+    key tile every head shares (its block index ignores the head) and the
+    per-head tile of that key's gradient."""
     rows = G * block_q
+    rope = {} if Dr is None else dict(
+        qr=pl.BlockSpec((1, rows, Dr), lambda b, t, ii, jj: (b, ii[t], 0)),
+        kr=pl.BlockSpec((1, block_k, Dr), lambda b, t, ii, jj: (0, jj[t], 0)),
+        dkr=pl.BlockSpec((1, block_k, Dr),
+                         lambda b, t, ii, jj: (b, jj[t], 0)))
     return dict(
+        rope,
         q=pl.BlockSpec((1, rows, D), lambda b, t, ii, jj: (b, ii[t], 0)),
         kv=pl.BlockSpec((1, block_k, D), lambda b, t, ii, jj: (b, jj[t], 0)),
         keep=pl.BlockSpec((block_q, block_k),
@@ -1010,31 +1069,44 @@ def _masked_specs(block_q, block_k, G, D):
                          lambda b, t, ii, jj: (b, ii[t], 0, 0)))
 
 
-def _mask_operand(keep8, spec):
-    """(the mask's in_specs, its operands, the call's family name): one of
-    each with a mask (`masked_attention`), none without
-    (`banded_attention`)."""
+def _mask_operand(keep8, spec, rope=None, sp=None):
+    """(the in_specs, the operands, the call's family name) of what a call
+    has beside the operands every call has: a mask (`masked_attention`),
+    nothing (`banded_attention`), or with `rope` = (rotary query rows
+    folded, the shared rotary key `[1, S, Dr]`) those two
+    (`latent_attention`)."""
+    if rope is not None:
+        return [sp["qr"], sp["kr"]], list(rope), "latent_attention"
     if keep8 is not None:
         return [spec], [keep8], "masked_attention"
     return [], [], "banded_attention"
 
 
+def _rope_statics(rope):
+    """The kernels' static arguments that only `latent_attention` sets: none
+    for the other two families, whose calls stay as they were."""
+    return {} if rope is None else {"has_rope": True}
+
+
 def _masked_fwd(q, k, v, keep8, G, scale, causal, block_q, block_k,
-                interpret, window=None):
+                interpret, window=None, rope=None):
     """Folded q `[KV, G*S, D]`, k, v `[KV, S, D]`, keep8 `[S, S]` int8 or
-    None -> (o folded, lse `[KV, G*S, 1]` float32)."""
+    None -> (o folded, lse `[KV, G*S, 1]` float32). `rope`: see
+    `_mask_operand`."""
     from jax.experimental.pallas import tpu as pltpu
 
     KV, S, D = k.shape
     nq, nk = S // block_q, S // block_k
     ir, jr = _pair_arrays(nq, nk, block_q, block_k, causal, "row", window)
-    sp = _masked_specs(block_q, block_k, G, D)
+    sp = _masked_specs(block_q, block_k, G, D,
+                       None if rope is None else rope[1].shape[-1])
     rows, W = G * block_q, min(128, block_k)   # W: the statistics' lanes
-    keep_spec, keep_arg, family = _mask_operand(keep8, sp["keep"])
+    keep_spec, keep_arg, family = _mask_operand(keep8, sp["keep"], rope, sp)
     return pl.pallas_call(
         functools.partial(_masked_fwd_kernel, block_q=block_q,
                           block_k=block_k, nk=nk, causal=causal, scale=scale,
-                          window=window, has_keep=keep8 is not None),
+                          window=window, has_keep=keep8 is not None,
+                          **_rope_statics(rope)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(KV, len(ir)),
             in_specs=[sp["q"], sp["kv"], sp["kv"]] + keep_spec,
@@ -1050,8 +1122,11 @@ def _masked_fwd(q, k, v, keep8, G, scale, causal, block_q, block_k,
 
 
 def _masked_bwd(q, k, v, keep8, do, o, lse, G, scale, causal, block_q,
-                block_k, interpret, window=None):
-    """(dq folded, dk, dv) from the folded residuals."""
+                block_k, interpret, window=None, rope=None):
+    """(dq folded, dk, dv) from the folded residuals; with `rope` (see
+    `_mask_operand`) `dq` is (dq, the rotary rows' gradient folded) and a
+    fourth result is the shared rotary key's gradient by KV head,
+    `[KV, S, Dr]`, for the caller to sum over the heads."""
     from jax.experimental.pallas import tpu as pltpu
 
     KV, S, D = k.shape
@@ -1059,21 +1134,32 @@ def _masked_bwd(q, k, v, keep8, do, o, lse, G, scale, causal, block_q,
     rows = G * block_q
     d_row = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1, keepdims=True)                  # [KV, G*S, 1]
-    sp = _masked_specs(block_q, block_k, G, D)
+    Dr = None if rope is None else rope[1].shape[-1]
+    sp = _masked_specs(block_q, block_k, G, D, Dr)
+    # what only a call with `rope` has: one more result and accumulator each
+    dq_more = ([], [], []) if rope is None else (
+        [sp["qr"]], [pltpu.VMEM((rows, Dr), jnp.float32)],
+        [jax.ShapeDtypeStruct(rope[0].shape, rope[0].dtype)])
+    dkv_more = ([], [], []) if rope is None else (
+        [sp["dkr"]], [pltpu.VMEM((block_k, Dr), jnp.float32)],
+        [jax.ShapeDtypeStruct((KV, S, Dr), jnp.float32)])
 
     ir, jr = _pair_arrays(nq, nk, block_q, block_k, causal, "row", window)
-    keep_spec, keep_arg, family = _mask_operand(keep8, sp["keep"])
+    keep_spec, keep_arg, family = _mask_operand(keep8, sp["keep"], rope, sp)
     dq = pl.pallas_call(
         functools.partial(_masked_dq_kernel, block_q=block_q,
                           block_k=block_k, nk=nk, causal=causal, scale=scale,
-                          window=window, has_keep=keep8 is not None),
+                          window=window, has_keep=keep8 is not None,
+                          **_rope_statics(rope)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(KV, len(ir)),
             in_specs=[sp["q"], sp["kv"], sp["kv"]] + keep_spec
             + [sp["q"], sp["col"], sp["col"]],
-            out_specs=sp["q"],
-            scratch_shapes=[pltpu.VMEM((rows, D), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            out_specs=[sp["q"]] + dq_more[0] if rope else sp["q"],
+            scratch_shapes=[pltpu.VMEM((rows, D), jnp.float32)]
+            + dq_more[1]),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)] + dq_more[2]
+        if rope else jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
         name=f"{family}_dq",
     )(jnp.asarray(ir), jnp.asarray(jr), q, k, v, *keep_arg, do, lse, d_row)
@@ -1081,25 +1167,27 @@ def _masked_bwd(q, k, v, keep8, do, o, lse, G, scale, causal, block_q,
     ic, jc = _pair_arrays(nq, nk, block_q, block_k, causal, "col", window)
     as_rows = lambda c: c.reshape(KV, nq, G, block_q)
     keep_spec, keep_arg, family = _mask_operand(
-        None if keep8 is None else keep8.T, sp["keep_t"])
-    dk, dv = pl.pallas_call(
+        None if keep8 is None else keep8.T, sp["keep_t"], rope, sp)
+    dk, dv, *dk_r = pl.pallas_call(
         functools.partial(_masked_dkv_kernel, block_q=block_q,
                           block_k=block_k, nq=nq, causal=causal, scale=scale,
-                          window=window, has_keep=keep8 is not None),
+                          window=window, has_keep=keep8 is not None,
+                          **_rope_statics(rope)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(KV, len(ic)),
             in_specs=[sp["kv"], sp["kv"], sp["q"], sp["q"]] + keep_spec
             + [sp["row"], sp["row"]],
-            out_specs=[sp["kv"], sp["kv"]],
+            out_specs=[sp["kv"], sp["kv"]] + dkv_more[0],
             scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                            pltpu.VMEM((block_k, D), jnp.float32)]),
+                            pltpu.VMEM((block_k, D), jnp.float32)]
+            + dkv_more[1]),
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)] + dkv_more[2],
         interpret=interpret,
         name=f"{family}_dkv",
     )(jnp.asarray(ic), jnp.asarray(jc), k, v, q, do, *keep_arg,
       as_rows(lse), as_rows(d_row))
-    return dq, dk, dv
+    return (dq, dk, dv, *dk_r)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
@@ -1205,6 +1293,64 @@ def banded_attention(q, k, v, window: Optional[int] = None,
                                     band_window(q.shape[0], window))
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _latent_attention_pallas(q_n, q_r, k_n, k_r, v, block_q: int,
+                             block_k: int, interpret: bool = False):
+    """Causal attention whose score is a sum of two products of different
+    widths, the second against a key every head shares (multi-head latent
+    attention): q_n, k_n: [S, H, Dn]; q_r: [S, H, Dr]; k_r: [S, Dr]; v:
+    [S, H, Dn] -> [S, H, Dn], with `score_h[t, s] = (q_n . k_n + q_r . k_r)
+    / sqrt(Dn + Dr)`. The masked kernels with no mask operand and the
+    static case `has_rope`: each head is its own KV head (G = 1), the
+    shared key's tile is fetched by key block alone, and its gradient
+    leaves the dk/dv kernel by head, in float32, to be summed here."""
+    return _latent_attention_fwd(q_n, q_r, k_n, k_r, v, block_q, block_k,
+                                 interpret)[0]
+
+
+def _latent_operands(q_n, q_r, k_n, k_r, v, block_q):
+    """(q_n folded, k_n and v `[H, S, Dn]`), the rotary pair (q_r folded,
+    k_r `[1, S, Dr]`) and the scale."""
+    H = q_n.shape[1]
+    return ((_fold_heads(q_n, H, block_q), jnp.swapaxes(k_n, 0, 1),
+             jnp.swapaxes(v, 0, 1)),
+            (_fold_heads(q_r, H, block_q), k_r[None]),
+            (q_n.shape[2] + q_r.shape[2]) ** -0.5)
+
+
+def _latent_attention_fwd(q_n, q_r, k_n, k_r, v, block_q, block_k,
+                          interpret):
+    _require_block_multiple(q_n.shape[0], block_q, block_k)
+    operands, rope, scale = _latent_operands(q_n, q_r, k_n, k_r, v, block_q)
+    o, lse = _masked_fwd(*operands, None, 1, scale, True, block_q, block_k,
+                         interpret, rope=rope)
+    return _unfold_heads(o, 1, block_q), (q_n, q_r, k_n, k_r, v, o, lse)
+
+
+def _latent_attention_bwd(block_q, block_k, interpret, res, g):
+    q_n, q_r, k_n, k_r, v, o, lse = res
+    operands, rope, scale = _latent_operands(q_n, q_r, k_n, k_r, v, block_q)
+    (dq_n, dq_r), dk_n, dv, dk_r = _masked_bwd(
+        *operands, None, _fold_heads(g, g.shape[1], block_q), o, lse, 1,
+        scale, True, block_q, block_k, interpret, rope=rope)
+    return (_unfold_heads(dq_n, 1, block_q), _unfold_heads(dq_r, 1, block_q),
+            jnp.swapaxes(dk_n, 0, 1),
+            jnp.sum(dk_r, axis=0).astype(k_r.dtype), jnp.swapaxes(dv, 0, 1))
+
+
+_latent_attention_pallas.defvjp(_latent_attention_fwd, _latent_attention_bwd)
+
+
+def latent_attention(q_n, q_r, k_n, k_r, v):
+    """The Pallas body of `latent_attention` at `masked_blocks`' blocks for
+    one head a KV head and rows of Dn + Dr; the caller has resolved the
+    registry."""
+    block_q, block_k = masked_blocks(
+        q_n.shape[0], 1, q_n.shape[2] + q_r.shape[2], q_n.dtype.itemsize)
+    return _latent_attention_pallas(q_n, q_r, k_n, k_r, v, block_q, block_k,
+                                    _registry.interpret_mode())
+
+
 def band_window(S: int, window: Optional[int]):
     """`window` as the kernels take it: None where it reaches every earlier
     key of a sequence of S anyway."""
@@ -1272,12 +1418,45 @@ def _banded_pallas_available(backend, shapes, dtypes, meta=(), forced=False):
     return ok, why
 
 
+def _latent_pallas_available(backend, shapes, dtypes, meta=(), forced=False):
+    """`shapes` is `(S, H, Dn, Dr, Dv)`: the widths of the two products and
+    of the values."""
+    if backend != "tpu" and not forced:
+        return False, ("auto off-TPU keeps the XLA row-block body (interpret "
+                       "mode is for the forced parity tests)")
+    if dtypes and dtypes[0] not in ("bfloat16", "float32"):
+        return False, (f"dtype {dtypes[0]}: the kernel takes bfloat16 or "
+                       "float32 operands (Mosaic has no 64-bit arithmetic)")
+    if shapes:
+        S, H, Dn, Dr, Dv = shapes
+        if Dn != Dv:
+            return False, (f"Dn={Dn}, Dv={Dv}: the kernel's key and value "
+                           "tiles are one width")
+        if masked_blocks(S, 1, Dn + Dr, 2 if dtypes == ("bfloat16",)
+                         else 4) is None:
+            return False, (f"S={S}, Dn+Dr={Dn + Dr}: S is not a multiple of "
+                           "a 128-lane block, or the blocks outgrow VMEM")
+        if backend == "tpu" and (Dn % 128 or (Dr != 64 and Dr % 128)):
+            return False, (f"Dn={Dn}, Dr={Dr}: the widths the described-chip "
+                           "compile covers are multiples of 128 and, for "
+                           "the rotary part, 64")
+    if backend == "tpu":
+        return True, ("TPU masked flash kernel with a second, rotary "
+                      "product into the score tile against a key tile all "
+                      "heads share")
+    return True, "interpret mode off-TPU (float-close parity tests only)"
+
+
 _registry.register("masked_attention", [
     _registry.KernelImpl("pallas", _masked_pallas_available),
     _registry.KernelImpl("xla", _masked_xla_available),
 ])
 _registry.register("banded_attention", [
     _registry.KernelImpl("pallas", _banded_pallas_available),
+    _registry.KernelImpl("xla", _masked_xla_available),
+])
+_registry.register("latent_attention", [
+    _registry.KernelImpl("pallas", _latent_pallas_available),
     _registry.KernelImpl("xla", _masked_xla_available),
 ])
 

@@ -60,6 +60,8 @@ MODES = ("auto", "xla", "pallas")
 # more, so a step with large layers no longer ravels them (PR 29). 5:
 # `banded_attention` exists, and an extended attention layer without an
 # indexer resolves it where it resolved `masked_attention` (PR 30).
+# `latent_attention` (PR 32) is a new name that no older signature resolves:
+# no bump.
 SELECTION_RULES = 5
 
 # Meta key the registry itself adds to a signature traced under a mesh of
@@ -88,6 +90,10 @@ KERNEL_MODULES = {
     # the tile's mask comes from iotas and only tiles that meet the band
     # are visited.
     "banded_attention": "deeplearning4j_tpu.kernels.flash_attention",
+    # The same kernels once more with a second, narrower product added into
+    # the score tile, its key tile shared by all heads: multi-head latent
+    # attention's core (`mla.attend`).
+    "latent_attention": "deeplearning4j_tpu.kernels.flash_attention",
 }
 
 

@@ -12,6 +12,8 @@ of the builder.
 
 from __future__ import annotations
 
+from typing import Optional
+
 from deeplearning4j_tpu.nn.conf.enums import Updater
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers import (
@@ -253,7 +255,8 @@ def transformer_lm(vocab_size: int, *, t: int = 64, d_model: int = 64,
 
 
 def sparse_moe_lm(vocab_size: int, *, t: int, d_model: int, n_blocks: int,
-                  n_heads: int, n_kv_heads: int, head_dim: int,
+                  n_heads: int, n_kv_heads: Optional[int] = None,
+                  head_dim: Optional[int] = None,
                   n_experts: int, top_k: int, expert_hidden: int,
                   experts_held=None, index_top_k=None, index_n_heads=None,
                   index_head_dim=None, rope_theta: float = 1e7,
@@ -261,11 +264,15 @@ def sparse_moe_lm(vocab_size: int, *, t: int, d_model: int, n_blocks: int,
                   aux_loss_weight: float = 1e-3, lr: float = 1e-5,
                   adam_mean_decay: float = 0.9, adam_var_decay: float = 0.95,
                   seed: int = 123, dtype_policy=None, layer_types=None,
-                  attention_types=None):
+                  attention_types=None, latent_attention=None,
+                  first_dense: int = 0, dense_hidden: Optional[int] = None,
+                  scoring=None, routed_scaling_factor=None,
+                  shared_hidden=None):
     """Decoder-only mixture-of-experts language model (the Qwen3-MoE block;
     with DeepSeek sparse attention's indexer as Keye-VL-2.0-30B-A3B's
     language model has it, or with sliding-window and full layers in a
-    pattern as Mellum2-12B-A2.5B has them), built from DSL layers and
+    pattern as Mellum2-12B-A2.5B has them; or the DeepSeek-V3 block as
+    Kimi-VL-A3B's language model has it), built from DSL layers and
     trained by `ComputationGraph.fit` on integer ids `[B, T]` with integer
     next-token labels `[B, T]`:
 
@@ -285,20 +292,37 @@ def sparse_moe_lm(vocab_size: int, *, t: int, d_model: int, n_blocks: int,
     block is alike. A layer without an indexer runs the registry's
     `banded_attention`: the causal triangle or the window's band, the mask
     from iotas inside the kernel and no `[S, S]` array anywhere.
+    `latent_attention`: multi-head latent attention in place of the
+    grouped-query heads, its `SelfAttentionLayer` fields under their
+    `transformers` names (`{"kv_lora_rank": 512, "qk_nope_head_dim": 128,
+    "qk_rope_head_dim": 64, "v_head_dim": 128}`):
+    a compressed key/value projection under an RMS norm, a rotary part that
+    is a slice of each query head and ONE rotary key head for all, no
+    QK-norm; `n_kv_heads` and `head_dim` are then left out. It runs the
+    registry's `latent_attention`.
     MoE: `n_experts` gated SiLU experts of `expert_hidden`, `top_k` per
     token, dropless; `experts_held = (first, count)` keeps only those
     experts' weights here and computes their part of the sum
-    (`parallel/expert.py::moe_ffn_dropless`). `vocab_size` is the number of
-    embedding and head rows held (a slice of the model's vocabulary).
+    (`parallel/expert.py::moe_ffn_dropless`). `scoring="sigmoid"` with
+    `routed_scaling_factor`: the router scores each expert by its own
+    sigmoid, chooses by score + a frozen float32 selection bias (`gate_b`,
+    zeros until set), weighs by the unbiased scores and balances per
+    sequence (`MoELayer.scoring`); `shared_hidden`: a shared expert of that
+    width beside the routed ones. `first_dense`: that many leading blocks
+    have a dense gated SiLU MLP of `dense_hidden` (`GatedDenseLayer`) where
+    the others have experts. `vocab_size` is the number of embedding and
+    head rows held (a slice of the model's vocabulary).
 
     Device-trace scopes: `dsa.indexer`, `dsa.select`, `dsa.attend` (under an
-    indexer), `attn.sliding`, `attn.full` (without), `attn.rope`,
-    `moe.route`, `moe.experts`, `lm.head`."""
+    indexer), `attn.sliding`, `attn.full` (without), `mla.project`,
+    `mla.attend` (latent attention), `attn.rope`, `moe.route`,
+    `moe.experts`, `moe.shared`, `ffn.dense`, `lm.head`."""
     import dataclasses
 
     from deeplearning4j_tpu.nn.conf.distributions import NormalDistribution
     from deeplearning4j_tpu.nn.conf.layers import (
-        EmbeddingLayer, MoELayer, RMSNormalization, SelfAttentionLayer,
+        EmbeddingLayer, GatedDenseLayer, MoELayer, RMSNormalization,
+        SelfAttentionLayer,
     )
 
     nb = (NeuralNetConfiguration.builder()
@@ -320,15 +344,19 @@ def sparse_moe_lm(vocab_size: int, *, t: int, d_model: int, n_blocks: int,
               dist=NormalDistribution(0.0, 1.0)), "tokens"))
     attn = SelfAttentionLayer(
         n_out=d_model, n_heads=n_heads, n_kv_heads=n_kv_heads,
-        head_dim=head_dim, causal=True,
-        rope_theta=float(rope_theta), qk_norm_eps=float(rms_eps),
-        index_top_k=index_top_k, index_n_heads=index_n_heads,
-        index_head_dim=index_head_dim)
+        head_dim=head_dim, causal=True, rope_theta=float(rope_theta),
+        **(latent_attention if latent_attention is not None else dict(
+            qk_norm_eps=float(rms_eps), index_top_k=index_top_k,
+            index_n_heads=index_n_heads, index_head_dim=index_head_dim)))
     ffn = MoELayer(
         n_out=d_model, n_experts=n_experts, expert_hidden=expert_hidden,
         top_k=top_k, dropless=True,
         norm_topk_prob=norm_topk_prob, aux_loss_weight=aux_loss_weight,
-        experts_held=None if experts_held is None else tuple(experts_held))
+        experts_held=None if experts_held is None else tuple(experts_held),
+        scoring=scoring, routed_scaling_factor=routed_scaling_factor,
+        shared_hidden=shared_hidden)
+    dense = GatedDenseLayer(n_out=d_model, hidden=dense_hidden or 0,
+                            scope="ffn.dense")
     kinds = list(layer_types or [None])
     if n_blocks % len(kinds):
         raise ValueError(f"layer_types has {len(kinds)} entries: n_blocks "
@@ -337,7 +365,8 @@ def sparse_moe_lm(vocab_size: int, *, t: int, d_model: int, n_blocks: int,
     for i, kind in enumerate(kinds * (n_blocks // len(kinds))):
         prev = _add_transformer_block(
             gb, prev, i, d_model, n_heads, causal=True,
-            norm=lambda: RMSNormalization(eps=rms_eps), ffn=ffn,
+            norm=lambda: RMSNormalization(eps=rms_eps),
+            ffn=dense if i < first_dense else ffn,
             attn=attn if kind is None else dataclasses.replace(
                 attn, **(attention_types or {}).get(kind, {})))
     gb.add_layer("ln_out", RMSNormalization(eps=rms_eps), prev)

@@ -224,6 +224,28 @@ class DenseLayer(FeedForwardLayer):
 
 @register_layer
 @dataclass
+class GatedDenseLayer(FeedForwardLayer):
+    """Gated SiLU MLP, `W_down(silu(W_gate x) * W_up x)` with no biases
+    (Shazeer 2020's SwiGLU; the dense FFN of the Llama and DeepSeek
+    families): `hidden` is the inner width (0 -> 4 * n_in at build time),
+    n_out the model width. `activation` (identity) applies to the result."""
+
+    hidden: int = 0
+    activation: Any = "identity"
+
+    def set_n_in(self, input_type: InputType, override: bool) -> None:
+        super().set_n_in(input_type, override)
+        if not self.hidden:
+            self.hidden = 4 * self.n_in
+
+    def param_shapes(self):
+        h = self.hidden or 4 * self.n_in
+        return {"W_gate": (self.n_in, h), "W_up": (self.n_in, h),
+                "W_down": (h, self.n_out)}
+
+
+@register_layer
+@dataclass
 class BaseOutputLayer(FeedForwardLayer):
     loss_function: Any = LossFunction.MCXENT
     # None/True: `W` and `b` as ever; False: no bias (an LM head).
@@ -775,18 +797,52 @@ class SelfAttentionLayer(BaseRecurrentLayer):
     # the band are visited.
     sliding_window: Optional[int] = None
     rope_scaling: Optional[dict] = None
+    # Multi-head latent attention (DeepSeek-V2's MLA, the fields under their
+    # `transformers` names; `dsa._latent_sequence` has the equations): with
+    # `kv_lora_rank` set, keys and values come from one compressed
+    # projection `Wdkv` to `kv_lora_rank + qk_rope_head_dim` columns, the
+    # latent under an RMS norm (`gamma_kv`, eps 1e-6: `dsa.LATENT_NORM_EPS`)
+    # and expanded by `Wukv` to `n_heads` heads of `qk_nope_head_dim +
+    # v_head_dim`; a query head is `qk_nope_head_dim + qk_rope_head_dim`
+    # wide, only the rope part is rotated, and its key part is ONE head that
+    # all query heads share. Scores are scaled by (nope + rope)^-1/2. The
+    # core is the registry's `latent_attention`, under the scope
+    # `mla.attend`; the projections and the latent norm under `mla.project`.
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
 
     INDEXER_PARAMS = ("Wiq", "Wik", "Wiw", "gamma_ik", "beta_ik")
+
+    def __post_init__(self):
+        if self.kv_lora_rank is None:
+            return
+        if None in (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                    self.v_head_dim, self.rope_theta):
+            raise ValueError(
+                "kv_lora_rank needs qk_nope_head_dim, qk_rope_head_dim, "
+                "v_head_dim and rope_theta")
+        if any(v is not None for v in (
+                self.n_kv_heads, self.head_dim, self.qk_norm_eps,
+                self.index_top_k, self.sliding_window,
+                self.rope_scaling)) or not self.causal:
+            raise ValueError(
+                "a latent-attention layer (kv_lora_rank) is causal and "
+                "takes none of n_kv_heads, head_dim, qk_norm_eps, "
+                "index_top_k, sliding_window, rope_scaling")
 
     def is_extended(self) -> bool:
         return any(v is not None for v in (
             self.n_kv_heads, self.head_dim, self.rope_theta,
             self.qk_norm_eps, self.index_top_k, self.sliding_window,
-            self.rope_scaling))
+            self.rope_scaling, self.kv_lora_rank))
 
     def attention_scope(self) -> str:
         """The `jax.named_scope` around the attention of an extended layer
         without an indexer, by its kind."""
+        if self.kv_lora_rank is not None:
+            return "mla.attend"
         return "attn.full" if self.sliding_window is None else "attn.sliding"
 
     def param_shapes(self):
@@ -804,6 +860,12 @@ class SelfAttentionLayer(BaseRecurrentLayer):
 
     def _extended_param_shapes(self):
         H = self.n_heads
+        if self.kv_lora_rank is not None:
+            R, Dn, Dr, Dv = (self.kv_lora_rank, self.qk_nope_head_dim,
+                             self.qk_rope_head_dim, self.v_head_dim)
+            return {"Wq": (self.n_in, H * (Dn + Dr)),
+                    "Wdkv": (self.n_in, R + Dr), "gamma_kv": (R,),
+                    "Wukv": (R, H * (Dn + Dv)), "Wo": (H * Dv, self.n_out)}
         KV = self.n_kv_heads or H
         Dh = self.head_dim or self.n_out // H
         if H % KV:
@@ -823,8 +885,9 @@ class SelfAttentionLayer(BaseRecurrentLayer):
         return self.INDEXER_PARAMS if self.index_top_k is not None else ()
 
     def full_precision_param_names(self):
-        # the q/k norms' scales and the indexer's key norm (extended path)
-        return ("gamma_q", "gamma_k", "gamma_ik", "beta_ik")
+        # the q/k norms' scales, the indexer's key norm and the latent norm's
+        # scale (extended path)
+        return ("gamma_q", "gamma_k", "gamma_ik", "beta_ik", "gamma_kv")
 
     def state_shapes(self):
         # Mean number of keys a query attends to, of the last forward pass
@@ -873,14 +936,41 @@ class MoELayer(FeedForwardLayer):
     dropless: Optional[bool] = None
     norm_topk_prob: Optional[bool] = None
     experts_held: Optional[Tuple[int, int]] = None
+    # The dropless router's scoring. None: softmax over all experts, the
+    # weights the chosen probabilities. "sigmoid" (DeepSeek-V3's `noaux_tc`
+    # with one group, `expert.route_top_k`): each expert's own sigmoid
+    # score; the choice is the top_k of score + `gate_b`, a selection bias
+    # leaf [n_experts] that is float32 whatever the policy and frozen (no
+    # gradient reaches it and it has no updater state; zeros at init); the
+    # weights are the unbiased scores, normalised with `norm_topk_prob` and
+    # multiplied by `routed_scaling_factor`; the balance term is the
+    # sequence-wise one (`expert.sequence_balance`). `shared_hidden`: a
+    # shared expert beside the routed ones, one gated SiLU MLP of that width
+    # over every token (scope `moe.shared`), held whole whatever
+    # `experts_held` says.
+    scoring: Optional[str] = None
+    routed_scaling_factor: Optional[float] = None
+    shared_hidden: Optional[int] = None
+
+    SHARED_PARAMS = ("shared_gate", "shared_up", "shared_down")
 
     def __post_init__(self):
         if self.experts_held is not None:
             self.experts_held = tuple(int(v) for v in self.experts_held)
-        if (self.experts_held is not None
-                or self.norm_topk_prob is not None) and not self.dropless:
-            raise ValueError("norm_topk_prob and experts_held belong to the "
-                             "dropless path: set dropless=True")
+        if any(v is not None for v in (
+                self.experts_held, self.norm_topk_prob, self.scoring,
+                self.routed_scaling_factor, self.shared_hidden)) \
+                and not self.dropless:
+            raise ValueError(
+                "norm_topk_prob, experts_held, scoring, "
+                "routed_scaling_factor and shared_hidden belong to the "
+                "dropless path: set dropless=True")
+        if self.scoring not in (None, "softmax", "sigmoid"):
+            raise ValueError(f"scoring {self.scoring!r}: softmax or sigmoid")
+        if self.routed_scaling_factor is not None \
+                and self.scoring != "sigmoid":
+            raise ValueError("routed_scaling_factor scales the sigmoid "
+                             "router's weights: set scoring='sigmoid'")
 
     def set_n_in(self, input_type: InputType, override: bool) -> None:
         super().set_n_in(input_type, override)
@@ -899,13 +989,29 @@ class MoELayer(FeedForwardLayer):
         E, h = self.n_experts, self.expert_hidden or 4 * self.n_in
         if self.dropless:
             Eh = self.held()[1]
-            return {"gate_w": (self.n_in, E), "w_gate": (Eh, self.n_in, h),
-                    "w_up": (Eh, self.n_in, h), "w_down": (Eh, h, self.n_out)}
+            shapes = {"gate_w": (self.n_in, E),
+                      "w_gate": (Eh, self.n_in, h),
+                      "w_up": (Eh, self.n_in, h),
+                      "w_down": (Eh, h, self.n_out)}
+            if self.scoring == "sigmoid":
+                shapes["gate_b"] = (E,)
+            if self.shared_hidden:
+                hs = self.shared_hidden
+                shapes.update(shared_gate=(self.n_in, hs),
+                              shared_up=(self.n_in, hs),
+                              shared_down=(hs, self.n_out))
+            return shapes
         return {
             "gate_w": (self.n_in, E),
             "w1": (E, self.n_in, h), "b_1": (E, h),
             "w2": (E, h, self.n_out), "b_2": (E, self.n_out),
         }
+
+    def frozen_param_names(self):
+        return ("gate_b",)
+
+    def full_precision_param_names(self):
+        return ("gate_b",)
 
     def state_shapes(self):
         # Routing statistics of the last forward pass, read where the score

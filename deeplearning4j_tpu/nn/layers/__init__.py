@@ -24,6 +24,7 @@ from deeplearning4j_tpu.nn.layers import (
 
 LAYER_IMPLS = {
     "DenseLayer": feedforward.dense_apply,
+    "GatedDenseLayer": feedforward.gated_dense_apply,
     "OutputLayer": feedforward.preoutput,  # loss fused at the network level
     "RnnOutputLayer": feedforward.preoutput,
     "CenterLossOutputLayer": feedforward.preoutput,

@@ -65,6 +65,10 @@ import jax
 import jax.numpy as jnp
 
 _NEG = -1e30
+# The RMS norm on a latent-attention layer's compressed key/value latent: the
+# published DeepSeek-V2/V3 code builds that norm with its class default, not
+# with the model's `rms_norm_eps`.
+LATENT_NORM_EPS = 1e-6
 
 
 def rms_norm(x, gamma, eps):
@@ -377,6 +381,72 @@ def banded_gqa_attention_xla(q, k, v, window=None, causal: bool = True, *,
     return _ungrouped(jnp.moveaxis(o, 0, 2).reshape(qg.shape))
 
 
+def latent_attention(q_n, q_r, k_n, k_r, v):
+    """Causal attention whose score is the sum of two products, the second
+    against a key all heads share: q_n, k_n: [S, H, Dn]; q_r: [S, H, Dr];
+    k_r: [S, Dr]; v: [S, H, Dv] -> ([S, H, Dv], the Pallas body's fill share
+    as `banded_gqa_attention` gives it). `score_h[t, s] = (q_n,h[t] .
+    k_n,h[s] + q_r,h[t] . k_r[s]) / sqrt(Dn + Dr)`. The registry's
+    `latent_attention` decides between the Pallas flash body
+    (`kernels/flash_attention.py`: the masked kernels with the second
+    product added into the score tile, 2 (Dn + Dr) FLOP a pair for the score
+    and 2 Dv for the values, no operand padded) and
+    `latent_attention_xla`."""
+    from deeplearning4j_tpu.kernels import flash_attention, registry
+
+    S, H, Dn = q_n.shape
+    Dr, Dv = q_r.shape[2], v.shape[2]
+    res = registry.resolve("latent_attention", dtypes=(str(q_n.dtype),),
+                           shapes=(int(S), int(H), int(Dn), int(Dr), int(Dv)))
+    if res.impl == "pallas":
+        return (flash_attention.latent_attention(q_n, q_r, k_n, k_r, v),
+                flash_attention.band_fill_share(S, 1, Dn + Dr,
+                                                q_n.dtype.itemsize))
+    return latent_attention_xla(q_n, q_r, k_n, k_r, v), 0.0
+
+
+def latent_attention_xla(q_n, q_r, k_n, k_r, v):
+    """`latent_attention` in XLA row blocks: the two parts of each head's
+    query side by side, the shared rotary key copied beside every head's own
+    part, and `banded_gqa_attention_xla` over heads of Dn + Dr with values of
+    Dv (its scale is that width's). The shared key's gradient is the sum
+    over the heads by the broadcast's transpose."""
+    S, H, _ = q_n.shape
+    q = jnp.concatenate([q_n, q_r], axis=-1)
+    k = jnp.concatenate(
+        [k_n, jnp.broadcast_to(k_r[:, None, :], (S, H, k_r.shape[-1]))],
+        axis=-1)
+    return banded_gqa_attention_xla(q, k, v)
+
+
+def _latent_sequence(conf, params, h):
+    """Multi-head latent attention over one sequence h: [S, n_in] ->
+    (out [S, n_out], None, the fill share of its Pallas body's tiles):
+
+        q = h Wq -> [S, H, Dn + Dr], split q_n | q_r
+        [c ; k_r] = h Wdkv -> kv_lora_rank + Dr;  c <- RMSNorm(c; gamma_kv)
+        [k_n ; v] = c Wukv -> [S, H, Dn + Dv]
+        q_r, k_r <- rotate-half RoPE at rope_theta; k_r is one head for all
+        o_h(t) = sum_{s <= t} softmax_s((q_n,h . k_n,h + q_r,h . k_r)
+                 / sqrt(Dn + Dr)) v_h(s);   out = concat_h(o_h) Wo"""
+    S, H = h.shape[0], conf.n_heads
+    R, Dn, Dr, Dv = (conf.kv_lora_rank, conf.qk_nope_head_dim,
+                     conf.qk_rope_head_dim, conf.v_head_dim)
+    with jax.named_scope("mla.project"):
+        q = (h @ params["Wq"]).reshape(S, H, Dn + Dr)
+        ckv = h @ params["Wdkv"]
+        c = rms_norm(ckv[:, :R], params["gamma_kv"], LATENT_NORM_EPS)
+        kv = (c @ params["Wukv"]).reshape(S, H, Dn + Dv)
+    with jax.named_scope("attn.rope"):
+        q_r = rope(q[..., Dn:], conf.rope_theta)
+        k_r = rope(ckv[:, R:], conf.rope_theta)
+    with jax.named_scope(conf.attention_scope()):
+        o, fill = latent_attention(q[..., :Dn], q_r, kv[..., :Dn], k_r,
+                                   kv[..., Dn:])
+    acc = jnp.promote_types(h.dtype, jnp.float32)
+    return o.reshape(S, H * Dv) @ params["Wo"], None, jnp.asarray(fill, acc)
+
+
 def select_keys(conf, params, h):
     """h: [S, n_in] -> bool [S, S], the keys each query of a layer with
     `index_top_k` attends to: the indexer's selection S(t). A constant of
@@ -403,6 +473,8 @@ def _one_sequence(conf, params, h):
     (out, None, the share of the pairs in the tiles its attention's Pallas
     body visits that lie inside the causal band: static per layer, sequence
     length and block choice; 0 from the XLA body)."""
+    if conf.kv_lora_rank is not None:
+        return _latent_sequence(conf, params, h)
     S = h.shape[0]
     H = conf.n_heads
     KV = conf.n_kv_heads or H

@@ -30,6 +30,20 @@ def dense_apply(conf, params, state, x, *, rng=None, train=False, mask=None):
     return out, state, mask
 
 
+def gated_silu_mlp(x, w_gate, w_up, w_down):
+    """`(silu(x w_gate) * x w_up) w_down` over the last axis."""
+    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+
+
+def gated_dense_apply(conf, params, state, x, *, rng=None, train=False,
+                      mask=None):
+    """`GatedDenseLayer`: a gated SiLU MLP without biases."""
+    x = layer_input_dropout(conf, x, rng, train)
+    out = gated_silu_mlp(x, params["W_gate"], params["W_up"],
+                         params["W_down"])
+    return activations.resolve(conf.activation)(out), state, mask
+
+
 def preoutput(conf, params, state, x, *, rng=None, train=False, mask=None):
     """Linear pre-activation (used by output layers for stable fused losses)."""
     x = layer_input_dropout(conf, x, rng, train)

@@ -14,10 +14,13 @@ the reference predates MoE — SURVEY.md §2.3 extension row).
 
 from __future__ import annotations
 
+import math
+
 import jax
 
 from deeplearning4j_tpu.nn import activations
 from deeplearning4j_tpu.nn.layers.common import layer_input_dropout
+from deeplearning4j_tpu.nn.layers.feedforward import gated_silu_mlp
 from deeplearning4j_tpu.parallel.context import current_context
 
 
@@ -74,6 +77,13 @@ def _dropless_apply(conf, params, state, tokens, lead, mask):
     norm = conf.norm_topk_prob is not False
     ctx = current_context()
     kwargs = dict(top_k=conf.top_k, first=first, norm_topk_prob=norm)
+    if conf.scoring is not None:
+        # sequences: the balance term of this router is taken per sequence
+        kwargs.update(
+            scoring=conf.scoring, sequences=math.prod(lead[:-1]),
+            routed_scaling_factor=conf.routed_scaling_factor or 1.0)
+    shared = {k: params[k] for k in conf.SHARED_PARAMS if k in params}
+    params = {k: v for k, v in params.items() if k not in shared}
     if (ctx is not None and ctx.expert_axis is not None
             and ctx.axis_size("expert") > 1):
         y, aux, stats, idx = expert_mod.moe_ffn_dropless_sharded(
@@ -81,6 +91,12 @@ def _dropless_apply(conf, params, state, tokens, lead, mask):
     else:
         y, aux, stats, idx = expert_mod.moe_ffn_dropless(params, tokens,
                                                          **kwargs)
+    if shared:
+        # The shared expert: every token, in token order, beside the routed
+        # sum (one gated SiLU MLP of `shared_hidden`; counted once whatever
+        # share of the routed experts is held here).
+        with jax.named_scope("moe.shared"):
+            y = y + gated_silu_mlp(tokens, *shared.values())
     out = activations.resolve(conf.activation)(y.reshape(lead + (conf.n_out,)))
     new_state = dict(state)
     new_state["_aux_loss"] = conf.aux_loss_weight * aux

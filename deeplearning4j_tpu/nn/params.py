@@ -78,6 +78,11 @@ def init_layer_params(conf: Layer, rng: jax.Array, dtype=jnp.float32) -> Dict[st
             # indexer's key norm): ones. Their `beta_*` take the bias path.
             params[name] = jnp.ones(shape, dtype)
             continue
+        if isinstance(conf, MoELayer) and name == "gate_b":
+            # the sigmoid router's selection bias: no bias until one is
+            # loaded or set
+            params[name] = jnp.zeros(shape, dtype)
+            continue
         if isinstance(conf, BottleneckBlock) and name.startswith("gamma_"):
             # Per-branch BN scale: ones, like BatchNormalization's default
             # gamma (beta_* lands in the bias path below -> zeros).

@@ -160,18 +160,52 @@ def moe_ffn(params, x, *, capacity_factor: float = 1.25,
     return y
 
 
-def route_top_k(gate_w, x, top_k: int, norm_topk_prob: bool = True):
+def route_top_k(gate_w, x, top_k: int, norm_topk_prob: bool = True, *,
+                scoring: str = "softmax", gate_b=None,
+                routed_scaling_factor: float = 1.0):
     """The router of the dropless path: softmax over all experts' logits in
     >= float32, the `top_k` largest per token. x: [N, D], gate_w: [D, E] ->
     `(probs [N, E], gate [N, k], idx [N, k])`, `gate` renormalised to sum
-    to 1 with `norm_topk_prob`."""
+    to 1 with `norm_topk_prob`.
+
+    `scoring="sigmoid"` (DeepSeek-V3's `noaux_tc` router with one group):
+    the scores are `s = sigmoid(logits)`, each expert's own; the choice is
+    the `top_k` largest of `s + gate_b` (`gate_b` [E]: the selection bias,
+    which enters the choice and nothing else, so no gradient reaches it);
+    `gate` is the unbiased `s` at the chosen experts, divided by its sum
+    (+ 1e-20) with `norm_topk_prob`, times `routed_scaling_factor`; and
+    `probs` is `s / sum_e s`, what the balance term averages."""
     acc = jnp.promote_types(x.dtype, jnp.float32)
     logits = x.astype(acc) @ gate_w.astype(acc)
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        choice = scores if gate_b is None else scores + gate_b.astype(acc)
+        _, idx = jax.lax.top_k(jax.lax.stop_gradient(choice), top_k)
+        gate = jnp.take_along_axis(scores, idx, axis=-1)
+        if norm_topk_prob:
+            gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
+        return (scores / jnp.sum(scores, axis=-1, keepdims=True),
+                gate * routed_scaling_factor, idx)
+    if scoring != "softmax":
+        raise ValueError(f"scoring {scoring!r}: softmax or sigmoid")
     probs = jax.nn.softmax(logits, axis=-1)
     gate, idx = jax.lax.top_k(probs, top_k)
     if norm_topk_prob:
         gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
     return probs, gate, idx
+
+
+def sequence_balance(probs, idx, sequences: int):
+    """The sequence-wise balance term (DeepSeek-V2/V3): `sum_i f_i P_i` with
+    `f_i = E / (k S) * #{t: i chosen}` and `P_i = mean_t probs_i(t)` over
+    each sequence's own S tokens, averaged over the sequences. probs:
+    [N, E], idx: [N, k], N = sequences * S. 1 under an even router."""
+    N, E = probs.shape
+    S, k = N // sequences, idx.shape[1]
+    counts = jnp.zeros((sequences, E), probs.dtype).at[
+        jnp.arange(N)[:, None] // S, idx].add(1.0)
+    mean_probs = jnp.mean(probs.reshape(sequences, S, E), axis=1)
+    return jnp.mean(jnp.sum(counts * (E / (k * S)) * mean_probs, axis=1))
 
 
 def _rows_of_pairs(src, token):
@@ -285,7 +319,8 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 def moe_ffn_dropless(params, x, *, top_k: int, first=0,
-                     norm_topk_prob: bool = True):
+                     norm_topk_prob: bool = True, scoring: str = "softmax",
+                     routed_scaling_factor: float = 1.0, sequences: int = 1):
     """Dropless top-k routed experts, this holder's part of the sum.
 
     x: [N, D] tokens. `params["gate_w"]`: [D, E] router over ALL E experts;
@@ -330,18 +365,26 @@ def moe_ffn_dropless(params, x, *, top_k: int, first=0,
     Returns `(y [N, D_out], aux, stats, idx)`: `aux = E * sum_e f_e * P_e`
     over all E experts with f_e the pairs routed to e per token and P_e the
     mean router probability (Qwen3-MoE's `load_balancing_loss_func`);
-    `stats` = `(pairs_held_share, expert_load_max_over_mean)` over the held
-    experts; `idx` [N, top_k] the experts each token was routed to."""
+    under `scoring="sigmoid"` (`route_top_k`: the bias is `params["gate_b"]`
+    where the layer has one) `sequence_balance` over the `sequences` the
+    tokens are; `stats` = `(pairs_held_share, expert_load_max_over_mean)`
+    over the held experts; `idx` [N, top_k] the experts each token was
+    routed to."""
     N, D = x.shape
     E = params["gate_w"].shape[1]
     Eh = params["w_gate"].shape[0]
     acc = jnp.promote_types(x.dtype, jnp.float32)
 
     with jax.named_scope("moe.route"):
-        probs, gate, idx = route_top_k(params["gate_w"], x, top_k,
-                                       norm_topk_prob)            # [N, k]
-        counts = jnp.zeros((E,), acc).at[idx.reshape(-1)].add(1.0)
-        aux = E * jnp.sum(counts / N * jnp.mean(probs, axis=0))
+        probs, gate, idx = route_top_k(
+            params["gate_w"], x, top_k, norm_topk_prob, scoring=scoring,
+            gate_b=params.get("gate_b"),
+            routed_scaling_factor=routed_scaling_factor)          # [N, k]
+        if scoring == "sigmoid":
+            aux = sequence_balance(probs, idx, sequences)
+        else:
+            counts = jnp.zeros((E,), acc).at[idx.reshape(-1)].add(1.0)
+            aux = E * jnp.sum(counts / N * jnp.mean(probs, axis=0))
         # Sort the pairs by local expert, those held elsewhere (Eh) last.
         local = idx.reshape(-1) - first
         held = (local >= 0) & (local < Eh)
@@ -363,7 +406,7 @@ def moe_ffn_dropless(params, x, *, top_k: int, first=0,
 
 def moe_ffn_dropless_sharded(params, x, mesh: Mesh, expert_axis: str, *,
                              top_k: int, first=0,
-                             norm_topk_prob: bool = True):
+                             norm_topk_prob: bool = True, **routing):
     """`moe_ffn_dropless` with the held expert tables split over
     `expert_axis`: every device routes every token over all experts, takes
     its own experts by its index on the axis, and the parts are summed
@@ -381,12 +424,13 @@ def moe_ffn_dropless_sharded(params, x, mesh: Mesh, expert_axis: str, *,
         y, aux, (share, load), idx = moe_ffn_dropless(
             tables, tokens, top_k=top_k,
             first=first + me * (count // n_dev),
-            norm_topk_prob=norm_topk_prob)
+            norm_topk_prob=norm_topk_prob, **routing)
         return (jax.lax.psum(y, expert_axis), aux,
                 (jax.lax.psum(share, expert_axis),
                  jax.lax.pmax(load, expert_axis)), idx)
 
-    specs = {k: (P() if k == "gate_w" else P(expert_axis)) for k in params}
+    specs = {k: (P() if k in ("gate_w", "gate_b") else P(expert_axis))
+             for k in params}
     return shard_map(part, mesh=mesh, in_specs=(specs, P()),
                      out_specs=(P(), P(), (P(), P()), P()),
                      check_vma=False)(params, x)

@@ -589,6 +589,90 @@ def test_windowed_and_full_layers_run_three_kernels_under_their_scope(
     assert "attn.rope" in text and f"[{S},{S}]" not in text
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_latent_attention_forward_backward(chip, dtype):
+    """The three kernels of `latent_attention` (the masked kernels with a
+    second, rotary product into the score tile against a key tile that all
+    heads share) at `kimi_vl_a3b.fit_seq8k`'s shapes: 16 heads of 128 + 64
+    against values of 128. No operand is padded to 256 and nothing is
+    `[S, S]`."""
+    S, H, Dn, Dr = 8192, 16, 128, 64
+    ok, why = fa._latent_pallas_available(
+        "tpu", (S, H, Dn, Dr, Dn), (jnp.dtype(dtype).name,))
+    assert ok, why
+    block_q, block_k = fa.masked_blocks(S, 1, Dn + Dr,
+                                        jnp.dtype(dtype).itemsize)
+
+    def loss(q_n, q_r, k_n, k_r, v):
+        o = fa._latent_attention_pallas(q_n, q_r, k_n, k_r, v, block_q,
+                                        block_k, False)
+        return jnp.sum(o.astype(jnp.float32))
+
+    exe = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        chip((S, H, Dn), dtype), chip((S, H, Dr), dtype),
+        chip((S, H, Dn), dtype), chip((S, Dr), dtype),
+        chip((S, H, Dn), dtype)).compile()
+    text = exe.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for name in ("latent_attention_fwd", "latent_attention_dq",
+                 "latent_attention_dkv"):
+        assert name in text
+    assert "banded_attention" not in text and f"[{S},{S}]" not in text
+    assert f"{S},256]" not in text and f"{S},{H},256]" not in text
+
+
+def test_latent_attention_layer_runs_three_kernels_under_its_scope(
+        chip, monkeypatch):
+    """`SelfAttentionLayer` with `kv_lora_rank` at the cell's widths, traced
+    as a TPU process would trace it: `latent_attention` resolves `pallas`,
+    the compiled gradient holds the forward and both backward kernels with
+    `mla.attend` in their `op_name`, the projections carry `mla.project`
+    and the rotary step `attn.rope`."""
+    from deeplearning4j_tpu import observability as obs
+    from deeplearning4j_tpu.kernels import registry
+    from deeplearning4j_tpu.nn.conf.layers import SelfAttentionLayer
+    from deeplearning4j_tpu.nn.layers import dsa
+
+    monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")
+    monkeypatch.delenv("DL4J_TPU_KERNELS", raising=False)
+    monkeypatch.delenv("DL4J_TPU_KERNEL_LATENT_ATTENTION", raising=False)
+    registry.clear_cache()
+    S, D = 8192, 2048
+    conf = SelfAttentionLayer(
+        n_in=D, n_out=D, n_heads=16, rope_theta=8e5, causal=True,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128)
+    bf = jnp.bfloat16
+
+    def loss(params, x):
+        out, state, _ = dsa.extended_attention_apply(conf, params, {}, x)
+        assert set(state) == {"band_fill_share"}
+        return jnp.sum(out.astype(jnp.float32))
+
+    def count(impl):
+        fam = obs.metrics.get_family("dl4j_kernel_dispatch_total")
+        return sum(c.get() for c in fam.children() if c.labels == {
+            "kernel": "latent_attention", "impl": impl})
+
+    before = count("pallas"), count("xla")
+    exe = jax.jit(jax.grad(loss)).lower(
+        {n: chip(shape, jnp.float32 if n == "gamma_kv" else bf)
+         for n, shape in conf.param_shapes().items()},
+        chip((1, S, D), bf)).compile()
+    registry.clear_cache()
+    assert (count("pallas"), count("xla")) == (before[0] + 1, before[1])
+    text = exe.as_text()
+    calls = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
+    assert len(calls) == 3, calls
+    assert all("mla.attend" in c and "latent_attention" in c
+               for c in calls), calls
+    assert sum("transpose(" in c for c in calls) == 2, calls
+    assert "mla.project" in text and "attn.rope" in text
+    assert f"[{S},{S}]" not in text
+
+
 @pytest.mark.parametrize("N,D,F,E,Eh,temporaries", [
     (8192, 2048, 768, 128, 16, 2.5e9),      # keye_vl2_30b_a3b.fit_seq8k
     (16384, 2304, 896, 64, 8, 3.0e9),       # mellum2_12b_a2_5b.fit_seq16k

@@ -571,7 +571,8 @@ class TestDispatchMetric:
 PARITY_COVERED = {"lstm_cell", "fused_update", "norm_act", "flash_attention",
                   "flash_attention_paged", "bottleneck_block",
                   "masked_attention",      # test_masked_attention.py
-                  "banded_attention"}      # test_banded_attention.py
+                  "banded_attention",      # test_banded_attention.py
+                  "latent_attention"}      # test_latent_moe_lm.py
 
 
 def test_every_kernel_has_parity_coverage():
