@@ -13,35 +13,54 @@ source = ("https://huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B/blob/main/"
           "config.json")
 
 # What the on-chip check compares, each limit set between two readings on the
-# chip (my chip runs, PR 26; PERF.md section 4): the worst the program gave
-# over its sound seeds (eight on the final tree, after 90 to 162 steps), and
-# what it gives under a planted fault, which has to come out as not correct:
-# every matrix rounded to float8_e4m3fn, half the keys selected, the state
-# left unchanged, half the positions trained on.
+# chip, both taken when the program had made the cell's `check.at_step` = 320
+# train steps (my chip runs, PR 34, calls A, DE and F; PERF.md section 4): the
+# worst the program gave over its sound runs (19 at 320 steps on 11 seeds,
+# and the sound first passes of 6 more checks at 323 and 324), and the least
+# it gave under a planted fault run through the harness on three seeds, which
+# has to come out as not correct: every matrix rounded to float8_e4m3fn (the
+# nearest precision below the configuration's bf16), half the keys selected,
+# the state left unchanged, half the positions trained on. Every reading
+# moves with the steps taken (the loss has fallen from 9.9 to 2.1 by step
+# 320, the two cached batches half memorised), which is why the count is
+# fixed; PR 26's limits, set at 90 to 162 steps, are in `git show
+# f11d091:benchmark/configs/keye_vl2_30b_a3b.py`. "x above" is limit / worst
+# sound reading, "x under" least faulty reading / limit; for a floor both
+# are taken on 1 - the share.
 LIMITS = {
     # bf16 index scores flip near-ties at the index_top_k-th place: smallest
-    # overlap of a layer 0.9923; fp8 0.9791 at best; half the keys 0.587
-    "selection_overlap_min": 0.986,
+    # overlap of a layer (the last), sound 0.9910 to 0.9915 | fp8 0.9606 to
+    # 0.9612, half the keys 0.586: 2.2 x above, 1.9 x under fp8
+    "selection_overlap_min": 0.98,
     # and near-ties at the router's top_k-th place: share of a token's
-    # experts that program and reference agree on, 0.9858; fp8 0.9596
-    "routing_agreement_min": 0.975,
+    # experts that program and reference agree on, worst layer (the last),
+    # sound 0.9773 to 0.9797 | fp8 0.9179 to 0.9187, half the keys 0.898 to
+    # 0.900: 2.0 x above, 1.8 x under fp8
+    "routing_agreement_min": 0.955,
     # loss, the program's (bf16 compute) against the reference's (float32),
     # with its own selection and routing and given the program's:
-    # |difference| / reference, 3.9e-5 at most; fp8 1.3e-4 at least
-    "loss_rel": 8e-5,
+    # |difference| / reference, sound 2.4e-5 to 2.7e-4 | fp8 1.18e-2 to
+    # 1.43e-2, half the keys 2.3e-2 with the reference's own selection (and
+    # sound given the program's): 5.6 x above, 7.9 x under. At 320 steps the
+    # loss separates fp8 from sound sixtyfold, where at 100 to 200 steps
+    # (8e-5, sound to 9.2e-5, fp8 from 1.3e-4) it did not
+    "loss_rel": 1.5e-3,
     # logits of 256 positions and every compared gradient, the reference
     # given the program's selection and routing: ||program - reference|| /
-    # ||reference||. Logits 0.0063 at most; fp8 0.0716. The worst gradient
-    # (attn0.Wo every time) 0.037 to 0.091 after 98 steps, 0.101 after 162:
-    # it rises with the steps taken (PERF.md PR 26); fp8 0.301 and 0.341
-    # after 16 and 26 steps, where a sound run reads 0.03.
-    "logits_rel": 0.03,
-    "grad_rel": 0.20,
+    # ||reference||. Logits sound 0.0053 to 0.0055 | fp8 0.0527 to 0.0531:
+    # 3.7 x above, 2.6 x under. The worst gradient (attn0.Wo every time)
+    # sound 0.068 to 0.118 at 320 steps and 0.090 to 0.213 at 323 and 324: it
+    # swings threefold by the seed | fp8 2.23 to 2.53: 2.8 x above, 3.7 x
+    # under (0.20, the limit until PR 34, is inside the sound readings here)
+    "logits_rel": 0.02,
+    "grad_rel": 0.6,
     # the change one compiled train step makes to a leaf against the
-    # reference's Adam step from the same state, worst leaf: 0.0185 at most
-    # after 98 steps, 0.0279 after 162; half the positions 0.218 (0.081 on
-    # its best leaf); state unchanged 1 on every leaf
-    "update_rel": 0.05,
+    # reference's Adam step from the same state, worst leaf (attn0.Wo every
+    # time): sound 0.043 to 0.064 | half the positions 0.288 to 0.312 (its
+    # best leaf 0.071), half the keys 0.206 to 0.213, state unchanged 1 on
+    # every leaf: 2.2 x above, 2.1 x under half the positions; fp8 reads
+    # 0.114 to 0.124 and is the other limits' to catch
+    "update_rel": 0.14,
 }
 
 

@@ -38,7 +38,15 @@ LIMITS = {
     # reference agree on, worst layer (the last every time; the first reads
     # 0.979 to 0.984). 0.9342 to 0.9444; 0.9226; 0.9230 | 0.8233 to 0.8317,
     # 0.7359 to 0.7455, 0.9055 to 0.9127, 0.7400, 0.4591, 0.2144
-    "routing_agreement_min": 0.90,
+    # **At the cell's fixed count of 288 steps (PR 34; the loss has fallen to
+    # 0.83 there, the two batches memorised, and agreement has sunk with
+    # it): sound 0.894 to 0.918 over 16 runs on 10 seeds and 3 more
+    # first passes at 295 steps | fp8 0.760 to 0.766, no_router_bias
+    # 0.695 to 0.700, bias_in_weights 0.852 to 0.854 (not this limit's to
+    # catch). 0.90, the limit until PR 34, left a sound run no room at 288;
+    # 0.85 is 1.4 x above the worst sound reading and 1.6 x under fp8 on
+    # 1 - the share: the two stand 2.2 x apart, fp8 is the others' to catch.**
+    "routing_agreement_min": 0.85,
     # loss, the program's (bf16 compute) against the reference's (float32),
     # with its own routing and given the program's: |difference| /
     # reference. The bf16 loss stands 1e-4 to 3e-3 from the float32 one in
@@ -49,7 +57,12 @@ LIMITS = {
     # middle of the worst reading at the window's step count and fp8's: five
     # times of room at 1x, three and a half over the one reading at 3x, six
     # under fp8; `bias_in_weights` passes it and fails by the gradients
-    "loss_rel": 5e-3,
+    # **At 288 steps (PR 34): sound 1.9e-4 to 3.8e-3 with the reference's own
+    # routing, 9.4e-5 to 5.6e-4 given the program's | fp8 0.101 to 0.103
+    # own, 0.027 given; no_router_bias 0.207 to 0.220 own. 5e-3, the limit
+    # until PR 34, left 1.4 times of room over the worst sound reading;
+    # 1.5e-2 is 3.9 x above it, 6.7 x under fp8's own, 1.8 x under its given.**
+    "loss_rel": 1.5e-2,
     # logits of 256 positions and every compared gradient, the reference
     # given the program's routing: ||program - reference|| / ||reference||.
     # Logits 0.0070 to 0.0075; 0.0061; 0.0060 | 0.0741 to 0.0748, 0.0074
@@ -57,6 +70,10 @@ LIMITS = {
     # 0.0228, 0.279, 0.676, 30.9. The worst gradient (an attention layer's
     # `Wo`, `Wukv` or `Wdkv`) 0.021 to 0.060; 0.040; 0.086 | 0.686 to 0.897,
     # 0.018, 0.394 to 0.771, 1.56, 2.29, 2.5e4
+    # **At 288 steps (PR 34): logits 0.0061 to 0.0063 | fp8 0.0611 to 0.0621
+    # (3.2 x of room, 3.1 x under); the worst gradient 0.026 to 0.067 | fp8
+    # 0.50 to 0.57, bias_in_weights 1.12 to 1.32 (3.0 x of room, 2.5 x
+    # under): both limits stay.**
     "logits_rel": 0.02,
     "grad_rel": 0.20,
     # the change one compiled train step makes to a leaf against the
@@ -65,6 +82,9 @@ LIMITS = {
     # all 27 leaves; fp8 0.174 to 0.205, no_router_bias 0.520 to 0.582,
     # bias_in_weights 0.080 to 0.088, 0.491, 0.734, 0.865. The room is above the reading: fresh seeds and
     # later checks read higher.
+    # **At 288 steps (PR 34): 0.0097 to 0.0112 | fp8 0.30 to 0.34,
+    # bias_in_weights 0.111 to 0.135 (5.4 x of room, 1.8 x under the
+    # faintest fault): the limit stays.**
     "update_rel": 0.06,
 }
 

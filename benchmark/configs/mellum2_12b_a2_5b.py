@@ -30,6 +30,9 @@ LIMITS = {
     # 0.9954 to 0.9960 (0.9956 at 162 steps) | 0.9658 (its best layer
     # 0.9700), 0.8786, 0.9631 and 0.9743 (the full layer's experts alone);
     # 0.9650, 0.8817, 0.9614, 0.9760
+    # **At 160 steps (PR 34)**: 0.9951 to 0.9958 | 0.9658 to 0.9668, 0.8722
+    # to 0.8765, 0.9395 to 0.9495, 0.9609 to 0.9664: 3 x of room on 1 - the
+    # share, the limit stays
     "routing_agreement_min": 0.985,
     # loss, the program's (bf16 compute) against the reference's (float32),
     # with its own routing and given the program's: |difference| /
@@ -37,6 +40,8 @@ LIMITS = {
     # ~1.5e-4 above the float32 one while the loss falls: the ratio rises
     # with the steps, PERF.md section 4) | 1.0e-3, 1.9e-2, 7.1e-3, 6.2e-3;
     # 1.1e-3, 1.8e-2, 8.0e-3, 5.6e-3
+    # **At 160 steps (PR 34)**: 6.9e-6 to 5.3e-5 | 2.4e-3 to 2.6e-3, 4.1e-2
+    # to 4.4e-2, 1.8e-2 to 2.0e-2, 1.3e-2: 3.8 x of room, the limit stays
     "loss_rel": 2e-4,
     # logits of 256 positions and every compared gradient, the reference
     # given the program's routing: ||program - reference|| / ||reference||.
@@ -44,13 +49,24 @@ LIMITS = {
     # 0.0554; 0.0732, 0.270, 0.0818, 0.0547. The worst gradient (`attn0.Wo`
     # every time) 0.025 to 0.060, 0.044 and 0.0715 at 162 steps | 0.837, 3.57,
     # 0.835, 0.642; 0.750, 3.23, 0.838, 0.650 (on `attn0.Wo` or `attn3.Wq`)
+    # **At the cell's fixed count of 160 steps (PR 34, calls B, DE and F: 19
+    # sound runs on 13 seeds, every fault on three)**: logits 0.0078 to 0.0079
+    # | 0.0759 to 0.0766, 0.279 to 0.290, 0.112 to 0.128, 0.0738 to 0.0807:
+    # 2.5 x of room, the limit stays. The worst gradient (`attn0.Wo` every
+    # time) 0.033 to 0.144: it swings fourfold by the seed, and 0.20, the
+    # limit until PR 34, left 1.4 x of room | 1.44 to 1.80, 2.57 to 5.45, 1.67
+    # to 2.05, 1.02 to 2.05: 0.35 is 2.4 x above the worst sound reading and
+    # 2.9 x under the least faulty one
     "logits_rel": 0.02,
-    "grad_rel": 0.20,
+    "grad_rel": 0.35,
     # the change one compiled train step makes to a leaf against the
     # reference's Adam step from the same state, worst leaf (`attn0.Wo`):
     # 0.0076 to 0.0100, 0.0180 and 0.0212 at 162 steps | state unchanged 1
     # on all 14 leaves. The room is above the reading: fresh seeds and later
     # checks read higher.
+    # **At 160 steps (PR 34)**: 0.0147 to 0.0221 | state unchanged 1 on all
+    # 14 leaves (the window halved 0.056 to 0.063): 2.7 x of room, the limit
+    # stays
     "update_rel": 0.06,
 }
 

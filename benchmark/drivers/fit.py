@@ -85,6 +85,13 @@ def run(cell, args, clock) -> dict:
         problems.append(f"compiled inside the window: {compiled.names} "
                         f"({delta['xla_compiles']} XLA compiles)")
 
+    # Each number compared, beside its limit, for the run's last line.
+    compared = {
+        "loss_last_over_first": [losses[-1] / losses[0] if losses[0]
+                                 else None, 1.05],
+        "compiles_in_window": [len(compiled.names) + delta["xla_compiles"],
+                               0]}
+
     executables = net._get_jit("train_step").executables()
     groups_sorted = sorted(groups)
     return {
@@ -92,6 +99,7 @@ def run(cell, args, clock) -> dict:
         "window_s": window_s,
         "correct": not problems,
         "problems": problems,
+        "compared": compared,
         "attempted": steps,
         "failed": 0,
         "end_to_end": {"fit_samples_per_s": samples / window_s},

@@ -328,6 +328,26 @@ def two_pass_check(net, batch, *, ref, cfg, sizes: dict, limits: dict,
     return {"numbers": numbers, "problems": problems}
 
 
+def compared(numbers: dict) -> dict:
+    """`{name: [reading, limit]}` for every number of a check's `numbers`
+    that was held to a limit, under the limit's own name: the least of a
+    score that has a floor (`<name>_min`), the worst leaf of a dictionary
+    of readings (`<name>_max`), the number itself otherwise;
+    `loss_rel_given` stands under `loss_rel`'s limit. What the run's last
+    line carries, so that a run that missed says which limit and by how
+    much."""
+    out = {}
+    for name, limit in numbers["limits"].items():
+        if name.endswith("_min"):
+            reading = min(numbers[name[:-len("_min")]])
+        else:
+            reading = numbers.get(f"{name}_max", numbers[name])
+        out[name] = [float(reading), float(limit)]
+    out["loss_rel_given"] = [float(numbers["loss_rel_given"]),
+                             float(numbers["limits"]["loss_rel"])]
+    return out
+
+
 def lm_cell(net, sizes: dict, seed: int, *, forward, check) -> dict:
     """What a language model's `build` returns to the `fit` and `fit_ref`
     drivers: `staged_batches` seeded batches of `batch_per_chip` sequences

@@ -37,30 +37,37 @@ def op_names(executables) -> dict:
     return names
 
 
-def seconds_in_scope(events, names: dict, needle: str) -> float:
+def seconds_in_scope(events, names: dict, needle: str,
+                     named: str | None = None) -> float:
     """Seconds of `events` (`(name, start_ns, duration_ns)`, names as
     `trace_reduce.short_name` gives them) whose instruction's `op_name`
-    contains `needle`."""
+    contains `needle`, or whose own name matches the regex `named` (for
+    what the compiler names itself whatever scope issued it)."""
     from benchmark.harness import trace_reduce
 
+    rx = re.compile(named) if named else None
     # A `while` or a `conditional` is an event and so is every operation
-    # inside it: the union of the intervals counts each instant once.
+    # inside it, and one operation can match both ways: the union of the
+    # intervals counts each instant once.
     spans = [(start, start + duration) for name, start, duration in events
              if duration > 0
-             and needle in names.get(name.split(" ", 1)[0], "")]
+             and (needle in names.get(name.split(" ", 1)[0], "")
+                  or (rx is not None and rx.search(name)))]
     return sum(e - s for s, e in trace_reduce.merge(spans)) / 1e9
 
 
-def scope_share_percent(context, needle: str):
+def scope_share_percent(context, needle: str, named: str | None = None):
     """Share of the device's busy time in operations traced under a scope
-    whose name contains `needle`, averaged over the chips used, in percent;
-    None where there is no trace, no program text or no such operation."""
+    whose name contains `needle` (and, with `named`, in operations whose
+    own name matches that regex: the union of both), averaged over the
+    chips used, in percent; None where there is no trace, or neither the
+    program's text nor the trace holds such an operation."""
     reduced = context["tracer"].reduced(context["cell"].chips)
     if not reduced or not reduced["busy_s"]:
         return None
     names = op_names(context.get("executables") or [])
-    if not any(needle in op for op in names.values()):
-        return None
-    seconds = [seconds_in_scope(events, names, needle)
+    seconds = [seconds_in_scope(events, names, needle, named)
                for events in reduced["events"].values()]
+    if not any(needle in op for op in names.values()) and not any(seconds):
+        return None
     return 100.0 * (sum(seconds) / len(seconds)) / reduced["busy_s"]

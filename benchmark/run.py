@@ -7,7 +7,9 @@ runs one cell of `BENCHMARK.json` on the machine it is started on, in one
 process, and prints as the last line of its standard output one JSON object:
 `correct`, `attempted`, `failed`, `metrics`, `device` (and `breakdown` with
 `--trace 1`). With `--trace 0` the metrics are the cell's end-to-end
-metrics, with `--trace 1` its per-layer metrics.
+metrics, with `--trace 1` its per-layer metrics. The line's last key,
+`check`, holds each number the run compared beside its limit (`{name:
+[reading, limit]}`), and so do the last lines of standard error.
 
 Off the TPU, or with fewer chips than the cell asks for, it exits non-zero
 and prints no result. `--rehearsal` is the only way to run off-chip: the
@@ -23,6 +25,7 @@ _T_START = time.perf_counter()  # process start, to within the interpreter's own
 
 import argparse  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -106,6 +109,14 @@ def main(argv=None) -> int:
         line["rehearsal"] = "tiny sizes; not a measurement"
     if result.get("problems"):
         line["problems"] = result["problems"]
+    # Each number the run compared, beside its limit: `{name: [reading,
+    # limit]}`, the line's last key and the last lines of standard error.
+    if result.get("compared"):
+        # a reading that is not finite travels as its name: the line stays
+        # JSON that any parser reads
+        line["check"] = {
+            name: [r if r is None or math.isfinite(r) else repr(r), limit]
+            for name, (r, limit) in result["compared"].items()}
     # An earlier line for the reader: sample counts and what the run saw.
     import jax
 
@@ -116,6 +127,9 @@ def main(argv=None) -> int:
                       "setup_phases_end_s": clock.phases,
                       "end_to_end": result["end_to_end"]}), flush=True)
     print(json.dumps(line), flush=True)
+    for name, (reading, limit) in line.get("check", {}).items():
+        print(f"check {name}: {reading!r} limit {limit!r}", file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

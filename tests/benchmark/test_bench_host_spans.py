@@ -13,7 +13,7 @@ MS = 1_000_000  # ns
 
 READERS = ["fit_enqueue_mean_ms", "fit_dispatch_self_mean_ms",
            "idle_in_dispatch_share.fit", "idle_in_input_wait_share.fit",
-           "idle_unnamed_share.fit", "norm_act_time_share.fit"]
+           "idle_unnamed_share.fit", "pallas_time_share.fit"]
 
 
 def ms(spans):
@@ -33,7 +33,10 @@ CALLER = ms([
 ])
 STAGER = ms([("staging.put", 11, 30), ("staging.put", 45, 10)])
 # The device: busy 0-5, 38-60, 72-100; idle 5-38 (33 ms) and 60-72 (12 ms).
-DEVICE = ms([("fusion.1", 0, 5), ("jvp_norm_act_batchnorm_.3", 38, 12),
+# (`norm_act_time_share.fit` read the second event until PR 34 took the metric
+# out; it is a Pallas call now, for the reader that stayed.)
+PALLAS = "masked_attention_fwd.3 = custom-call(x), target=tpu_custom_call"
+DEVICE = ms([("fusion.1", 0, 5), (PALLAS, 38, 12),
              ("fusion.2", 50, 10), ("fusion.1", 72, 28)])
 
 
@@ -142,7 +145,7 @@ def test_readers_on_a_trace_and_shares_sum_to_the_idle_share(context):
     assert read["idle_in_dispatch_share.fit"] == pytest.approx(30.0)
     assert read["idle_in_input_wait_share.fit"] == pytest.approx(6.0)
     assert read["idle_unnamed_share.fit"] == pytest.approx(5.0)
-    assert read["norm_act_time_share.fit"] == pytest.approx(100 * 12 / 55)
+    assert read["pallas_time_share.fit"] == pytest.approx(100 * 12 / 55)
     run = host_spans.of_run(context)
     in_fit_itself = host_spans.idle_by_span(
         run["chips"][0], run["threads"][run["enqueuing"]])["graph.fit"]
@@ -184,4 +187,7 @@ planes { id: 2 name: "/host:CPU"
     tracer = trace_reduce.WindowTracer(str(tmp_path))
     tracer.state = "done"
     context = {"tracer": tracer, "cell": types.SimpleNamespace(chips=1)}
-    assert cells.load_module("layer_metrics", name).read(context) is None
+    got = cells.load_module("layer_metrics", name).read(context)
+    # the span readers find nothing to read; a share of the busy time in
+    # Pallas calls is read, and is nought
+    assert got == (0.0 if name == "pallas_time_share.fit" else None)
