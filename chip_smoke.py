@@ -458,7 +458,8 @@ def phase_sparse_lm(args, size: Sizes):
          losses=[round(l, 5) for l in losses],
          first_fit_seconds_with_compile=round(first_fit_s, 2),
          seconds_per_step_after=round(steps_s / 3, 4), gauges=gauges,
-         kernels=resolutions("masked_attention"), hbm=hbm(jax.devices()[0]))
+         kernels=resolutions("masked_attention", "grouped_matmul"),
+         hbm=hbm(jax.devices()[0]))
 
 
 # -------------------------------------------------------------------- serve

@@ -21,7 +21,14 @@ and resolves once per jit signature. Kernels:
 - ``bottleneck_block``— the fused ResNet bottleneck chain (conv1x1/BN/act
                         x3 + residual in one VMEM residency, PERF.md §27),
                         `nn/layers/bottleneck.py`'s seam, with an
-                        int8-weight inference variant for serving.
+                        int8-weight inference variant for serving;
+- ``grouped_matmul``  — the dropless experts' grouped products over rows
+                        sorted by expert (`parallel/expert.py::
+                        _held_experts`): rows by group against a table,
+                        against its transpose, and a table's gradient; a
+                        visit list over row tiles and groups built from
+                        the traced group sizes. XLA's candidate is
+                        `jax.lax.ragged_dot`.
 
 `DL4J_TPU_KERNELS=auto|xla|pallas` (+ per-kernel
 `DL4J_TPU_KERNEL_<NAME>`) select the mode; `python -m

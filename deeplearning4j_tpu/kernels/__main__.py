@@ -25,6 +25,9 @@ process would get, from any machine::
         --meta mesh_devices=4
     python -m deeplearning4j_tpu.kernels --backend tpu --probe \
         fused_update "2048,128;16,2048,768" float32 --meta kind=adam
+    python -m deeplearning4j_tpu.kernels --backend tpu --probe \
+        grouped_matmul 131072,2304,896,8 bfloat16,bfloat16 \
+        --meta entry=contracted
 """
 
 from __future__ import annotations

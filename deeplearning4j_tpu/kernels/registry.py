@@ -31,7 +31,11 @@ Env knobs (read at resolve time, so tests can monkeypatch):
 - ``DL4J_TPU_KERNEL_<NAME>`` (e.g. ``DL4J_TPU_KERNEL_LSTM_CELL``) —
   per-kernel override, same values, wins over the global mode.
 
-``python -m deeplearning4j_tpu.kernels`` prints what resolves and why.
+``python -m deeplearning4j_tpu.kernels`` prints what resolves and why, for
+every name in `KERNEL_MODULES`: `bottleneck_block`, `lstm_cell`,
+`fused_update`, `norm_act`, `flash_attention` and its `_paged`, `masked_`,
+`banded_` and `latent_` siblings, and `grouped_matmul` (the dropless
+experts' grouped products, PR 35).
 
 Registration is lazy: kernel modules self-register at import, and
 `resolve()`/`describe()` import them on demand, so importing the
@@ -61,8 +65,10 @@ MODES = ("auto", "xla", "pallas")
 # `banded_attention` exists, and an extended attention layer without an
 # indexer resolves it where it resolved `masked_attention` (PR 30).
 # `latent_attention` (PR 32) is a new name that no older signature resolves:
-# no bump.
-SELECTION_RULES = 5
+# no bump. 6: `grouped_matmul` exists, and on a TPU the dropless experts'
+# grouped products resolve its Pallas body where they were
+# `jax.lax.ragged_dot` calls outside the registry (PR 35).
+SELECTION_RULES = 6
 
 # Meta key the registry itself adds to a signature traced under a mesh of
 # more than one device (and `--probe --meta mesh_devices=N` passes by hand).
@@ -94,6 +100,10 @@ KERNEL_MODULES = {
     # the score tile, its key tile shared by all heads: multi-head latent
     # attention's core (`mla.attend`).
     "latent_attention": "deeplearning4j_tpu.kernels.flash_attention",
+    # The dropless experts' grouped products over rows sorted by expert
+    # (PR 35): rows by group against a table, against its transpose, and a
+    # table's gradient; XLA's candidate is `jax.lax.ragged_dot`.
+    "grouped_matmul": "deeplearning4j_tpu.kernels.grouped_matmul",
 }
 
 

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from deeplearning4j_tpu.kernels import grouped_matmul
+
 
 def init_moe_params(rng_key, d_model: int, d_hidden: int, n_experts: int,
                     dtype=jnp.float32):
@@ -228,28 +230,17 @@ def _permute_scalars(v, inverse):
     return jax.lax.sort((inverse, v), num_keys=1)[1]
 
 
-_GROUPS_CONTRACTED = jax.lax.RaggedDotDimensionNumbers(
-    dot_dimension_numbers=(((0,), (0,)), ((), ())),
-    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
-
-
-def _table_gradient(rows, ct, group_sizes):
-    """[M, A], [M, B] -> [G, A, B]: each group's rows contracted, what
-    `jax.lax.ragged_dot`'s own transpose makes of a table's cotangent."""
-    return jax.lax.ragged_dot_general(rows, ct, group_sizes,
-                                      _GROUPS_CONTRACTED)
-
-
 def _grouped_ffn(x, tables, token, group_sizes):
     """The held experts over the sorted pairs: the rows, the two inner
     products and the output, each `[N * top_k, ...]` in expert order. A
-    grouped product writes the rows of its groups, the live prefix, and
-    leaves the rest of its result as the buffer was."""
+    grouped product (the registry's `grouped_matmul`) writes the rows of its
+    groups, the live prefix, and leaves the rest of its result as the buffer
+    was."""
     w_gate, w_up, w_down = tables
     rows = _rows_of_pairs(x, token)
-    g = jax.lax.ragged_dot(rows, w_gate, group_sizes)
-    u = jax.lax.ragged_dot(rows, w_up, group_sizes)
-    out = jax.lax.ragged_dot(jax.nn.silu(g) * u, w_down, group_sizes)
+    g = grouped_matmul.rows_table(rows, w_gate, group_sizes)
+    u = grouped_matmul.rows_table(rows, w_up, group_sizes)
+    out = grouped_matmul.rows_table(jax.nn.silu(g) * u, w_down, group_sizes)
     return rows, g, u, out
 
 
@@ -293,7 +284,12 @@ def _held_experts_bwd(top_k, res, dy):
     # arrays live from one pass to the other, 3.3 GB more temporaries in the
     # step of `mellum2_12b_a2_5b.fit_seq16k` (PERF.md PR 31).
     x, tables, dy_rows = jax.lax.optimization_barrier((x, tables, dy_rows))
-    w_gate, w_up, w_down = (jnp.swapaxes(w, 1, 2) for w in tables)
+    # The tables as the backward products take them: XLA's candidate wants
+    # transposed copies, made here where they always were; the kernel reads
+    # a table as stored.
+    t_gate, t_up, t_down = (
+        grouped_matmul.transposed(w, dy_rows.shape[0], x.dtype)
+        for w in tables)
     rows, g, u, out = _grouped_ffn(x, tables, token, group_sizes)
     hmid, silu_mul_vjp = jax.vjp(lambda g, u: jax.nn.silu(g) * u, g, u)
     dy_rows = dy_rows.astype(acc)
@@ -303,13 +299,13 @@ def _held_experts_bwd(top_k, res, dy):
     gate = _permute_scalars(gate.reshape(-1), inverse)
     dout = (dy_rows * gate[:, None]).astype(out.dtype)
 
-    dhmid = jax.lax.ragged_dot(dout, w_down, group_sizes)
+    dhmid = grouped_matmul.rows_table_t(dout, t_down, group_sizes)
     dg, du = silu_mul_vjp(dhmid)
-    drows = (jax.lax.ragged_dot(dg, w_gate, group_sizes)
-             + jax.lax.ragged_dot(du, w_up, group_sizes))
-    dtables = (_table_gradient(rows, dg, group_sizes),
-               _table_gradient(rows, du, group_sizes),
-               _table_gradient(hmid, dout, group_sizes))
+    drows = (grouped_matmul.rows_table_t(dg, t_gate, group_sizes)
+             + grouped_matmul.rows_table_t(du, t_up, group_sizes))
+    dtables = (grouped_matmul.contracted(rows, dg, group_sizes),
+               grouped_matmul.contracted(rows, du, group_sizes),
+               grouped_matmul.contracted(hmid, dout, group_sizes))
     drows = drows[inverse].reshape(N, top_k, -1)
     dx = jnp.sum(jnp.where(held[:, :, None], drows, 0), axis=1, dtype=acc)
     return dx.astype(x.dtype), dgate, dtables, None, None, None
@@ -340,8 +336,9 @@ def moe_ffn_dropless(params, x, *, top_k: int, first=0,
     - the rows are gathered whole from `x` by token index
       (`_rows_of_pairs`), no repeat of `x` in front: every row is some
       token's, those past the prefix no held expert's;
-    - each matrix is one grouped product (`jax.lax.ragged_dot`), which on
-      the TPU skips the rows past the groups and leaves them unwritten:
+    - each matrix is one grouped product (the registry's `grouped_matmul`:
+      a Pallas kernel on the TPU, `jax.lax.ragged_dot` elsewhere), which
+      skips the rows past the groups and leaves them unwritten:
       65,536 rows of which 8,192 are live cost what 10,240 rows do (PERF.md
       PR 26). Its result is defined on the live prefix only, and so is
       everything computed from it; with the groups contracted (the tables'

@@ -705,3 +705,69 @@ def test_dropless_experts_at_published_widths(chip, N, D, F, E, Eh,
     selects = re.findall(
         rf"= \w+\[{N * 8},(?:{D}|{F})\]\S* select\(", text)
     assert not selects, selects
+
+
+@pytest.mark.parametrize("N,D,F,E,Eh,K,temporaries", [
+    (8192, 2048, 768, 128, 16, 8, 2.5e9),    # keye_vl2_30b_a3b.fit_seq8k
+    (16384, 2304, 896, 64, 8, 8, 3.0e9),     # mellum2_12b_a2_5b.fit_seq16k
+    (8192, 2048, 1408, 64, 8, 6, 2.5e9),     # kimi_vl_a3b.fit_seq8k
+], ids=["keye_vl2_30b_a3b", "mellum2_12b_a2_5b", "kimi_vl_a3b"])
+def test_dropless_experts_run_the_grouped_kernel_at_published_widths(
+        chip, monkeypatch, N, D, F, E, Eh, K, temporaries):
+    """`expert.moe_ffn_dropless` at the three language models' points, traced
+    as a TPU process traces it: the registry's `grouped_matmul` resolves
+    `pallas` for all twelve grouped products of the layer, the chip's
+    compiler takes every body at the tiles the registry chose, inside the
+    16 MiB of VMEM a kernel has without asking for more (VMEM is found
+    here, not on the chip), none of
+    XLA's `ragged-dot` calls is left, and each call carries `moe.experts`
+    in its `op_name`, which is how `moe_time_share.fit` finds its time."""
+    from deeplearning4j_tpu import observability as obs
+    from deeplearning4j_tpu.kernels import grouped_matmul as gm
+    from deeplearning4j_tpu.kernels import registry
+    from deeplearning4j_tpu.parallel import expert
+
+    monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")
+    monkeypatch.delenv("DL4J_TPU_KERNELS", raising=False)
+    monkeypatch.delenv("DL4J_TPU_KERNEL_GROUPED_MATMUL", raising=False)
+    registry.clear_cache()
+
+    def loss(x, gate_w, w_gate, w_up, w_down):
+        y, aux, _, _ = expert.moe_ffn_dropless(
+            {"gate_w": gate_w, "w_gate": w_gate, "w_up": w_up,
+             "w_down": w_down}, x, top_k=K)
+        return jnp.sum(y.astype(jnp.float32)) + aux
+
+    def count(impl):
+        fam = obs.metrics.get_family("dl4j_kernel_dispatch_total")
+        return sum(c.get() for c in fam.children() if c.labels == {
+            "kernel": "grouped_matmul", "impl": impl})
+
+    bf = jnp.bfloat16
+    before = count("pallas"), count("xla")
+    # the value too: the gradient alone does not need the forward's products
+    exe = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        chip((N, D), bf), chip((D, E), bf), chip((Eh, D, F), bf),
+        chip((Eh, D, F), bf), chip((Eh, F, D), bf)).compile()
+    registry.clear_cache()
+    # a resolution a product: 3 forward, 3 recomputed, 3 + 3 backward
+    assert (count("pallas"), count("xla")) == (before[0] + 12, before[1])
+    text = exe.as_text()
+    assert "ragged-dot" not in text
+    calls = [m for m in re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
+        if "grouped_matmul" in m]
+    assert len(calls) == 12 and all("moe.experts" in c for c in calls), calls
+    kinds = [c.split("/")[-2] for c in calls]
+    assert sorted(kinds) == sorted(
+        ["grouped_matmul_rows_table"] * 6 + ["grouped_matmul_rows_table_t"] * 3
+        + ["grouped_matmul_contracted"] * 3), kinds
+    assert exe.memory_analysis().temp_size_in_bytes < temporaries
+    # the three transposed copies of the tables are gone with XLA's calls
+    assert not re.findall(rf"= bf16\[{Eh},{F},{D}\]\S* transpose\(", text)
+    assert "vmem_limit_bytes" not in text
+    used = [int(n) for n in re.findall(
+        r'grouped_matmul[\w.]* = .*?"used_scoped_memory_configs":\[\{'
+        r'"memory_space":"1","offset":"0","size":"(\d+)"', text)]
+    assert len(used) == 12 and max(used) <= 16 << 20, used
+    assert gm.tiling("rows_table", N * K, D, F, 2) == (256,)

@@ -187,3 +187,46 @@ class TestMoE:
         # Router gradients flow (gate_w moved).
         assert not np.allclose(np.asarray(p["gate_w"]),
                                np.asarray(params["gate_w"]))
+
+
+@pytest.mark.parametrize("scoring", ["softmax", "sigmoid"])
+def test_sharded_dropless_experts_under_the_grouped_kernel_match_xla(
+        scoring, mesh, monkeypatch):
+    """`moe_ffn_dropless_sharded` (each device its own experts under
+    `shard_map`) with the registry's `grouped_matmul` forced to its Pallas
+    body, interpreted, against XLA's `ragged_dot`: y and every gradient.
+    Under `auto` the CPU resolves XLA's."""
+    from deeplearning4j_tpu.kernels import registry
+    from deeplearning4j_tpu.parallel.expert import moe_ffn_dropless_sharded
+
+    rng = np.random.default_rng(7)
+    E, D, F, N, K = 16, 128, 128, 128, 4
+
+    def mk(*shape, scale=1.0):
+        return jnp.asarray(rng.normal(size=shape) * scale, jnp.float32)
+
+    params = {"gate_w": mk(D, E), "w_gate": mk(E, D, F, scale=0.1),
+              "w_up": mk(E, D, F, scale=0.1), "w_down": mk(E, F, D, scale=0.1)}
+    x, r = mk(N, D), mk(N, D)
+
+    def program(params, x):
+        y, aux, _, _ = moe_ffn_dropless_sharded(
+            params, x, mesh, "expert", top_k=K, scoring=scoring)
+        return jnp.sum(y * r) + aux, y
+
+    got = {}
+    for mode in ("auto", "xla", "pallas"):
+        monkeypatch.setenv("DL4J_TPU_KERNEL_GROUPED_MATMUL", mode)
+        registry.clear_cache()
+        (_, y), grads = jax.jit(jax.value_and_grad(
+            program, argnums=(0, 1), has_aux=True))(params, x)
+        got[mode] = jax.tree.leaves((y, grads))
+        assert {res.impl for res in registry.resolved()
+                if res.kernel == "grouped_matmul"} == {
+                    "pallas" if mode == "pallas" else "xla"}
+    registry.clear_cache()
+    for a, b, c in zip(got["pallas"], got["xla"], got["auto"]):
+        assert np.all(np.isfinite(np.asarray(a)))
+        assert np.linalg.norm(np.asarray(a - b)) <= 2e-5 * np.linalg.norm(
+            np.asarray(b))
+        assert np.array_equal(np.asarray(b), np.asarray(c))

@@ -14,6 +14,7 @@ from deeplearning4j_tpu import observability as obs
 from deeplearning4j_tpu.datasets.dataset import DataSet
 from deeplearning4j_tpu.datasets.iterators import DeviceCacheDataSetIterator
 from deeplearning4j_tpu.gradientcheck import check_gradients
+from deeplearning4j_tpu.kernels import grouped_matmul, registry
 from deeplearning4j_tpu.models import zoo
 from deeplearning4j_tpu.nn.conf.layers import (
     MoELayer, RMSNormalization, SelfAttentionLayer, layer_from_dict)
@@ -411,7 +412,10 @@ def test_dropless_dead_rows_may_hold_anything(case, monkeypatch):
 
     monkeypatch.setattr(expert_mod, "_rows_of_pairs",
                         dead_rows_nan(expert_mod._rows_of_pairs))
-    monkeypatch.setattr(jax.lax, "ragged_dot",
+    # the registry's `grouped_matmul` resolves its XLA candidate here, which
+    # looks `jax.lax.ragged_dot` up at each call
+    assert registry.resolve("grouped_matmul").impl == "xla"
+    monkeypatch.setattr(grouped_matmul.jax.lax, "ragged_dot",
                         dead_rows_nan(jax.lax.ragged_dot))
     got = c["run"](c["first"])
     # three gathers into expert order, nine grouped products with rows out
@@ -420,6 +424,96 @@ def test_dropless_dead_rows_may_hold_anything(case, monkeypatch):
     for a, b in zip(got, clean):
         assert np.array_equal(np.asarray(a), np.asarray(b))
     _check_held_case(case, got, c)
+
+
+# The Pallas bodies of `grouped_matmul` want a lane grid and four row tiles:
+# 128 tokens top-4 (512 pairs), rows of 128 and 256. Experts `first ..` of 16
+# held: (12, 6) holds two experts the router does not have, groups of no rows.
+KERNEL_CASES = {"softmax-first-0": ("softmax", 0, 6),
+                "softmax-some-empty": ("softmax", 12, 6),
+                "sigmoid-first-3": ("sigmoid", 3, 5),
+                "sigmoid-some-empty": ("sigmoid", 13, 4)}
+
+
+def _kernel_case(case, dtype):
+    scoring, first, count = KERNEL_CASES[case]
+    rng = np.random.default_rng(21)
+    E, D, F, N, K = 16, 128, 256, 128, 4
+
+    def mk(*shape, scale=1.0):
+        return jnp.asarray(rng.normal(size=shape) * scale, dtype)
+
+    params = {"gate_w": mk(D, E), "w_gate": mk(count, D, F, scale=0.1),
+              "w_up": mk(count, D, F, scale=0.1),
+              "w_down": mk(count, F, D, scale=0.1)}
+    if scoring == "sigmoid":
+        params["gate_b"] = jnp.asarray(rng.normal(size=(E,)) * 0.05,
+                                       jnp.float32)
+    x, r = mk(N, D), jnp.asarray(rng.normal(size=(N, D)), jnp.float32)
+
+    def run(params, x):
+        def program(params, x):
+            y, aux, stats, _ = expert_mod.moe_ffn_dropless(
+                params, x, top_k=K, first=first, scoring=scoring,
+                routed_scaling_factor=2.0 if scoring == "sigmoid" else 1.0)
+            return jnp.sum(y.astype(jnp.float32) * r) + aux, (y, stats)
+        (_, (y, stats)), (dp, dx) = jax.jit(jax.value_and_grad(
+            program, argnums=(0, 1), has_aux=True))(params, x)
+        dp.pop("gate_b", None)          # frozen: it enters the choice alone
+        return dict(dp, y=y, dx=dx), stats
+    return run, params, x, (N * K, first, count, E)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_dropless_experts_under_the_grouped_kernel_match_xla(
+        case, dtype, monkeypatch):
+    """`moe_ffn_dropless` with the registry's `grouped_matmul` forced to its
+    Pallas body (interpreted here) against XLA's `ragged_dot`: y, dx, the
+    router's and the three tables' gradients."""
+    run, params, x, (pairs, first, count, E) = _kernel_case(
+        case, jnp.dtype(dtype))
+    got = {}
+    for mode in ("xla", "pallas"):
+        monkeypatch.setenv("DL4J_TPU_KERNEL_GROUPED_MATMUL", mode)
+        registry.clear_cache()
+        got[mode], stats = run(params, x)
+        assert {r.impl for r in registry.resolved()
+                if r.kernel == "grouped_matmul"} == {mode}
+    registry.clear_cache()
+    assert 0.05 < float(stats[0]) < 0.6
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    for name, want in got["xla"].items():
+        a = np.asarray(got["pallas"][name], np.float32)
+        assert np.all(np.isfinite(a)), name
+        assert _rel(a, np.asarray(want, np.float32)) <= tol, name
+    if first + count > E:       # experts the router has not: their tables'
+        for name in ("w_gate", "w_up", "w_down"):       # gradients are zeros
+            assert not np.any(np.asarray(
+                got["pallas"][name][E - first:], np.float32)), name
+
+
+def test_dead_rows_may_hold_anything_under_the_grouped_kernel(monkeypatch):
+    """The same guard as above for the Pallas bodies: NaN in every row the
+    gathers into expert order make past the live prefix changes nothing."""
+    run, params, x, _ = _kernel_case("softmax-some-empty", jnp.float32)
+    monkeypatch.setenv("DL4J_TPU_KERNEL_GROUPED_MATMUL", "pallas")
+    registry.clear_cache()
+    clean, stats = run(params, x)
+    live = int(round(float(stats[0]) * 512))
+    gathers = []
+
+    def dead_rows_nan(src, token):
+        gathers.append(src.shape)
+        dead = jnp.arange(token.shape[0]) >= live
+        return jnp.where(dead[:, None], jnp.nan, src[token])
+
+    monkeypatch.setattr(expert_mod, "_rows_of_pairs", dead_rows_nan)
+    got, _ = run(params, x)
+    registry.clear_cache()
+    assert len(gathers) == 3 and 0 < live < 512
+    for name, want in clean.items():
+        assert np.array_equal(np.asarray(got[name]), np.asarray(want)), name
 
 
 def test_gradient_check_of_the_new_layers():
