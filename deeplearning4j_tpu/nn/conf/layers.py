@@ -107,10 +107,15 @@ class Layer:
     frozen: Optional[bool] = None
     lora_rank: Optional[int] = None
     lora_alpha: Optional[float] = None
-    # `jax.named_scope` around the layer's forward (and, for an output
-    # layer, its loss): the name reaches every HLO operation's `op_name`,
-    # forward and backward, so a device trace can be read by it. None
-    # (the default) adds no scope and keeps the traced program as it was.
+    # A `jax.named_scope` of the layer's own around its forward (and, for
+    # an output layer, its loss), inside the `L.<vertex>` scope the engine
+    # opens around every vertex (`nn/engine.py::scope`): `lm.head` makes the
+    # head's operations read `.../L.out/lm.head/...` in every HLO
+    # operation's `op_name`, forward and backward, and a reader of a device
+    # trace finds them by that name whatever the vertex is called. Used as
+    # written: choose a dotted lower-case name (`family.part`). None (the
+    # default) adds no scope beside the vertex's. Scopes are metadata: the
+    # compiled program is the same with or without them.
     scope: Optional[str] = None
 
     # ---- shape inference ----

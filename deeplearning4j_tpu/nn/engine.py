@@ -23,6 +23,8 @@ from __future__ import annotations
 import copy
 import math
 import os
+import re
+from contextlib import nullcontext
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -53,6 +55,30 @@ from deeplearning4j_tpu.nn.conf.layers import is_bias_param
 from deeplearning4j_tpu.ops import grad_norm as grad_norm_mod
 from deeplearning4j_tpu.ops import schedules as schedules_mod
 from deeplearning4j_tpu.ops import updaters as updaters_mod
+
+
+_SCOPE_UNSAFE = re.compile(r"[^A-Za-z0-9_\-]")
+
+
+def scope(name, prefix: str = ""):
+    """The `jax.named_scope` the engine runs a part of a compiled program
+    under: the name reaches the `op_name` of every HLO operation traced
+    inside, forward and backward (`transpose(jvp(L.attn2))`), so a device
+    trace reads by it; metadata only, the program itself does not move.
+    The names are contract (PERF.md section 3): `L.<vertex>` around all of
+    a vertex (`prefix="L."`: its parameters' cast, its preprocessor, its
+    forward, an output layer's loss, and inside `step.update` its updater),
+    `step.<phase>` around a phase of the train step (`prefix="step."`), and
+    with no prefix a name a layer chose itself (`Layer.scope`), as written.
+    A prefixed name keeps letters, digits, `_` and `-` only: a user's vertex
+    `moe.x` runs under `L.moe_x`, so no vertex name can read as one of the
+    dotted scopes the layer bodies open (`moe.`, `lm.head`, ...). No name,
+    no scope."""
+    if not name:
+        return nullcontext()
+    if prefix:
+        name = prefix + _SCOPE_UNSAFE.sub("_", str(name))
+    return jax.named_scope(name)
 
 
 def _first(part):
@@ -428,14 +454,15 @@ class Engine:
             l2 = float(layer.l2 or 0.0)
             if (l1 == 0.0 and l2 == 0.0) or key not in params:
                 continue
-            for wk in layer.weight_param_keys():
-                if wk not in params[key]:
-                    continue
-                w = params[key][wk].astype(self._loss_dtype)
-                if l2:
-                    total = total + 0.5 * l2 * jnp.sum(w * w)
-                if l1:
-                    total = total + l1 * jnp.sum(jnp.abs(w))
+            with scope(key, "L."):
+                for wk in layer.weight_param_keys():
+                    if wk not in params[key]:
+                        continue
+                    w = params[key][wk].astype(self._loss_dtype)
+                    if l2:
+                        total = total + 0.5 * l2 * jnp.sum(w * w)
+                    if l1:
+                        total = total + l1 * jnp.sum(jnp.abs(w))
         return total
 
     # ----------------------------------------------------------- train step
@@ -476,15 +503,18 @@ class Engine:
 
             (_, (loss, new_state)), grads = jax.value_and_grad(
                 scaled_loss_fn, has_aux=True)(params)
-            grads = jax.tree_util.tree_map(
-                lambda a: a.astype(jnp.float32) / scale, grads)
-            finite = jnp.bool_(True)
-            for leaf in jax.tree_util.tree_leaves(grads):
-                finite = jnp.logical_and(finite, jnp.all(jnp.isfinite(leaf)))
+            with scope("grad_cast", "step."):
+                grads = jax.tree_util.tree_map(
+                    lambda a: a.astype(jnp.float32) / scale, grads)
+                finite = jnp.bool_(True)
+                for leaf in jax.tree_util.tree_leaves(grads):
+                    finite = jnp.logical_and(finite,
+                                             jnp.all(jnp.isfinite(leaf)))
         else:
             (loss, new_state), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
             if lowp:
-                grads = params_mod.cast_floating(grads, jnp.float32)
+                with scope("grad_cast", "step."):
+                    grads = params_mod.cast_floating(grads, jnp.float32)
 
         # Low-precision params: updates apply to the f32 MASTER copy (and
         # f32 updater state); stored params are its cast, so tiny updates
@@ -496,51 +526,55 @@ class Engine:
         new_base, new_opt, stats = self._apply_updates(
             base, grads, opt_state, step, collect_stats=collect_stats)
 
-        if scaling:
-            # Skip-step on non-finite scaled grads: every updated leaf
-            # selects its OLD value (params, updater state, batch stats),
-            # then the scale backs off; after `growth_interval` consecutive
-            # finite steps it grows. All `jnp.where` on device — no host
-            # sync, superstep-safe.
-            def sel(n, o):
-                return jnp.where(finite, n, o)
+        # `step.store`: what turns the updated master into the stored tree
+        # (the skip-step selects, the master -> stored cast, the frozen merge).
+        with scope("store", "step."):
+            if scaling:
+                # Skip-step on non-finite scaled grads: every updated leaf
+                # selects its OLD value (params, updater state, batch stats),
+                # then the scale backs off; after `growth_interval` consecutive
+                # finite steps it grows. All `jnp.where` on device — no host
+                # sync, superstep-safe.
+                def sel(n, o):
+                    return jnp.where(finite, n, o)
 
-            new_base = jax.tree_util.tree_map(
-                sel, new_base, {n: base[n] for n in new_base})
-            new_opt = jax.tree_util.tree_map(
-                sel, new_opt, {n: opt_state[n] for n in new_opt})
-            new_state = {
-                n: {k: (sel(v, state[n][k])
-                        if n in state and k in state[n] else v)
-                    for k, v in s.items()}
-                for n, s in new_state.items()
-            }
-            new_good = jnp.where(finite, good + 1.0, jnp.float32(0.0))
-            grow = new_good >= jnp.float32(pol.loss_scale_growth_interval)
-            new_scale = jnp.where(
-                finite,
-                jnp.where(grow,
-                          scale * jnp.float32(pol.loss_scale_growth_factor),
-                          scale),
-                scale * jnp.float32(pol.loss_scale_backoff_factor))
-            new_good = jnp.where(grow, jnp.float32(0.0), new_good)
+                new_base = jax.tree_util.tree_map(
+                    sel, new_base, {n: base[n] for n in new_base})
+                new_opt = jax.tree_util.tree_map(
+                    sel, new_opt, {n: opt_state[n] for n in new_opt})
+                new_state = {
+                    n: {k: (sel(v, state[n][k])
+                            if n in state and k in state[n] else v)
+                        for k, v in s.items()}
+                    for n, s in new_state.items()
+                }
+                new_good = jnp.where(finite, good + 1.0, jnp.float32(0.0))
+                grow = new_good >= jnp.float32(pol.loss_scale_growth_interval)
+                new_scale = jnp.where(
+                    finite,
+                    jnp.where(grow,
+                              scale * jnp.float32(pol.loss_scale_growth_factor),
+                              scale),
+                    scale * jnp.float32(pol.loss_scale_backoff_factor))
+                new_good = jnp.where(grow, jnp.float32(0.0), new_good)
 
-        if lowp:
-            new_params = params_mod.cast_floating(new_base, pol.jnp_param)
-            if frozen_stored is not None:
-                # Frozen STORED leaves pass through untouched (no recast);
-                # the master keeps its frozen f32 copies alongside.
-                new_params = transfer_mod.merge_tree(new_params, frozen_stored)
-                new_opt["_master"] = transfer_mod.merge_tree(
-                    new_base, frozen_master)
+            if lowp:
+                new_params = params_mod.cast_floating(new_base, pol.jnp_param)
+                if frozen_stored is not None:
+                    # Frozen STORED leaves pass through untouched (no recast);
+                    # the master keeps its frozen f32 copies alongside.
+                    new_params = transfer_mod.merge_tree(new_params,
+                                                         frozen_stored)
+                    new_opt["_master"] = transfer_mod.merge_tree(
+                        new_base, frozen_master)
+                else:
+                    new_opt["_master"] = new_base
+            elif frozen_stored is not None:
+                new_params = transfer_mod.merge_tree(new_base, frozen_stored)
             else:
-                new_opt["_master"] = new_base
-        elif frozen_stored is not None:
-            new_params = transfer_mod.merge_tree(new_base, frozen_stored)
-        else:
-            new_params = new_base
-        if scaling:
-            new_opt["_ls"] = (new_scale, new_good)
+                new_params = new_base
+            if scaling:
+                new_opt["_ls"] = (new_scale, new_good)
 
         # Merge persistent-state updates (BN stats / rnn carries) over old state.
         merged_state = dict(state)
@@ -556,7 +590,8 @@ class Engine:
                        collect_stats=False):
         """Per-layer gradient-normalize + updater + param update (traced) —
         the reference's LayerUpdater stack. Shared by `_train_step` and
-        `parallel/pipeline_trainer.py`'s pipelined step."""
+        `parallel/pipeline_trainer.py`'s pipelined step; all of it runs under
+        `step.update`, each layer's part under `step.update/L.<key>`."""
         g = self.conf.global_conf
         sign = 1.0 if g.minimize else -1.0
         new_params: Dict[str, Any] = {}
@@ -568,38 +603,46 @@ class Engine:
                 new_params[key] = params.get(key, {})
                 new_opt[key] = opt_state.get(key, ())
                 continue
-            lgrads = grad_norm_mod.normalize_layer_gradients(
-                lgrads, layer.gradient_normalization,
-                float(layer.gradient_normalization_threshold or 1.0),
-            )
-            lr = self._schedules[key](step)
-            st, deltas = self._updaters[key].update(opt_state[key], lgrads, lr, step)
-            base_lr = float(layer.learning_rate if layer.learning_rate is not None else g.learning_rate)
-            bias_lr = float(layer.bias_learning_rate if layer.bias_learning_rate is not None else base_lr)
-            if bias_lr != base_lr and base_lr != 0.0:
-                factor = bias_lr / base_lr
-                # is_bias_param covers every bias name (b, b_f/b_b for
-                # bidirectional RNNs, vb/eb/db for RBM/VAE, beta for BN) —
-                # reference `LayerUpdater.java:243` applies biasLearningRate
-                # per param TYPE, not only to params literally named "b".
-                deltas = {k: (d * factor if is_bias_param(k) else d)
-                          for k, d in deltas.items()}
-            new_params[key] = {
-                k: params[key][k] - sign * deltas[k] for k in params[key]
-            }
-            new_opt[key] = st
-            if collect_stats:
-                # Per-param mean magnitudes of gradient/update/param, computed
-                # in-jit so only scalars cross the device boundary (reference
-                # StatsListener "mean magnitudes", BaseStatsListener.java:273).
-                stats[key] = {
-                    k: {
-                        "grad_mm": jnp.mean(jnp.abs(lgrads[k])),
-                        "update_mm": jnp.mean(jnp.abs(deltas[k])),
-                        "param_mm": jnp.mean(jnp.abs(new_params[key][k])),
-                    }
-                    for k in lgrads
+            with scope("update", "step."), scope(key, "L."):
+                lgrads = grad_norm_mod.normalize_layer_gradients(
+                    lgrads, layer.gradient_normalization,
+                    float(layer.gradient_normalization_threshold or 1.0),
+                )
+                lr = self._schedules[key](step)
+                st, deltas = self._updaters[key].update(
+                    opt_state[key], lgrads, lr, step)
+                base_lr = float(layer.learning_rate
+                                if layer.learning_rate is not None
+                                else g.learning_rate)
+                bias_lr = float(layer.bias_learning_rate
+                                if layer.bias_learning_rate is not None
+                                else base_lr)
+                if bias_lr != base_lr and base_lr != 0.0:
+                    factor = bias_lr / base_lr
+                    # is_bias_param covers every bias name (b, b_f/b_b for
+                    # bidirectional RNNs, vb/eb/db for RBM/VAE, beta for BN)
+                    # — reference `LayerUpdater.java:243` applies
+                    # biasLearningRate per param TYPE, not only to params
+                    # literally named "b".
+                    deltas = {k: (d * factor if is_bias_param(k) else d)
+                              for k, d in deltas.items()}
+                new_params[key] = {
+                    k: params[key][k] - sign * deltas[k] for k in params[key]
                 }
+                new_opt[key] = st
+                if collect_stats:
+                    # Per-param mean magnitudes of gradient/update/param,
+                    # computed in-jit so only scalars cross the device
+                    # boundary (reference StatsListener "mean magnitudes",
+                    # BaseStatsListener.java:273).
+                    stats[key] = {
+                        k: {
+                            "grad_mm": jnp.mean(jnp.abs(lgrads[k])),
+                            "update_mm": jnp.mean(jnp.abs(deltas[k])),
+                            "param_mm": jnp.mean(jnp.abs(new_params[key][k])),
+                        }
+                        for k in lgrads
+                    }
         return new_params, new_opt, stats
 
     # ------------------------------------------------------------------ fit

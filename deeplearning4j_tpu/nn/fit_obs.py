@@ -12,6 +12,15 @@ import, observability/metrics.py rule 2) and opens the spans of that loop:
 
 `<e>.iteration` minus `<e>.enqueue` is the engine's own host time per
 step. The names are read by `benchmark/harness/host_spans.py`.
+
+These are the host's side. The device's side is named where the step is
+traced, not here: `nn/engine.py::scope` runs every vertex under
+`L.<vertex>` and every phase of the train step under `step.grad_cast`,
+`step.update` (a layer's part under `L.<key>` inside it) and `step.store`,
+`jax.named_scope`s that reach each HLO operation's `op_name`; a layer body's
+own scopes (`dsa.attend`, `moe.experts`, `Layer.scope`, ...) nest inside.
+`benchmark/harness/scope_table.py` reads a traced step by them. The gauges
+below ride in the step's state and are set where the score is read.
 """
 
 from __future__ import annotations

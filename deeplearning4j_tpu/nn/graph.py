@@ -9,7 +9,6 @@ objects never exist at runtime.
 
 from __future__ import annotations
 
-from contextlib import nullcontext
 from typing import Any, Dict, List, Optional, Sequence
 
 import jax
@@ -26,17 +25,11 @@ from deeplearning4j_tpu.nn.conf.graph import (
 )
 from deeplearning4j_tpu.nn.conf.neural_net import ComputationGraphConfiguration
 from deeplearning4j_tpu.nn.conf import preprocessors as preprocessors_mod
-from deeplearning4j_tpu.nn.engine import Engine
+from deeplearning4j_tpu.nn.engine import Engine, scope
 from deeplearning4j_tpu.nn.layers import OUTPUT_LAYER_TYPES, get_impl
 from deeplearning4j_tpu.datasets.dataset import DataSet, MultiDataSet
 from deeplearning4j_tpu.datasets.iterators import MultiSuperbatch, Superbatch
 from deeplearning4j_tpu.nn.fit_obs import FitObs
-
-
-def _layer_scope(layer):
-    """`jax.named_scope(layer.scope)` where a layer names one, else nothing
-    (the traced program of a net without scopes is unchanged)."""
-    return jax.named_scope(layer.scope) if layer.scope else nullcontext()
 
 
 def _as_mds(data, labels=None):
@@ -148,68 +141,72 @@ class ComputationGraph(Engine):
             # 0-255 -> 0-1 on device — but only for value consumers; an
             # input feeding an ids-format EmbeddingLayer is cast, and a
             # uint8 input feeding both kinds raises instead of guessing.
-            values[name] = preprocessors_mod.apply_uint8_policy(
-                jnp.asarray(inputs[i]), policies[name], cdt)
+            with scope(name, "L."):
+                values[name] = preprocessors_mod.apply_uint8_policy(
+                    jnp.asarray(inputs[i]), policies[name], cdt)
             masks[name] = None if fmasks is None else fmasks[i]
         new_state: Dict[str, Any] = {}
         aux: Dict[str, Any] = {}
         for vi, name in enumerate(self.topo_order):
-            vertex = self.conf.vertices[name]
-            in_names = self.conf.vertex_inputs[name]
-            in_vals = [values[n] for n in in_names]
-            in_masks = [masks[n] for n in in_names]
-            if isinstance(vertex, LayerVertex):
-                x, mask = in_vals[0], in_masks[0]
-                if vertex.preprocessor is not None:
-                    x, mask = vertex.preprocessor(x, mask)
-                layer = vertex.layer
-                if type(layer).__name__ == "CenterLossOutputLayer":
-                    aux[f"center_loss_input:{name}"] = x
-                    aux[f"centers:{name}"] = state.get(name, {}).get("centers")
-                lrng = jax.random.fold_in(rng, vi) if rng is not None else None
-                # Params stored at param_dtype, cast (or dequantized) to the
-                # policy's compute dtype at use (nn/params.py).
-                lparams = params_mod.prep_layer_params(params.get(name, {}),
-                                                       cdt, layer=layer)
-                with _layer_scope(layer):
-                    out, lstate_new, mask = get_impl(layer)(
-                        layer, lparams, state.get(name, {}), x,
-                        rng=lrng, train=train, mask=mask,
-                    )
-                if lstate_new and "_aux_loss" in lstate_new:
-                    # Reserved key: auxiliary loss terms (MoE load balance)
-                    # go into the objective, never persist as state.
-                    lstate_new = dict(lstate_new)
-                    aux["aux_loss"] = aux.get("aux_loss", 0.0) + \
-                        lstate_new.pop("_aux_loss")
-                if lstate_new:
-                    # `_name`: a layer's by-product (the keys an attention
-                    # layer selected, the experts a token was routed to),
-                    # never state; a collecting pass hands it out as
-                    # `<layer>.<name>`.
-                    declared = set(layer.state_shapes())
-                    keep = {k: v for k, v in lstate_new.items()
-                            if not k.startswith("_")
-                            and (k in declared or keep_rnn_state)}
-                    if keep:
-                        new_state[name] = keep
-                    if collect:
-                        values.update({f"{name}.{k[1:]}": v
-                                       for k, v in lstate_new.items()
-                                       if k.startswith("_")})
-                values[name] = out
-                masks[name] = mask
-            elif isinstance(vertex, DuplicateToTimeSeriesVertex):
-                ref = values[vertex.input_name]
-                values[name] = vertex.apply(in_vals, in_masks, time_steps=ref.shape[1])
-                masks[name] = masks.get(vertex.input_name)
-            elif isinstance(vertex, LastTimeStepVertex):
-                m = masks.get(vertex.mask_array_input) if vertex.mask_array_input else in_masks[0]
-                values[name] = vertex.apply(in_vals, [m])
-                masks[name] = None
-            else:
-                values[name] = vertex.apply(in_vals, in_masks)
-                masks[name] = in_masks[0] if in_masks else None
+            # Every vertex, a layer or not, runs under `L.<name>`: the cast
+            # of its parameters and its preprocessor too.
+            with scope(name, "L."):
+                vertex = self.conf.vertices[name]
+                in_names = self.conf.vertex_inputs[name]
+                in_vals = [values[n] for n in in_names]
+                in_masks = [masks[n] for n in in_names]
+                if isinstance(vertex, LayerVertex):
+                    x, mask = in_vals[0], in_masks[0]
+                    if vertex.preprocessor is not None:
+                        x, mask = vertex.preprocessor(x, mask)
+                    layer = vertex.layer
+                    if type(layer).__name__ == "CenterLossOutputLayer":
+                        aux[f"center_loss_input:{name}"] = x
+                        aux[f"centers:{name}"] = state.get(name, {}).get("centers")
+                    lrng = jax.random.fold_in(rng, vi) if rng is not None else None
+                    # Params stored at param_dtype, cast (or dequantized) to the
+                    # policy's compute dtype at use (nn/params.py).
+                    lparams = params_mod.prep_layer_params(params.get(name, {}),
+                                                           cdt, layer=layer)
+                    with scope(layer.scope):
+                        out, lstate_new, mask = get_impl(layer)(
+                            layer, lparams, state.get(name, {}), x,
+                            rng=lrng, train=train, mask=mask,
+                        )
+                    if lstate_new and "_aux_loss" in lstate_new:
+                        # Reserved key: auxiliary loss terms (MoE load balance)
+                        # go into the objective, never persist as state.
+                        lstate_new = dict(lstate_new)
+                        aux["aux_loss"] = aux.get("aux_loss", 0.0) + \
+                            lstate_new.pop("_aux_loss")
+                    if lstate_new:
+                        # `_name`: a layer's by-product (the keys an attention
+                        # layer selected, the experts a token was routed to),
+                        # never state; a collecting pass hands it out as
+                        # `<layer>.<name>`.
+                        declared = set(layer.state_shapes())
+                        keep = {k: v for k, v in lstate_new.items()
+                                if not k.startswith("_")
+                                and (k in declared or keep_rnn_state)}
+                        if keep:
+                            new_state[name] = keep
+                        if collect:
+                            values.update({f"{name}.{k[1:]}": v
+                                           for k, v in lstate_new.items()
+                                           if k.startswith("_")})
+                    values[name] = out
+                    masks[name] = mask
+                elif isinstance(vertex, DuplicateToTimeSeriesVertex):
+                    ref = values[vertex.input_name]
+                    values[name] = vertex.apply(in_vals, in_masks, time_steps=ref.shape[1])
+                    masks[name] = masks.get(vertex.input_name)
+                elif isinstance(vertex, LastTimeStepVertex):
+                    m = masks.get(vertex.mask_array_input) if vertex.mask_array_input else in_masks[0]
+                    values[name] = vertex.apply(in_vals, [m])
+                    masks[name] = None
+                else:
+                    values[name] = vertex.apply(in_vals, in_masks)
+                    masks[name] = in_masks[0] if in_masks else None
         outs = [values[n] for n in self.conf.network_outputs]
         omasks = [masks.get(n) for n in self.conf.network_outputs]
         if collect:
@@ -254,39 +251,41 @@ class ComputationGraph(Engine):
             if v is None or type(v.layer).__name__ not in OUTPUT_LAYER_TYPES:
                 raise ValueError(f"Network output {name!r} is not an output layer")
             layer = v.layer
-            preout = outs[i].astype(self._loss_dtype)
-            y = labels[i]
-            lmask = lmasks[i] if lmasks is not None else None
-            if lmask is None and omasks and omasks[i] is not None and preout.ndim == 3:
-                lmask = omasks[i]
-            # `ebs` overrides the divisors for tBPTT chunks (full-sequence
-            # minibatch count, see MultiLayerNetwork._loss_from_preout).
-            eb = ebs[i] if ebs is not None else losses_mod.effective_batch_size(y, lmask)
-            if i == 0:
-                eb0 = eb
-            with _layer_scope(layer):
-                total = total + losses_mod.score(
-                    layer.loss_function, y, preout, layer.activation, lmask,
-                    average=False,
-                ) / eb
-            if type(layer).__name__ == "CenterLossOutputLayer":
-                feats = aux[f"center_loss_input:{name}"].astype(self._loss_dtype)
-                centers = aux[f"centers:{name}"]
-                cls = (jnp.asarray(y, jnp.int32)
-                       if jnp.issubdtype(jnp.asarray(y).dtype, jnp.integer)
-                       else jnp.argmax(y, axis=-1))
-                c = centers[cls]
-                # Row weights: labels mask excludes data-parallel padding rows
-                # from the center-loss term and the center updates.
-                w = jnp.ones(y.shape[0], self._loss_dtype) if lmask is None else (
-                    lmask.reshape(y.shape[0], -1)[:, 0].astype(self._loss_dtype))
-                total = total + 0.5 * layer.lambda_ * jnp.sum(
-                    w * jnp.sum((feats - c) ** 2, axis=-1)) / eb
-                diff = (c - feats) * w[:, None]
-                num = jax.ops.segment_sum(diff, cls, num_segments=layer.n_out)
-                cnt = jax.ops.segment_sum(w.astype(jnp.float32), cls,
-                                          num_segments=layer.n_out)
-                extra_state[name] = {"centers": centers - layer.alpha * num / (1.0 + cnt)[:, None]}
+            # The loss belongs to its output vertex: same `L.<name>`.
+            with scope(name, "L."):
+                preout = outs[i].astype(self._loss_dtype)
+                y = labels[i]
+                lmask = lmasks[i] if lmasks is not None else None
+                if lmask is None and omasks and omasks[i] is not None and preout.ndim == 3:
+                    lmask = omasks[i]
+                # `ebs` overrides the divisors for tBPTT chunks (full-sequence
+                # minibatch count, see MultiLayerNetwork._loss_from_preout).
+                eb = ebs[i] if ebs is not None else losses_mod.effective_batch_size(y, lmask)
+                if i == 0:
+                    eb0 = eb
+                with scope(layer.scope):
+                    total = total + losses_mod.score(
+                        layer.loss_function, y, preout, layer.activation, lmask,
+                        average=False,
+                    ) / eb
+                if type(layer).__name__ == "CenterLossOutputLayer":
+                    feats = aux[f"center_loss_input:{name}"].astype(self._loss_dtype)
+                    centers = aux[f"centers:{name}"]
+                    cls = (jnp.asarray(y, jnp.int32)
+                           if jnp.issubdtype(jnp.asarray(y).dtype, jnp.integer)
+                           else jnp.argmax(y, axis=-1))
+                    c = centers[cls]
+                    # Row weights: labels mask excludes data-parallel padding rows
+                    # from the center-loss term and the center updates.
+                    w = jnp.ones(y.shape[0], self._loss_dtype) if lmask is None else (
+                        lmask.reshape(y.shape[0], -1)[:, 0].astype(self._loss_dtype))
+                    total = total + 0.5 * layer.lambda_ * jnp.sum(
+                        w * jnp.sum((feats - c) ** 2, axis=-1)) / eb
+                    diff = (c - feats) * w[:, None]
+                    num = jax.ops.segment_sum(diff, cls, num_segments=layer.n_out)
+                    cnt = jax.ops.segment_sum(w.astype(jnp.float32), cls,
+                                              num_segments=layer.n_out)
+                    extra_state[name] = {"centers": centers - layer.alpha * num / (1.0 + cnt)[:, None]}
         if "aux_loss" in aux:
             # Layer-emitted auxiliary objectives (MoE load balance), already
             # scaled per-layer; batch-size-invariant means, not divided by eb.

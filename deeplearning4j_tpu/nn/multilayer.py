@@ -31,7 +31,7 @@ from deeplearning4j_tpu.nn import params as params_mod
 from deeplearning4j_tpu.nn.conf.layers import CenterLossOutputLayer
 from deeplearning4j_tpu.nn.conf.neural_net import MultiLayerConfiguration
 from deeplearning4j_tpu.nn.conf import preprocessors as preprocessors_mod
-from deeplearning4j_tpu.nn.engine import Engine
+from deeplearning4j_tpu.nn.engine import Engine, scope
 from deeplearning4j_tpu.nn.layers import OUTPUT_LAYER_TYPES, get_impl
 from deeplearning4j_tpu.datasets.dataset import DataSet
 from deeplearning4j_tpu.datasets.iterators import Superbatch, maybe_reset
@@ -127,8 +127,9 @@ class MultiLayerNetwork(Engine):
         # traffic of streamed image batches. The uint8
         # interpretation (image bytes vs embedding ids) is decided by the
         # first layer's declared structure, not sniffed from the dtype.
-        x = preprocessors_mod.apply_uint8_policy(
-            jnp.asarray(x), self._uint8_policy, cdt)
+        with scope("input", "L."):
+            x = preprocessors_mod.apply_uint8_policy(
+                jnp.asarray(x), self._uint8_policy, cdt)
         mask = fmask
         new_state: Dict[str, Any] = {}
         acts: List[jnp.ndarray] = []
@@ -137,20 +138,24 @@ class MultiLayerNetwork(Engine):
         for i in range(n):
             layer = self.layers[i]
             lk = self.layer_keys[i]
-            if i in self.conf.input_preprocessors:
-                x, mask = self.conf.input_preprocessors[i](x, mask)
-            if isinstance(layer, CenterLossOutputLayer):
-                aux["center_loss_input"] = x
-                aux["centers"] = state.get(lk, {}).get("centers")
-            lrng = jax.random.fold_in(rng, i) if rng is not None else None
-            # Params stored at param_dtype, cast (or dequantized) to the
-            # policy's compute dtype at use (nn/params.py).
-            lparams = params_mod.prep_layer_params(params.get(lk, {}), cdt,
-                                                   layer=layer)
-            lstate = state.get(lk, {})
-            x, lstate_new, mask = get_impl(layer)(
-                layer, lparams, lstate, x, rng=lrng, train=train, mask=mask
-            )
+            # A layer runs under `L.<key>`, as a graph's vertex does: the
+            # cast of its parameters and its preprocessor too.
+            with scope(lk, "L."):
+                if i in self.conf.input_preprocessors:
+                    x, mask = self.conf.input_preprocessors[i](x, mask)
+                if isinstance(layer, CenterLossOutputLayer):
+                    aux["center_loss_input"] = x
+                    aux["centers"] = state.get(lk, {}).get("centers")
+                lrng = jax.random.fold_in(rng, i) if rng is not None else None
+                # Params stored at param_dtype, cast (or dequantized) to the
+                # policy's compute dtype at use (nn/params.py).
+                lparams = params_mod.prep_layer_params(params.get(lk, {}),
+                                                       cdt, layer=layer)
+                lstate = state.get(lk, {})
+                with scope(layer.scope):
+                    x, lstate_new, mask = get_impl(layer)(
+                        layer, lparams, lstate, x, rng=lrng, train=train,
+                        mask=mask)
             if lstate_new and "_aux_loss" in lstate_new:
                 # Reserved key: auxiliary loss terms (MoE load balance) are
                 # collected into the objective, never persisted as state.
@@ -209,39 +214,42 @@ class MultiLayerNetwork(Engine):
             raise ValueError(
                 f"Last layer ({name}) is not an output layer; cannot compute loss"
             )
-        preout = preout.astype(self._loss_dtype)
-        # `eb` overrides the divisor for tBPTT chunks: a row fully masked
-        # within ONE chunk of a variable-length batch still counts toward the
-        # reference's divide-by-minibatch (computed from the full-sequence
-        # mask in `_fit_tbptt`), while data-parallel padding rows never do.
-        if eb is None:
-            eb = losses_mod.effective_batch_size(y, lmask)
-        data_loss = losses_mod.score(
-            layer.loss_function, y, preout, layer.activation, lmask,
-            average=False,
-        ) / eb
-        extra_state = {}
-        if isinstance(layer, CenterLossOutputLayer):
-            feats = aux["center_loss_input"].astype(self._loss_dtype)
-            centers = aux["centers"]
-            cls = (jnp.asarray(y, jnp.int32)
-                   if jnp.issubdtype(jnp.asarray(y).dtype, jnp.integer)
-                   else jnp.argmax(y, axis=-1))
-            c = centers[cls]
-            # Row weights: the labels mask excludes data-parallel padding rows
-            # from both the center-loss term and the center updates.
-            w = jnp.ones(y.shape[0], self._loss_dtype) if lmask is None else (
-                lmask.reshape(y.shape[0], -1)[:, 0].astype(self._loss_dtype))
-            data_loss = data_loss + 0.5 * layer.lambda_ * jnp.sum(
-                w * jnp.sum((feats - c) ** 2, axis=-1)
-            ) / eb
-            # EMA center update (reference: CenterLossOutputLayer center updates)
-            diff = (c - feats) * w[:, None]
-            num = jax.ops.segment_sum(diff, cls, num_segments=layer.n_out)
-            cnt = jax.ops.segment_sum(w.astype(jnp.float32), cls,
-                                      num_segments=layer.n_out)
-            new_centers = centers - layer.alpha * num / (1.0 + cnt)[:, None]
-            extra_state = {self.layer_keys[-1]: {"centers": new_centers}}
+        # The loss belongs to the output layer: same `L.<key>`.
+        with scope(self.layer_keys[-1], "L."):
+            preout = preout.astype(self._loss_dtype)
+            # `eb` overrides the divisor for tBPTT chunks: a row fully masked
+            # within ONE chunk of a variable-length batch still counts toward the
+            # reference's divide-by-minibatch (computed from the full-sequence
+            # mask in `_fit_tbptt`), while data-parallel padding rows never do.
+            if eb is None:
+                eb = losses_mod.effective_batch_size(y, lmask)
+            with scope(layer.scope):
+                data_loss = losses_mod.score(
+                    layer.loss_function, y, preout, layer.activation, lmask,
+                    average=False,
+                ) / eb
+            extra_state = {}
+            if isinstance(layer, CenterLossOutputLayer):
+                feats = aux["center_loss_input"].astype(self._loss_dtype)
+                centers = aux["centers"]
+                cls = (jnp.asarray(y, jnp.int32)
+                       if jnp.issubdtype(jnp.asarray(y).dtype, jnp.integer)
+                       else jnp.argmax(y, axis=-1))
+                c = centers[cls]
+                # Row weights: the labels mask excludes data-parallel padding rows
+                # from both the center-loss term and the center updates.
+                w = jnp.ones(y.shape[0], self._loss_dtype) if lmask is None else (
+                    lmask.reshape(y.shape[0], -1)[:, 0].astype(self._loss_dtype))
+                data_loss = data_loss + 0.5 * layer.lambda_ * jnp.sum(
+                    w * jnp.sum((feats - c) ** 2, axis=-1)
+                ) / eb
+                # EMA center update (reference: CenterLossOutputLayer center updates)
+                diff = (c - feats) * w[:, None]
+                num = jax.ops.segment_sum(diff, cls, num_segments=layer.n_out)
+                cnt = jax.ops.segment_sum(w.astype(jnp.float32), cls,
+                                          num_segments=layer.n_out)
+                new_centers = centers - layer.alpha * num / (1.0 + cnt)[:, None]
+                extra_state = {self.layer_keys[-1]: {"centers": new_centers}}
         if "aux_loss" in aux:
             # Layer-emitted auxiliary objectives (MoE load balance), already
             # scaled by their layer's weight; batch-size-invariant means, so

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.datasets.dataset import DataSet
+from deeplearning4j_tpu.nn.engine import scope
 from deeplearning4j_tpu.nn.layers import get_impl
 from deeplearning4j_tpu.parallel.pipeline import pipeline_apply
 from deeplearning4j_tpu import observability as _obs
@@ -154,16 +155,17 @@ class PipelineTrainer:
         for i in range(lo, hi):
             layer = net.layers[i]
             lk = net.layer_keys[i]
-            if i in net.conf.input_preprocessors:
-                x, _ = net.conf.input_preprocessors[i](x, None)
-            lrng = jax.random.fold_in(rng, i) if rng is not None else None
-            lparams = jax.tree_util.tree_map(
-                lambda a: a.astype(cdt)
-                if jnp.issubdtype(a.dtype, jnp.floating) else a,
-                params.get(lk, {}))
-            x, lstate_new, _ = get_impl(layer)(layer, lparams, {}, x,
-                                               rng=lrng, train=True,
-                                               mask=None)
+            with scope(lk, "L."):
+                if i in net.conf.input_preprocessors:
+                    x, _ = net.conf.input_preprocessors[i](x, None)
+                lrng = jax.random.fold_in(rng, i) if rng is not None else None
+                lparams = jax.tree_util.tree_map(
+                    lambda a: a.astype(cdt)
+                    if jnp.issubdtype(a.dtype, jnp.floating) else a,
+                    params.get(lk, {}))
+                x, lstate_new, _ = get_impl(layer)(layer, lparams, {}, x,
+                                                   rng=lrng, train=True,
+                                                   mask=None)
             if lstate_new and "_aux_loss" in lstate_new:
                 aux["aux_loss"] = aux.get("aux_loss", 0.0) + \
                     lstate_new["_aux_loss"]
