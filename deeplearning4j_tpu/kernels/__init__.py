@@ -28,7 +28,13 @@ and resolves once per jit signature. Kernels:
                         against its transpose, and a table's gradient; a
                         visit list over row tiles and groups built from
                         the traced group sizes. XLA's candidate is
-                        `jax.lax.ragged_dot`.
+                        `jax.lax.ragged_dot`;
+- ``rotary``          — the rotate-half rotary embedding of
+                        `nn/layers/dsa.py::rope` in one pass each way over
+                        the operand's positions-minor view (float32 tables
+                        of cos and sin; the backward pass is the same body
+                        with the sine negated). XLA's candidate is
+                        `dsa.rope_xla`.
 
 `DL4J_TPU_KERNELS=auto|xla|pallas` (+ per-kernel
 `DL4J_TPU_KERNEL_<NAME>`) select the mode; `python -m

@@ -28,6 +28,8 @@ process would get, from any machine::
     python -m deeplearning4j_tpu.kernels --backend tpu --probe \
         grouped_matmul 131072,2304,896,8 bfloat16,bfloat16 \
         --meta entry=contracted
+    python -m deeplearning4j_tpu.kernels --backend tpu --probe \
+        rotary 16384,4096,128 bfloat16
 """
 
 from __future__ import annotations

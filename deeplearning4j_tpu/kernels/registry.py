@@ -34,8 +34,9 @@ Env knobs (read at resolve time, so tests can monkeypatch):
 ``python -m deeplearning4j_tpu.kernels`` prints what resolves and why, for
 every name in `KERNEL_MODULES`: `bottleneck_block`, `lstm_cell`,
 `fused_update`, `norm_act`, `flash_attention` and its `_paged`, `masked_`,
-`banded_` and `latent_` siblings, and `grouped_matmul` (the dropless
-experts' grouped products, PR 35).
+`banded_` and `latent_` siblings, `grouped_matmul` (the dropless
+experts' grouped products) and `rotary` (the rotate-half rotary
+embedding, one pass each way over the operand).
 
 Registration is lazy: kernel modules self-register at import, and
 `resolve()`/`describe()` import them on demand, so importing the
@@ -68,7 +69,9 @@ MODES = ("auto", "xla", "pallas")
 # no bump. 6: `grouped_matmul` exists, and on a TPU the dropless experts'
 # grouped products resolve its Pallas body where they were
 # `jax.lax.ragged_dot` calls outside the registry (PR 35).
-SELECTION_RULES = 6
+# 7: `rotary` exists, and on a TPU `dsa.rope` resolves its Pallas body
+# where it ran the XLA expression inline.
+SELECTION_RULES = 7
 
 # Meta key the registry itself adds to a signature traced under a mesh of
 # more than one device (and `--probe --meta mesh_devices=N` passes by hand).
@@ -104,6 +107,11 @@ KERNEL_MODULES = {
     # (PR 35): rows by group against a table, against its transpose, and a
     # table's gradient; XLA's candidate is `jax.lax.ragged_dot`.
     "grouped_matmul": "deeplearning4j_tpu.kernels.grouped_matmul",
+    # The rotate-half rotary embedding: one pass over the operand's
+    # positions-minor view `[heads * D, S]` forward and one backward (the
+    # rotation by the opposite angle); XLA's candidate is
+    # `nn/layers/dsa.py::rope_xla`.
+    "rotary": "deeplearning4j_tpu.kernels.rotary",
 }
 
 

@@ -128,7 +128,25 @@ def rope_frequencies(D: int, theta: float, scaling, dtype):
 def rope(x, theta: float, scaling=None):
     """Rotate-half rotary embedding over the last axis of [S, ..., D]
     (position t on axis 0): pairs (i, i + D/2) turn by t * theta^(-2i/D),
-    or by `rope_frequencies`' under a `scaling`."""
+    or by `rope_frequencies`' under a `scaling`. The registry's `rotary`
+    decides between the Pallas body (`kernels/rotary.py`: one pass each way
+    over the operand) and `rope_xla`; both take cos and sin from the same
+    operations and compute in the same float32."""
+    from deeplearning4j_tpu.kernels import registry, rotary
+
+    res = registry.resolve("rotary", shapes=rotary.signature(x),
+                           dtypes=(str(x.dtype),))
+    if res.impl != "pallas":
+        return rope_xla(x, theta, scaling)
+    acc = jnp.promote_types(x.dtype, jnp.float32)
+    t = jnp.arange(x.shape[0], dtype=acc)
+    inv, mscale = rope_frequencies(x.shape[-1], theta, scaling, acc)
+    return rotary.rotate(x, *rotary.tables(t, inv, mscale))
+
+
+def rope_xla(x, theta: float, scaling=None):
+    """`rope` in XLA: the two halves of every head apart, in >= float32,
+    and concatenated again."""
     S, D = x.shape[0], x.shape[-1]
     acc = jnp.promote_types(x.dtype, jnp.float32)
     t = jnp.arange(S, dtype=acc)

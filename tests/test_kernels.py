@@ -573,7 +573,8 @@ PARITY_COVERED = {"lstm_cell", "fused_update", "norm_act", "flash_attention",
                   "masked_attention",      # test_masked_attention.py
                   "banded_attention",      # test_banded_attention.py
                   "latent_attention",      # test_latent_moe_lm.py
-                  "grouped_matmul"}        # test_grouped_matmul.py
+                  "grouped_matmul",        # test_grouped_matmul.py
+                  "rotary"}                # test_rotary.py
 
 
 def test_every_kernel_has_parity_coverage():
