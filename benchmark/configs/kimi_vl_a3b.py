@@ -200,12 +200,9 @@ _EXPERTS = {"router": ("ffn", "gate_w"), "router_bias": ("ffn", "gate_b"),
             "ws_up": ("ffn", "shared_up"), "ws_down": ("ffn", "shared_down")}
 
 
-def reference_params(tree, sizes: dict, copy_dense: bool = False) -> dict:
+def reference_params(tree, sizes: dict) -> dict:
     """The program's parameter tree under the reference's names (the same
-    arrays; `copy_dense`: the leading layer's as copies, because
-    `two_pass_check` copies only what it stacks and the head's three before
-    the train step is donated the program's)."""
-    import jax.numpy as jnp
+    arrays, no copy)."""
 
     def layer(i, names):
         return {ref: tree[f"{stem}{i}"][leaf]
@@ -213,8 +210,6 @@ def reference_params(tree, sizes: dict, copy_dense: bool = False) -> dict:
 
     first = n_dense(sizes)
     dense = layer(0, {**_ATTENTION, **_DENSE}) if first else None
-    if dense is not None and copy_dense:
-        dense = {k: jnp.copy(v) for k, v in dense.items()}
     return {"embed": tree["emb"]["W"], "dense": dense,
             "layers": [layer(i, {**_ATTENTION, **_EXPERTS}) for i in range(
                 first, int(sizes["num_hidden_layers"]))],
@@ -326,8 +321,7 @@ def reference_check(net, sizes: dict, batch, *, positions: int = 256,
         # the harness counts the layers it collects a set from and stacks
         sizes=dict(sizes, num_hidden_layers=n_expert_layers),
         limits=LIMITS, leaves=compared_leaves(sizes),
-        rparams_of=lambda tree: reference_params(tree, sizes,
-                                                 copy_dense=True),
+        rparams_of=lambda tree: reference_params(tree, sizes),
         sets=(routes,), plant=lambda fault: _plant(fault, net, sizes),
         faults=FAULTS, positions=positions, fault=fault)
 

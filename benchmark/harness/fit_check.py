@@ -201,7 +201,11 @@ def two_pass_check(net, batch, *, ref, cfg, sizes: dict, limits: dict,
     one of `FAULTS`, or one of the configuration's own `faults`, which
     `plant(fault)` makes on the program's side of the first pass and whose
     return undoes it. `rparams_of(tree)` is the program's tree under the
-    reference's names, `leaves` what `compared_leaves` gave."""
+    reference's names, `leaves` what `compared_leaves` gave. Every top-level
+    entry of that tree is copied before the train step is donated the
+    program's arrays (`layers` by stacking), whatever entries the
+    configuration has: one whose head is its embedding has no `head`, and
+    its reference differentiates the one leaf through both of its uses."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -246,8 +250,8 @@ def two_pass_check(net, batch, *, ref, cfg, sizes: dict, limits: dict,
     # parameters (the step is donated the program's), the compared leaves'
     # moments and the count of steps taken. The moments wait on the host.
     rparams = rparams_of(net.params_tree)
-    rparams = stack_layers(dict(rparams, **{
-        key: jnp.copy(rparams[key]) for key in ("embed", "norm", "head")}))
+    rparams = stack_layers(dict(rparams, **jax.tree_util.tree_map(
+        jnp.copy, {k: v for k, v in rparams.items() if k != "layers"})))
     moments = {key: tuple(np.asarray(net.opt_state[key[0]][mv][key[1]])
                           for mv in "mv") for key in leaves}
     taken = int(net.iteration)

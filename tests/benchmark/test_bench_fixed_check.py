@@ -12,44 +12,29 @@ import types
 
 import pytest
 
+import manifest_checks as checks
 from benchmark import run as bench_run
 from benchmark.drivers import fit, fit_ref
 from benchmark.harness import cells, device, fit_check
 
-FIT_REF_CELLS = ["keye_vl2_30b_a3b.fit_seq8k", "mellum2_12b_a2_5b.fit_seq16k",
-                 "kimi_vl_a3b.fit_seq8k"]
-AT_STEP = {"keye_vl2_30b_a3b.fit_seq8k": 320,
-           "mellum2_12b_a2_5b.fit_seq16k": 160,
-           "kimi_vl_a3b.fit_seq8k": 288}
+# Every `fit_ref` cell of the manifest, in its order; the three the fixed
+# count was set for come first (`manifest_checks.AT_STEP`).
+FIT_REF_CELLS = checks.fit_ref_cells(cells.manifest(), cells.BENCH)
+KEYE, MELLUM, KIMI = checks.AT_STEP
 
 
 # ---- the committed files ----------------------------------------------------
 
 @pytest.mark.parametrize("name", FIT_REF_CELLS)
 def test_committed_cell_fixes_a_count_of_whole_sync_groups(name):
-    spec = cells.load_json("workloads", name)
-    sizes = cells.load_json("configs", spec["config"])
-    group = int(spec["traffic"]["epochs_per_sync"]) * int(
-        sizes["staged_batches"])
-    assert group == 8
-    check = spec["check"]
-    assert check["fault"] is None
-    assert check["at_step"] == AT_STEP[name] and check["at_step"] % group == 0
-    assert "at_step_passed" in check["at_step_why"]   # says what happens then
-    # the rehearsal drives the same path with a small count of its own
-    small = spec["rehearsal"]["check"]["at_step"]
-    assert 0 < small < check["at_step"]
-    merged = cells.Cell(name, rehearsal=True).spec
-    assert merged["check"] == dict(check, at_step=small)
-    assert small % (int(merged["traffic"]["epochs_per_sync"])
-                    * int(sizes["staged_batches"])) == 0
+    checks.fixed_count(cells.manifest(), cells.BENCH, name)
 
 
 def test_every_fit_ref_cell_of_the_manifest_is_on_this_files_list():
-    listed = [w["name"] for w in cells.manifest()["workloads"]
-              if cells.load_json("workloads", w["name"])["driver"]
-              == "fit_ref"]
-    assert listed == FIT_REF_CELLS
+    """The three cells the fixed count was set for come first, in their
+    order, and every `fit_ref` cell of the manifest, later ones too, is held
+    to the rules of the test above."""
+    checks.known_fit_ref_cells_first(cells.manifest(), cells.BENCH)
 
 
 # ---- `advance_to` on a fake program ----------------------------------------
@@ -251,8 +236,8 @@ def test_the_gauges_reported_are_the_windows_last_sync(runs):
 
 
 @pytest.mark.parametrize("name,fault", [
-    (FIT_REF_CELLS[0], "state_unchanged"), (FIT_REF_CELLS[0], "half_positions"),
-    (FIT_REF_CELLS[1], "state_unchanged"), (FIT_REF_CELLS[2], "state_unchanged")])
+    (KEYE, "state_unchanged"), (KEYE, "half_positions"),
+    (MELLUM, "state_unchanged"), (KIMI, "state_unchanged")])
 def test_a_run_whose_step_is_broken_comes_out_not_correct_by_the_update(
         name, fault):
     """The rest of a run past the look for a chip, with the timed step broken
@@ -322,7 +307,7 @@ def _main_with(monkeypatch, capsys, canned, *extra):
     monkeypatch.setattr(device, "place_compile_cache", lambda root: None)
     monkeypatch.setattr(device, "require", lambda chips, rehearsal: {
         "platform": "tpu", "kind": "TPU v5 lite", "count": 1})
-    assert bench_run.main(["--workload", FIT_REF_CELLS[0], "--seed",
+    assert bench_run.main(["--workload", KEYE, "--seed",
                            str(2 ** 31 + 5), "--seconds", "1", "--trace",
                            "0", *extra]) == 0
     out, err = capsys.readouterr()
@@ -449,22 +434,14 @@ def test_moe_time_share_reads_nothing_where_there_is_nothing_to_read():
 
 @pytest.mark.parametrize("name", FIT_REF_CELLS)
 def test_moe_time_share_is_listed_for_every_language_model_cell(name):
-    assert "moe_time_share.fit" in cells.Cell(name).metric_names("per_layer")
-    entry = next(m for m in cells.manifest()["per_layer"]
-                 if m["name"] == "moe_time_share.fit")
-    assert entry["workloads"] == FIT_REF_CELLS
-    assert entry["source"] == "device_trace" and entry["layer"] == "kernels"
+    """The three language-model cells first, in their order, and only
+    `fit_ref` cells after them: a later cell of a model with experts joins
+    the list at its end."""
+    names = cells.Cell(name).metric_names("per_layer")
+    if name in checks.AT_STEP:
+        assert "moe_time_share.fit" in names
+    checks.moe_time_share_listing(cells.manifest(), cells.BENCH)
 
 
 def test_the_metric_that_read_nothing_is_gone_with_its_reader():
-    import os
-
-    names = [m["name"] for m in cells.manifest()["per_layer"]]
-    assert "norm_act_time_share.fit" not in names and len(names) == 25
-    assert not os.path.exists(os.path.join(
-        cells.BENCH, "layer_metrics", "norm_act_time_share.fit.py"))
-    # every metric listed has its reader, and every reader of a metric that
-    # a cell of the manifest reports is listed
-    for name in names:
-        assert os.path.isfile(os.path.join(cells.BENCH, "layer_metrics",
-                                           name + ".py")), name
+    checks.every_listed_metric_has_its_reader(cells.manifest(), cells.BENCH)

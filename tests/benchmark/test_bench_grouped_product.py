@@ -1,8 +1,7 @@
 """`grouped_product_time_share.fit`'s reader: the share of busy time in the
 experts' grouped products, whichever of XLA's `ragged-dot` calls and the
-registry's `grouped_matmul` kernels a program runs (PERF.md PR 35). The
-reader is in the tree and `BENCHMARK.json` does not list it yet: PERF.md
-section 7 says which pins a `benchmark` PR has to move with the entry."""
+registry's `grouped_matmul` kernels a program runs (PERF.md section 3).
+`BENCHMARK.json` lists it for the three language-model cells."""
 
 import types
 

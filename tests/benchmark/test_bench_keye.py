@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import manifest_checks
 from benchmark.harness import cells, flops, scope_time
 
 REF = cells.load_module("reference", "sparse_moe_lm")
@@ -472,21 +473,11 @@ def test_reference_adam_update_by_hand():
 
 
 def test_new_cells_name_files_that_exist_and_only_the_new_cell_has_a_check():
-    manifest = cells.manifest()
-    by_name = {w["name"]: w for w in manifest["workloads"]}
-    assert by_name["keye_vl2_30b_a3b.fit_seq8k"]["chips"] == 1
-    spec = cells.load_json("workloads", "keye_vl2_30b_a3b.fit_seq8k")
-    assert spec["driver"] == "fit_ref" and spec["check"]["fault"] is None
-    # no pin on the whole list (it held that day's two cells and failed once
-    # a third existed): the two come first, in their order, and every list
-    # that named this cell names it before any later one
-    assert list(by_name)[:2] == ["resnet50_b256.fit_cached",
-                                 "keye_vl2_30b_a3b.fit_seq8k"]
-    new = {"dsa_time_share.fit", "moe_time_share.fit",
-           "lm_head_time_share.fit", "moe_expert_load_max_over_mean",
-           "moe_pairs_held_share"}
-    for m in manifest["per_layer"]:
-        if m["name"] in new:
-            assert m["workloads"][0] == "keye_vl2_30b_a3b.fit_seq8k"
-            assert "resnet50_b256.fit_cached" not in m["workloads"]
-            assert m["moves"] == "fit_samples_per_s"
+    """No pin on the whole list (it held that day's two cells and failed
+    once a third existed): every entry keeps its place and what it said, its
+    lists growing at their ends only
+    (`manifest_checks.older_entries_in_their_places`), and the cell's own
+    metrics name it first (`manifest_checks.keye_entries`)."""
+    manifest_checks.older_entries_in_their_places(cells.manifest(),
+                                                  cells.BENCH)
+    manifest_checks.keye_entries(cells.manifest(), cells.BENCH)

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import manifest_checks
 from benchmark.harness import cells, fit_check, flops, kernel_costs
 from benchmark.harness import latent_costs
 
@@ -25,9 +26,6 @@ CONFIG = cells.load_module("configs", "kimi_vl_a3b")
 SIZES = cells.load_json("configs", "kimi_vl_a3b")
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 RUN = [sys.executable, os.path.join("benchmark", "run.py")]
-OLDER_CELLS = ["resnet50_b256.fit_cached", "keye_vl2_30b_a3b.fit_seq8k",
-               "mellum2_12b_a2_5b.fit_seq16k"]
-OLDER_CONFIGS = ["resnet50_b256", "keye_vl2_30b_a3b", "mellum2_12b_a2_5b"]
 
 
 def _tiny(seed=0, S=10, D=8, H=2, R=6, Dn=4, Dr=2, Dv=3, E=4, Eh=2, F=5,
@@ -419,30 +417,11 @@ def test_roofline_reader_divides_the_needed_flops_by_the_scoped_time(
 
 
 def test_the_cell_reports_what_the_issue_lists():
-    cell = cells.Cell(CELL)
-    assert cell.metric_names("end_to_end") == ["fit_samples_per_s", "setup_s"]
-    names = set(cell.metric_names("per_layer"))
-    new = {"mla_time_share.fit", "mla_project_time_share.fit",
-           "moe_shared_time_share.fit", "dense_ffn_time_share.fit",
-           "latent_attention_roofline.fit"}
-    assert new | {"fit_mfu", "lm_head_time_share.fit", "moe_pairs_held_share",
-                  "moe_expert_load_max_over_mean", "hbm_gb_per_step.fit",
-                  "pallas_time_share.fit", "device_idle_share.fit"} <= names
-    # `moe_time_share.fit` reads this cell since PR 34 (its reader finds
-    # XLA's `ragged-dot` calls by name); the siblings' own metrics do not
-    assert "moe_time_share.fit" in names
-    assert not {"dsa_time_share.fit", "swa_time_share.fit",
-                "full_attn_time_share.fit", "banded_attention_roofline.fit",
-                "attn_band_fill_share"} & names
-    assert len(names) == 20
-    only = [m for m in cells.manifest()["per_layer"]
-            if m.get("workloads") == [CELL]]
-    assert {m["name"] for m in only} == new and all(
-        m["layer"] == "kernels" and m["moves"] == "fit_samples_per_s"
-        and m["unit"] == "%" and m["source"] == "device_trace" for m in only)
-    for name in new:
-        assert os.path.isfile(os.path.join(cells.BENCH, "layer_metrics",
-                                           name + ".py"))
+    """The metrics the cell's issue listed are among its own, its siblings'
+    own are not, and the five it brought name it first and no older cell
+    (`manifest_checks.kimi_reports`): no count, so a later metric may list
+    it."""
+    manifest_checks.kimi_reports(cells.manifest(), cells.BENCH)
     spec = cells.load_json("workloads", CELL)
     assert spec["traffic"] == {"kind": "fit_seq8k", "epochs_per_sync": 4,
                                "trace_seconds": 4.0}
@@ -451,42 +430,12 @@ def test_the_cell_reports_what_the_issue_lists():
 
 
 def test_the_older_cells_keep_their_entries_and_come_first():
-    """No pin on the whole list (`test_bench_keye.py` and
-    `test_bench_mellum.py` each pin it to their day's cells and fail since a
-    later cell exists: a `benchmark` PR's to repair): the three older cells
-    and configurations are the first three entries in their order, and
-    every list that named one of them still does, in that order, with newer
-    names only after them."""
-    manifest = cells.manifest()
-    assert [w["name"] for w in manifest["workloads"]][:3] == OLDER_CELLS
-    assert [c["name"] for c in manifest["configs"]][:3] == OLDER_CONFIGS
-    parent = {
-        "fit_samples_per_s": OLDER_CELLS,
-        "dsa_time_share.fit": OLDER_CELLS[1:2],
-        "moe_time_share.fit": OLDER_CELLS[1:],
-        "lm_head_time_share.fit": OLDER_CELLS[1:],
-        "moe_expert_load_max_over_mean": OLDER_CELLS[1:],
-        "moe_pairs_held_share": OLDER_CELLS[1:],
-        "swa_time_share.fit": OLDER_CELLS[2:],
-        "full_attn_time_share.fit": OLDER_CELLS[2:],
-        "banded_attention_roofline.fit": OLDER_CELLS[2:],
-        "attn_band_fill_share": OLDER_CELLS[2:]}
-    for m in manifest["per_layer"] + manifest["end_to_end"]:
-        listed = m.get("workloads")
-        if listed is None:
-            continue
-        older = [w for w in listed if w in OLDER_CELLS]
-        assert listed[:len(older)] == older, m["name"]
-        if older:
-            assert older == parent.get(m["name"], OLDER_CELLS), m["name"]
-        if m["name"] in ("dsa_time_share.fit", "swa_time_share.fit",
-                         "full_attn_time_share.fit",
-                         "banded_attention_roofline.fit",
-                         "attn_band_fill_share"):
-            assert CELL not in listed, m["name"]
-    for name in OLDER_CELLS:
-        assert os.path.isfile(os.path.join(cells.BENCH, "workloads",
-                                           name + ".json"))
+    """The three older cells and configurations are the first three entries
+    in their order, and every entry keeps its place and what it said, its
+    lists growing at their ends only
+    (`manifest_checks.older_entries_in_their_places`)."""
+    manifest_checks.older_entries_in_their_places(cells.manifest(),
+                                                  cells.BENCH)
 
 
 @pytest.mark.parametrize("trace", [0, 1])

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import manifest_checks
 from benchmark.harness import cells, fit_check, flops, kernel_costs
 
 CELL = "mellum2_12b_a2_5b.fit_seq16k"
@@ -413,54 +414,19 @@ def test_fill_share_reader_reads_the_worst_layer_and_none_without_the_gauge(
 
 
 def test_the_cell_reports_what_the_issue_lists():
-    cell = cells.Cell(CELL)
-    assert cell.metric_names("end_to_end") == ["fit_samples_per_s", "setup_s"]
-    names = set(cell.metric_names("per_layer"))
-    assert {"swa_time_share.fit", "full_attn_time_share.fit",
-            "banded_attention_roofline.fit", "attn_band_fill_share",
-            "fit_mfu", "lm_head_time_share.fit",
-            "moe_pairs_held_share", "hbm_gb_per_step.fit"} <= names
-    # `moe_time_share.fit` reads this cell since PR 34: its reader finds
-    # XLA's `ragged-dot` calls, half of the experts' time here, by name
-    assert "moe_time_share.fit" in names
-    assert "dsa_time_share.fit" not in names
-    assert len(names) == 19
-    new = [m for m in cells.manifest()["per_layer"]
-           if m.get("workloads") == [CELL]]
-    assert len(new) == 4 and all(
-        m["layer"] == "kernels" and m["moves"] == "fit_samples_per_s"
-        for m in new)
+    """The metrics the cell's issue listed are among its own, its sibling's
+    own are not, and the four it brought name it first and no older cell
+    (`manifest_checks.mellum_reports`): no count, so a later metric may
+    list it."""
+    manifest_checks.mellum_reports(cells.manifest(), cells.BENCH)
 
 
 def test_the_cells_the_benchmark_had_keep_their_entries_and_their_places():
-    """What stays true of PR 26's pin on the manifest
-    (`test_bench_keye.py::test_new_cells_name_files_that_exist_and_only_the_
-    new_cell_has_a_check` pins it to its day's two cells and fails since
-    this cell exists: a `benchmark` PR's to repair): the two older cells are
-    the first two entries, every list that named them names them first and
-    in that order, and only lists gained the new name."""
-    manifest = cells.manifest()
-    older = ["resnet50_b256.fit_cached", "keye_vl2_30b_a3b.fit_seq8k"]
-    assert [w["name"] for w in manifest["workloads"]][:3] == older + [CELL]
-    assert [c["name"] for c in manifest["configs"]][:3] == [
-        "resnet50_b256", "keye_vl2_30b_a3b", "mellum2_12b_a2_5b"]
-    spec = cells.load_json("workloads", "keye_vl2_30b_a3b.fit_seq8k")
-    assert spec["driver"] == "fit_ref" and spec["check"]["fault"] is None
-    expert = {"lm_head_time_share.fit", "moe_expert_load_max_over_mean",
-              "moe_pairs_held_share", "moe_time_share.fit"}
-    for m in manifest["per_layer"] + manifest["end_to_end"]:
-        listed = m.get("workloads")
-        if listed is None:
-            continue
-        # the cells of that day come first and in their order; cells that
-        # later PRs added come after them
-        then = [w for w in listed if w in older + [CELL]]
-        assert listed[:len(then)] == then, m["name"]
-        assert then == [w for w in older + [CELL] if w in then], m["name"]
-        if m["name"] in expert:
-            assert then == ["keye_vl2_30b_a3b.fit_seq8k", CELL]
-        if m["name"] == "dsa_time_share.fit":
-            assert CELL not in listed
+    """The two older cells are the first two entries, and every entry keeps
+    its place and what it said, its lists growing at their ends only
+    (`manifest_checks.older_entries_in_their_places`)."""
+    manifest_checks.older_entries_in_their_places(cells.manifest(),
+                                                  cells.BENCH)
 
 
 @pytest.mark.parametrize("trace", [0, 1])

@@ -2,9 +2,8 @@
 and `updater_time_share.fit` (PERF.md PR 36), on a hand-built trace and HLO
 text: the partition of busy time by the engine's scopes with a `while` event
 over its body's events, what an instruction without an `op_name` inherits,
-and that a program from before the scopes reads nothing. The readers are in
-the tree and `BENCHMARK.json` does not list them yet: PERF.md section 7 says
-which pins a `benchmark` PR has to move with the entries."""
+and that a program from before the scopes reads nothing. `BENCHMARK.json`
+lists both for every fit cell."""
 
 import json
 import types
