@@ -379,6 +379,102 @@ def sparse_moe_lm(vocab_size: int, *, t: int, d_model: int, n_blocks: int,
     return gb.build()
 
 
+def zaya_moe_lm(vocab_size: int, *, t: int, d_model: int, n_blocks: int,
+                n_heads: int, n_kv_heads: int, head_dim: int,
+                n_experts: int, top_k: int, expert_hidden: int,
+                router_hidden: int, experts_held=None,
+                rope_theta: float = 5e6, partial_rotary_factor: float = 0.5,
+                cca_time0: int = 2, cca_time1: int = 2, rms_eps: float = 1e-5,
+                aux_loss_weight: float = 1e-3, embedding_std: float = 1.0,
+                lr: float = 1e-5, adam_mean_decay: float = 0.9,
+                adam_var_decay: float = 0.95, seed: int = 123,
+                dtype_policy=None):
+    """Decoder-only mixture-of-experts language model of the ZAYA1 block,
+    built from DSL layers and trained by `ComputationGraph.fit` on integer
+    ids `[B, T]` with integer next-token labels `[B, T]`:
+
+        emb -> n_blocks x [ x = a_a x + b_a CCA(RMSNorm(x));
+                            r_i = EDA(RMSNorm(x) W_d, r_{i-1});
+                            x = a_f x + b_f MoE(RMSNorm(x), r_i) ]
+            -> RMSNorm -> head tied to the embedding, softmax cross-entropy
+
+    CCA: compressed convolutional attention of `n_heads` query heads over
+    `n_kv_heads` key/value heads of `head_dim` (`nn/layers/cca.py`: two
+    causal convolutions of `cca_time0` and `cca_time1` taps, the q-k mean,
+    L2-normalised q and k with a temperature on k, rotary on the first
+    `partial_rotary_factor` of each head at `rope_theta`, values half from
+    the previous position). `a_*`, `b_*`: learned per-channel gains
+    (`ScaledResidualLayer`, `res_a<i>` / `res_f<i>`). The router's state
+    `route<i>` (`RouterStateLayer`: a projection to `router_hidden` columns,
+    averaged with the previous block's state, exponential depth averaging,
+    from the second block on) is an edge of the graph into block i's
+    `ffn<i>` and block i + 1's `route<i+1>`. MoE: `n_experts` gated SiLU
+    experts of `expert_hidden`, dropless, routed by an MLP over the state
+    to `n_experts + 1` choices, the last a skip that no device holds, the
+    `top_k` best chosen and weighted by their unrenormalised softmax
+    probabilities; `experts_held = (first, count)` keeps only those experts
+    here. The head reads the embedding's table (`tied_to`): one leaf, its
+    gradient the sum of both uses. `embedding_std`: the table's init (a
+    tied head's logits have std `embedding_std * sqrt(d_model)` at init).
+    `vocab_size` is the number of rows held (a slice of the vocabulary).
+
+    Device-trace scopes: `cca.project`, `cca.conv`, `cca.attend`,
+    `moe.route` (the router's state and its MLP), `moe.experts`, `lm.head`.
+    """
+    from deeplearning4j_tpu.nn.conf.distributions import NormalDistribution
+    from deeplearning4j_tpu.nn.conf.layers import (
+        CompressedConvAttentionLayer, EmbeddingLayer, MoELayer,
+        RMSNormalization, RouterStateLayer, ScaledResidualLayer,
+    )
+
+    nb = (NeuralNetConfiguration.builder()
+          .seed(seed).learning_rate(lr).updater(Updater.ADAM)
+          .adam_mean_decay(adam_mean_decay).adam_var_decay(adam_var_decay)
+          .weight_init("xavier"))
+    if dtype_policy is not None:
+        nb = nb.dtype_policy(dtype_policy)
+    gb = (nb.graph_builder()
+          .add_inputs("tokens")
+          .add_layer("emb", EmbeddingLayer(
+              n_out=d_model, has_bias=False, input_format="ids",
+              activation="identity", weight_init="distribution",
+              dist=NormalDistribution(0.0, float(embedding_std))), "tokens"))
+    norm = lambda: RMSNormalization(eps=rms_eps)  # noqa: E731
+    prev = "emb"
+    for i in range(n_blocks):
+        gb.add_layer(f"ln_a{i}", norm(), prev)
+        gb.add_layer(f"attn{i}", CompressedConvAttentionLayer(
+            n_out=d_model, n_heads=n_heads, n_kv_heads=n_kv_heads,
+            head_dim=head_dim, rope_theta=float(rope_theta),
+            partial_rotary_factor=float(partial_rotary_factor),
+            cca_time0=cca_time0, cca_time1=cca_time1), f"ln_a{i}")
+        gb.add_layer(f"res_a{i}", ScaledResidualLayer(), prev, f"attn{i}")
+        gb.add_layer(f"ln_f{i}", norm(), f"res_a{i}")
+        gb.add_layer(f"route{i}", RouterStateLayer(
+            n_out=router_hidden, carry=i > 0),
+            f"ln_f{i}", *([f"route{i - 1}"] if i else []))
+        gb.add_layer(f"ffn{i}", MoELayer(
+            n_out=d_model, n_experts=n_experts, expert_hidden=expert_hidden,
+            top_k=top_k, dropless=True, norm_topk_prob=False,
+            aux_loss_weight=aux_loss_weight,
+            experts_held=None if experts_held is None
+            else tuple(experts_held),
+            router_hidden=router_hidden),
+            f"ln_f{i}", f"route{i}")
+        gb.add_layer(f"res_f{i}", ScaledResidualLayer(), f"res_a{i}",
+                     f"ffn{i}")
+        prev = f"res_f{i}"
+    gb.add_layer("ln_out", norm(), prev)
+    gb.add_layer("out", RnnOutputLayer(n_out=vocab_size, has_bias=False,
+                                       activation="softmax",
+                                       loss_function="mcxent",
+                                       tied_to="emb", scope="lm.head"),
+                 "ln_out")
+    gb.set_outputs("out")
+    gb.set_input_types(InputType.recurrent(vocab_size, t))
+    return gb.build()
+
+
 def _sample_token(probs, rng, temperature: float, top_k: int, top_p: float):
     """Sample one next-token id from a [V] probability vector (greedy at
     temperature<=0; top-k / nucleus top-p restrictions compose, applied

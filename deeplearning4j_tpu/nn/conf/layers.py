@@ -145,6 +145,12 @@ class Layer:
     def has_params(self) -> bool:
         return bool(self.param_shapes())
 
+    def n_inputs(self) -> int:
+        """Inputs the layer's forward takes. More than one: a graph vertex
+        hands it the tuple of its inputs' values, in the order the vertex
+        lists them, and its `n_in` is inferred from the first."""
+        return 1
+
     def frozen_param_names(self) -> Sequence[str]:
         """Params that never train, whatever `frozen` says (`nn/transfer.py`
         puts them in the frozen spec: no gradient, no updater state)."""
@@ -255,11 +261,18 @@ class BaseOutputLayer(FeedForwardLayer):
     loss_function: Any = LossFunction.MCXENT
     # None/True: `W` and `b` as ever; False: no bias (an LM head).
     has_bias: Optional[bool] = None
+    # A head tied to an embedding (`ComputationGraph` only): the name of an
+    # `EmbeddingLayer` vertex whose `W` [n_out, n_in] this layer reads,
+    # transposed, in place of a `W` of its own. The one leaf then has one
+    # set of updater moments, and its gradient is the sum of both uses.
+    tied_to: Optional[str] = None
 
     def param_shapes(self):
         shapes = super().param_shapes()
         if self.has_bias is False:
             shapes.pop("b", None)
+        if self.tied_to is not None:
+            shapes.pop("W")
         return shapes
 
     def to_dict(self):
@@ -907,6 +920,53 @@ class SelfAttentionLayer(BaseRecurrentLayer):
 
 @register_layer
 @dataclass
+class CompressedConvAttentionLayer(BaseRecurrentLayer):
+    """Compressed convolutional attention (CCA; Figliolia et al. 2025,
+    arXiv:2510.04476, as ZAYA1 has it; `nn/layers/cca.py` has the
+    equations): `n_heads` query heads and `n_kv_heads` key/value heads of
+    `head_dim`, causal. The queries and keys are mixed along the sequence by
+    two causal convolutions, one per channel of `cca_time0` taps and one
+    per head of `cca_time1` taps, the mean of the projected q and k added
+    back, L2-normalised with a learned temperature on k; the first
+    `partial_rotary_factor` of each head's channels take rotate-half RoPE at
+    `rope_theta`; the values are half the current position's projection
+    and half the previous one's. No biases on the projections."""
+
+    n_heads: int = 8
+    n_kv_heads: int = 2
+    head_dim: int = 128
+    rope_theta: float = 10000.0
+    partial_rotary_factor: float = 1.0
+    cca_time0: int = 2
+    cca_time1: int = 2
+    activation: Any = "identity"
+
+    def __post_init__(self):
+        if self.n_heads % self.n_kv_heads or self.head_dim % 2:
+            raise ValueError("n_heads must be a multiple of n_kv_heads and "
+                             "head_dim even")
+        if self.rotary_dim() % 2:
+            raise ValueError("partial_rotary_factor * head_dim must be even")
+
+    def rotary_dim(self) -> int:
+        return int(round(self.head_dim * self.partial_rotary_factor))
+
+    def param_shapes(self):
+        H, KV, Dh = self.n_heads, self.n_kv_heads, self.head_dim
+        C = (H + KV) * Dh
+        return {"Wq": (self.n_in, H * Dh), "Wk": (self.n_in, KV * Dh),
+                "Wv1": (self.n_in, KV * Dh // 2),
+                "Wv2": (self.n_in, KV * Dh // 2),
+                "W_conv0": (self.cca_time0, C), "b_conv0": (C,),
+                "W_conv1": (H + KV, self.cca_time1, Dh, Dh), "b_conv1": (C,),
+                "temp": (KV,), "Wo": (H * Dh, self.n_out)}
+
+    def full_precision_param_names(self):
+        return ("temp",)
+
+
+@register_layer
+@dataclass
 class MoELayer(FeedForwardLayer):
     """Mixture-of-experts FFN with GShard routing (top-1/top-2, capacity
     dropping, router jitter, load-balance aux loss).
@@ -956,6 +1016,16 @@ class MoELayer(FeedForwardLayer):
     scoring: Optional[str] = None
     routed_scaling_factor: Optional[float] = None
     shared_hidden: Optional[int] = None
+    # An MLP router (ZAYA1's): with `router_hidden` set, the layer takes two
+    # inputs, the tokens and the router's state [.., router_hidden] (a
+    # `RouterStateLayer`'s output), and its logits are
+    # `router_w3 gelu(router_w2 gelu(router_w1 state))`, softmax in float32;
+    # the choice is the top_k of logits + `gate_b` (a frozen float32
+    # selection bias, zeros at init), the weights the chosen probabilities
+    # (`expert.route_mlp`). The MLP router has one more logit than experts,
+    # the skip: a choice no device holds, so a token that takes it adds
+    # nothing here or anywhere (`skip_share`, `dl4j_moe_skip_share`).
+    router_hidden: Optional[int] = None
 
     SHARED_PARAMS = ("shared_gate", "shared_up", "shared_down")
 
@@ -976,6 +1046,13 @@ class MoELayer(FeedForwardLayer):
                 and self.scoring != "sigmoid":
             raise ValueError("routed_scaling_factor scales the sigmoid "
                              "router's weights: set scoring='sigmoid'")
+        if self.router_hidden is not None and (
+                not self.dropless or self.scoring is not None):
+            raise ValueError("router_hidden is the dropless path's MLP "
+                             "router: dropless=True, no scoring")
+
+    def n_inputs(self) -> int:
+        return 2 if self.router_hidden is not None else 1
 
     def set_n_in(self, input_type: InputType, override: bool) -> None:
         super().set_n_in(input_type, override)
@@ -994,10 +1071,14 @@ class MoELayer(FeedForwardLayer):
         E, h = self.n_experts, self.expert_hidden or 4 * self.n_in
         if self.dropless:
             Eh = self.held()[1]
-            shapes = {"gate_w": (self.n_in, E),
-                      "w_gate": (Eh, self.n_in, h),
-                      "w_up": (Eh, self.n_in, h),
-                      "w_down": (Eh, h, self.n_out)}
+            if self.router_hidden is not None:
+                R, C = self.router_hidden, self.n_experts + 1   # + the skip
+                shapes = {"router_w1": (R, R), "router_w2": (R, R),
+                          "router_w3": (R, C), "gate_b": (C,)}
+            else:
+                shapes = {"gate_w": (self.n_in, E)}
+            shapes.update(w_gate=(Eh, self.n_in, h), w_up=(Eh, self.n_in, h),
+                          w_down=(Eh, h, self.n_out))
             if self.scoring == "sigmoid":
                 shapes["gate_b"] = (E,)
             if self.shared_hidden:
@@ -1023,8 +1104,65 @@ class MoELayer(FeedForwardLayer):
         # is read (`dl4j_moe_pairs_held_share`,
         # `dl4j_moe_expert_load_max_over_mean`).
         if self.dropless:
-            return {"pairs_held_share": (), "expert_load_max_over_mean": ()}
+            shapes = {"pairs_held_share": (), "expert_load_max_over_mean": ()}
+            if self.router_hidden is not None:
+                shapes["skip_share"] = ()
+            return shapes
         return {}
+
+
+@register_layer
+@dataclass
+class RouterStateLayer(FeedForwardLayer):
+    """The state of an MLP router (`MoELayer.router_hidden`), an edge of the
+    graph from one block's router to the next's: `r = x W_d` to `n_out`
+    columns, and with `carry` (a second input, the previous block's state
+    r_prev) exponential depth averaging (ZAYA1's EDA):
+    `lambda * r_prev + (1 - lambda) * r`, `lambda = sigmoid(eda_theta)`
+    per column, `eda_theta` zeros at init. Float32 out whatever the policy
+    (the router's precision). The scope is `moe.route`, as the router's."""
+
+    carry: bool = False
+    activation: Any = "identity"
+    scope: Optional[str] = "moe.route"
+
+    def n_inputs(self) -> int:
+        return 2 if self.carry else 1
+
+    def param_shapes(self):
+        shapes = {"W_d": (self.n_in, self.n_out)}
+        if self.carry:
+            shapes["eda_theta"] = (self.n_out,)
+        return shapes
+
+    def full_precision_param_names(self):
+        return ("eda_theta",)
+
+
+@register_layer
+@dataclass
+class ScaledResidualLayer(FeedForwardLayer):
+    """A residual merge with learned gains (ZAYA1's residual scaling): two
+    inputs, the residual stream x and a sublayer's output y, to
+    `alpha * x + beta * y`, `alpha` and `beta` [n_out] ones at init and used
+    as stored (a gain of 1 + d reads 1 in bfloat16 until d passes 0.004)."""
+
+    activation: Any = "identity"
+
+    def n_inputs(self) -> int:
+        return 2
+
+    def get_output_type(self, input_type: InputType) -> InputType:
+        return input_type
+
+    def set_n_in(self, input_type, override):
+        self.n_in = self.n_out = input_type.flat_size()
+
+    def param_shapes(self):
+        return {"alpha": (self.n_out,), "beta": (self.n_out,)}
+
+    def full_precision_param_names(self):
+        return ("alpha", "beta")
 
 
 @register_layer

@@ -483,6 +483,21 @@ class ComputationGraphConfiguration:
         for o in self.network_outputs:
             if o not in self.vertices:
                 raise ValueError(f"Network output {o!r} is not a vertex")
+        for name, v in self.vertices.items():
+            if isinstance(v, LayerVertex) and v.layer is not None \
+                    and v.layer.n_inputs() > 1 and len(
+                        self.vertex_inputs.get(name, ())) != \
+                    v.layer.n_inputs():
+                raise ValueError(
+                    f"Vertex {name!r} ({type(v.layer).__name__}) takes "
+                    f"{v.layer.n_inputs()} input(s), given "
+                    f"{self.vertex_inputs.get(name)}")
+            tied = getattr(v, "layer", None) and getattr(v.layer, "tied_to",
+                                                         None)
+            if tied and type(getattr(self.vertices.get(tied), "layer",
+                                      None)).__name__ != "EmbeddingLayer":
+                raise ValueError(f"Vertex {name!r} is tied to {tied!r}, "
+                                 "which is no EmbeddingLayer vertex")
         self.topological_order()  # raises on cycles
 
     def topological_order(self) -> List[str]:

@@ -42,6 +42,10 @@ LAYER_STATS = {
         "dl4j_moe_expert_load_max_over_mean",
         "Largest load over mean load among the experts a dropless MoE "
         "layer holds (last step read)"),
+    "skip_share": (
+        "dl4j_moe_skip_share",
+        "Share of a dropless MoE layer's tokens routed to its skip choice, "
+        "which no device holds (last step read)"),
     "selected_keys_mean": (
         "dl4j_dsa_selected_keys_mean",
         "Mean number of keys a query of a sparse attention layer attends "

@@ -157,9 +157,13 @@ class ComputationGraph(Engine):
                 in_masks = [masks[n] for n in in_names]
                 if isinstance(vertex, LayerVertex):
                     x, mask = in_vals[0], in_masks[0]
+                    layer = vertex.layer
+                    if layer.n_inputs() > 1:
+                        # a layer of several inputs takes them all, in the
+                        # vertex's order (`Layer.n_inputs`)
+                        x = tuple(in_vals)
                     if vertex.preprocessor is not None:
                         x, mask = vertex.preprocessor(x, mask)
-                    layer = vertex.layer
                     if type(layer).__name__ == "CenterLossOutputLayer":
                         aux[f"center_loss_input:{name}"] = x
                         aux[f"centers:{name}"] = state.get(name, {}).get("centers")
@@ -168,6 +172,11 @@ class ComputationGraph(Engine):
                     # policy's compute dtype at use (nn/params.py).
                     lparams = params_mod.prep_layer_params(params.get(name, {}),
                                                            cdt, layer=layer)
+                    if getattr(layer, "tied_to", None) is not None:
+                        # a head tied to an embedding reads its table
+                        tied = self.layer_vertices[layer.tied_to].layer
+                        lparams = dict(lparams, W=params_mod.prep_layer_params(
+                            params[layer.tied_to], cdt, layer=tied)["W"].T)
                     with scope(layer.scope):
                         out, lstate_new, mask = get_impl(layer)(
                             layer, lparams, state.get(name, {}), x,

@@ -13,6 +13,7 @@ from __future__ import annotations
 from deeplearning4j_tpu.nn.layers import (
     attention,
     bottleneck,
+    cca,
     convolution,
     feedforward,
     moe,
@@ -49,6 +50,9 @@ LAYER_IMPLS = {
     "GlobalPoolingLayer": pooling.global_pooling_apply,
     "SelfAttentionLayer": attention.self_attention_apply,
     "MoELayer": moe.moe_apply,
+    "RouterStateLayer": moe.router_state_apply,
+    "ScaledResidualLayer": feedforward.scaled_residual_apply,
+    "CompressedConvAttentionLayer": cca.cca_apply,
     "VariationalAutoencoder": variational.vae_apply,
 }
 

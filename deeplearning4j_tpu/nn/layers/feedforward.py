@@ -198,3 +198,15 @@ def positional_embedding_apply(conf, params, state, x, *, rng=None,
         rows = jax.lax.dynamic_slice(
             params["P"], (start, jnp.int32(0)), (T, params["P"].shape[1]))
     return x + rows, {"pos": start + jnp.int32(T)}, mask
+
+
+def scaled_residual_apply(conf, params, state, x, *, rng=None, train=False,
+                          mask=None):
+    """`ScaledResidualLayer`: (x, y) -> `alpha * x + beta * y` in >= float32,
+    back in x's dtype."""
+    x, y = x
+    acc = jnp.promote_types(x.dtype, jnp.float32)
+    out = (params["alpha"].astype(acc) * x.astype(acc)
+           + params["beta"].astype(acc) * y.astype(acc))
+    out = activations.resolve(conf.activation)(out.astype(x.dtype))
+    return out, state, mask

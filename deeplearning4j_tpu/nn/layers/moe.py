@@ -17,6 +17,7 @@ from __future__ import annotations
 import math
 
 import jax
+import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn import activations
 from deeplearning4j_tpu.nn.layers.common import layer_input_dropout
@@ -36,11 +37,18 @@ def moe_apply(conf, params, state, x, *, rng=None, train=False, mask=None):
         # Independent streams: dropout and router jitter must not consume
         # the same key (identical bits => correlated draws).
         drop_rng, jitter_rng = jax.random.split(rng)
+    router_state = None
+    if conf.router_hidden is not None:
+        # two inputs: the tokens and the MLP router's state
+        x, router_state = x
     x = layer_input_dropout(conf, x, drop_rng, train)
     lead = x.shape[:-1]
     tokens = x.reshape(-1, x.shape[-1])
     if conf.dropless:
-        return _dropless_apply(conf, params, state, tokens, lead, mask)
+        if router_state is not None:
+            router_state = router_state.reshape(-1, router_state.shape[-1])
+        return _dropless_apply(conf, params, state, tokens, lead, mask,
+                               router_state)
     ffn_params = {
         "gate_w": params["gate_w"],
         "w1": params["w1"], "b1": params["b_1"],
@@ -64,7 +72,8 @@ def moe_apply(conf, params, state, x, *, rng=None, train=False, mask=None):
     return out, new_state, mask
 
 
-def _dropless_apply(conf, params, state, tokens, lead, mask):
+def _dropless_apply(conf, params, state, tokens, lead, mask,
+                    router_state=None):
     """Dropless top-k routing (`expert.moe_ffn_dropless`). The layer computes
     the part of the sum that belongs to the experts it holds
     (`conf.experts_held`). Under a `ParallelContext` with an expert axis the
@@ -82,10 +91,16 @@ def _dropless_apply(conf, params, state, tokens, lead, mask):
         kwargs.update(
             scoring=conf.scoring, sequences=math.prod(lead[:-1]),
             routed_scaling_factor=conf.routed_scaling_factor or 1.0)
+    if router_state is not None:
+        kwargs.update(router_state=router_state)
     shared = {k: params[k] for k in conf.SHARED_PARAMS if k in params}
     params = {k: v for k, v in params.items() if k not in shared}
     if (ctx is not None and ctx.expert_axis is not None
             and ctx.axis_size("expert") > 1):
+        if router_state is not None:
+            raise NotImplementedError("the MLP router has no expert-"
+                                      "parallel path: hold the layer's "
+                                      "share with experts_held")
         y, aux, stats, idx = expert_mod.moe_ffn_dropless_sharded(
             params, tokens, ctx.mesh, ctx.expert_axis, **kwargs)
     else:
@@ -101,7 +116,27 @@ def _dropless_apply(conf, params, state, tokens, lead, mask):
     new_state = dict(state)
     new_state["_aux_loss"] = conf.aux_loss_weight * aux
     new_state["pairs_held_share"], new_state["expert_load_max_over_mean"] = stats
+    if router_state is not None:
+        new_state["skip_share"] = jnp.mean(
+            (idx == conf.n_experts).astype(stats[0].dtype))
     # A by-product like `_selected_keys` (`nn/layers/dsa.py`): the experts
     # each token was routed to, for `loss_and_gradients(collect=...)`.
     new_state["_expert_idx"] = idx.reshape(lead + (conf.top_k,))
     return out, new_state, mask
+
+
+def router_state_apply(conf, params, state, x, *, rng=None, train=False,
+                       mask=None):
+    """`RouterStateLayer`: x [.., n_in] (and the previous block's state
+    with `carry`) -> the router's state [.., n_out] in >= float32:
+    `r = x W_d`, then `lambda * r_prev + (1 - lambda) * r` with `lambda =
+    sigmoid(eda_theta)` (exponential depth averaging)."""
+    prev = None
+    if conf.carry:
+        x, prev = x
+    acc = jnp.promote_types(x.dtype, jnp.float32)
+    r = x.astype(acc) @ params["W_d"].astype(acc)
+    if prev is not None:
+        lam = jax.nn.sigmoid(params["eda_theta"].astype(acc))
+        r = (1.0 - lam) * r + lam * prev.astype(acc)
+    return activations.resolve(conf.activation)(r), state, mask

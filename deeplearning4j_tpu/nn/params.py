@@ -42,6 +42,13 @@ def _fans(conf: Layer, name: str, shape: Tuple[int, ...]) -> Tuple[float, float]
         # so fused and unfused blocks draw identical init statistics.
         kh, kw, cin, cout = shape
         return (cin * kh * kw, cout * kh * kw)
+    if type(conf).__name__ == "CompressedConvAttentionLayer" and \
+            name.startswith("W_conv"):
+        # A causal convolution's taps: a channel of W_conv0 [T, C] reads T
+        # values, a head of W_conv1 [G, T, D, D] T * D of them; fan-in and
+        # fan-out alike, so Xavier keeps the mixed signal's scale.
+        fan = shape[0] if name == "W_conv0" else shape[1] * shape[2]
+        return (fan, fan)
     if isinstance(conf, MoELayer) and len(shape) == 3:
         # Per-expert FFN tables [E, in, out]: fans are the PER-EXPERT matmul
         # dims, not the stacked leading axis.
@@ -49,6 +56,11 @@ def _fans(conf: Layer, name: str, shape: Tuple[int, ...]) -> Tuple[float, float]
     if len(shape) >= 2:
         return (shape[0], shape[1])
     return (shape[0], shape[0])
+
+
+# Gains and temperatures that start at 1: (layer class, param).
+_ONES = {("ScaledResidualLayer", "alpha"), ("ScaledResidualLayer", "beta"),
+         ("CompressedConvAttentionLayer", "temp")}
 
 
 def init_layer_params(conf: Layer, rng: jax.Array, dtype=jnp.float32) -> Dict[str, jnp.ndarray]:
@@ -77,6 +89,14 @@ def init_layer_params(conf: Layer, rng: jax.Array, dtype=jnp.float32) -> Dict[st
             # Norm scales (RMS norm; the attention layer's q/k norms and its
             # indexer's key norm): ones. Their `beta_*` take the bias path.
             params[name] = jnp.ones(shape, dtype)
+            continue
+        if (type(conf).__name__, name) in _ONES:
+            params[name] = jnp.ones(shape, dtype)
+            continue
+        if type(conf).__name__ == "RouterStateLayer" and name == "eda_theta":
+            # lambda = sigmoid(0) = 1/2: the previous block's state and this
+            # block's own in equal parts
+            params[name] = jnp.zeros(shape, dtype)
             continue
         if isinstance(conf, MoELayer) and name == "gate_b":
             # the sigmoid router's selection bias: no bias until one is

@@ -197,6 +197,29 @@ def route_top_k(gate_w, x, top_k: int, norm_topk_prob: bool = True, *,
     return probs, gate, idx
 
 
+def route_mlp(params, r, top_k: int, norm_topk_prob: bool = False):
+    """The MLP router (ZAYA1's) over the router's state r [N, R] (a
+    `RouterStateLayer`'s output): logits `z = gelu(gelu(r W1) W2) W3` over
+    the C choices (GELU exact, the product in >= float32), `probs =
+    softmax(z)`, the choice the `top_k` largest of `z + gate_b` (`gate_b`
+    [C], a frozen selection bias: it enters the choice only), and `gate` the
+    chosen probabilities, renormalised only with `norm_topk_prob` (at top-1
+    a renormalised gate is 1 and no gradient reaches the router). ->
+    `(probs [N, C], gate [N, k], idx [N, k])`."""
+    acc = jnp.promote_types(r.dtype, jnp.float32)
+    z = r.astype(acc)
+    for name in ("router_w1", "router_w2"):
+        z = jax.nn.gelu(z @ params[name].astype(acc), approximate=False)
+    z = z @ params["router_w3"].astype(acc)
+    probs = jax.nn.softmax(z, axis=-1)
+    _, idx = jax.lax.top_k(
+        jax.lax.stop_gradient(z + params["gate_b"].astype(acc)), top_k)
+    gate = jnp.take_along_axis(probs, idx, axis=-1)
+    if norm_topk_prob:
+        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    return probs, gate, idx
+
+
 def sequence_balance(probs, idx, sequences: int):
     """The sequence-wise balance term (DeepSeek-V2/V3): `sum_i f_i P_i` with
     `f_i = E / (k S) * #{t: i chosen}` and `P_i = mean_t probs_i(t)` over
@@ -316,7 +339,8 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 def moe_ffn_dropless(params, x, *, top_k: int, first=0,
                      norm_topk_prob: bool = True, scoring: str = "softmax",
-                     routed_scaling_factor: float = 1.0, sequences: int = 1):
+                     routed_scaling_factor: float = 1.0, sequences: int = 1,
+                     router_state=None):
     """Dropless top-k routed experts, this holder's part of the sum.
 
     x: [N, D] tokens. `params["gate_w"]`: [D, E] router over ALL E experts;
@@ -366,20 +390,33 @@ def moe_ffn_dropless(params, x, *, top_k: int, first=0,
     where the layer has one) `sequence_balance` over the `sequences` the
     tokens are; `stats` = `(pairs_held_share, expert_load_max_over_mean)`
     over the held experts; `idx` [N, top_k] the experts each token was
-    routed to."""
+    routed to.
+
+    `router_state` [N, R]: the MLP router's input (`route_mlp`, whose
+    leaves `params` then holds in place of `gate_w`): its last choice, the
+    skip, is no expert's, so its pairs sort with those held elsewhere and
+    add nothing. The balance term is then over the experts alone."""
     N, D = x.shape
-    E = params["gate_w"].shape[1]
     Eh = params["w_gate"].shape[0]
     acc = jnp.promote_types(x.dtype, jnp.float32)
 
     with jax.named_scope("moe.route"):
-        probs, gate, idx = route_top_k(
-            params["gate_w"], x, top_k, norm_topk_prob, scoring=scoring,
-            gate_b=params.get("gate_b"),
-            routed_scaling_factor=routed_scaling_factor)          # [N, k]
+        if router_state is not None:
+            probs, gate, idx = route_mlp(params, router_state, top_k,
+                                         norm_topk_prob)
+            E = probs.shape[1] - 1                    # the skip is last
+            probs = probs[:, :E]
+        else:
+            E = params["gate_w"].shape[1]
+            probs, gate, idx = route_top_k(
+                params["gate_w"], x, top_k, norm_topk_prob, scoring=scoring,
+                gate_b=params.get("gate_b"),
+                routed_scaling_factor=routed_scaling_factor)      # [N, k]
         if scoring == "sigmoid":
             aux = sequence_balance(probs, idx, sequences)
         else:
+            # a skipped pair's index is past the experts' counts: a
+            # scatter out of bounds drops it
             counts = jnp.zeros((E,), acc).at[idx.reshape(-1)].add(1.0)
             aux = E * jnp.sum(counts / N * jnp.mean(probs, axis=0))
         # Sort the pairs by local expert, those held elsewhere (Eh) last.

@@ -602,6 +602,50 @@ def test_windowed_and_full_layers_run_three_kernels_under_their_scope(
     assert f"[{S},{S}]" not in text
 
 
+def test_cca_layer_runs_its_kernels_under_its_scopes(chip, monkeypatch):
+    """`CompressedConvAttentionLayer` at `zaya1_8b.fit_seq8k`'s widths (8
+    query and 2 key/value heads of 128 over 2048, rotary on 64 channels),
+    traced as a TPU process would trace it: the core is `banded_attention`'s
+    three kernels under `cca.attend`, the rotary step the registry's `rotary`
+    on the 64-channel part of q and k under `cca.conv` (forward and
+    backward), and nothing is `[S, S]`."""
+    from deeplearning4j_tpu.kernels import registry
+    from deeplearning4j_tpu.nn.conf.layers import (
+        CompressedConvAttentionLayer)
+    from deeplearning4j_tpu.nn.layers import cca
+
+    monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")
+    for knob in ("DL4J_TPU_KERNELS", "DL4J_TPU_KERNEL_BANDED_ATTENTION",
+                 "DL4J_TPU_KERNEL_ROTARY"):
+        monkeypatch.delenv(knob, raising=False)
+    registry.clear_cache()
+    S, D = 8192, 2048
+    conf = CompressedConvAttentionLayer(
+        n_in=D, n_out=D, n_heads=8, n_kv_heads=2, head_dim=128,
+        rope_theta=5e6, partial_rotary_factor=0.5)
+    bf = jnp.bfloat16
+
+    def loss(params, x):
+        return jnp.sum(cca.cca_apply(conf, params, {}, x)[0].astype(
+            jnp.float32))
+
+    shapes = {n: chip(shape, jnp.float32 if n == "temp" else bf)
+              for n, shape in conf.param_shapes().items()}
+    text = jax.jit(jax.grad(loss)).lower(shapes, chip((1, S, D), bf)) \
+        .compile().as_text()
+    registry.clear_cache()
+    calls = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
+    attention = [c for c in calls if "rotary" not in c]
+    assert len(attention) == 3 and all(
+        "cca.attend" in c and "banded_attention" in c for c in attention), \
+        calls
+    rotations = [c for c in calls if "rotary" in c]
+    assert len(rotations) == 4 and all("cca.conv" in c for c in rotations)
+    assert sum("transpose(" in c for c in rotations) == 2, rotations
+    assert f"[{S},{S}]" not in text
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
 def test_latent_attention_forward_backward(chip, dtype):
