@@ -32,11 +32,16 @@ a (row tile, group) pair that share a row, in row order, so a tile that
 straddles a group's edge is visited once a group under a row mask, and the
 grid's bound is the number of such pairs: a tile past the live prefix is
 never visited. The lists travel as scalar-prefetch operands and choose each
-step's blocks. Entries 1 and 2 tile neither K nor N: a step holds `[tm, K]`
-rows, the group's whole `[K, N]` matrix (fetched again only when the group
-changes) and a `[tm, N]` result. Entry 3 holds two row tiles and an
-`[ta, tb]` part of the group's float32 accumulator, and runs the visits
-once a part.
+step's blocks. Entries 1 and 2 tile neither K nor, where it fits, N: a
+step holds `[tm, K]` rows, the group's whole `[K, N]` matrix (fetched again
+only when the group changes) and a `[tm, N]` result. Where no row tile fits
+beside the whole matrix (ZAYA1's K = N = 2,048 in bf16: 16 MiB for the
+table's two buffers alone), N is split into the fewest `tn`-wide parts that
+fit and the parts are an outer grid axis: each visit multiplies `[tm, K]`
+rows by the group's `[K, tn]` slice into a `[tm, tn]` result, so a slice is
+fetched once a group and part, and the rows once a part. Entry 3 holds two
+row tiles and an `[ta, tb]` part of the group's float32 accumulator, and
+runs the visits once a part.
 
 **Every body stays inside the 16 MiB of VMEM a kernel has by default**
 (`tiling`; the registry's probe refuses a signature no tiling serves).
@@ -114,8 +119,8 @@ def _rows_of_group(offsets, group, tile, tm, axis=0):
 
 
 def _rows_table_kernel(offsets, group, tile, rows, table, out, *, tm,
-                       transposed):
-    _, mine, _ = _rows_of_group(offsets, group, tile, tm)
+                       transposed, axis):
+    _, mine, _ = _rows_of_group(offsets, group, tile, tm, axis)
     acc = jax.lax.dot_general(
         rows[...], table[...], (((1,), (1 if transposed else 0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -156,32 +161,47 @@ def _contracted_kernel(offsets, group, tile, rows, ct, out, acc, *, tm):
 # Jitted, so that a program which makes one product many times (a layer's
 # forward pass and its recomputation, every layer of a model) traces and
 # lowers each kernel once: 48 bodies a step added 1.3-2 s to every set-up.
-@functools.partial(jax.jit, static_argnames=("tm", "transposed", "interpret"))
-def rows_table_pallas(rows, table, group_sizes, *, tm, transposed=False,
-                      interpret=False):
+@functools.partial(jax.jit,
+                   static_argnames=("tm", "tn", "transposed", "interpret"))
+def rows_table_pallas(rows, table, group_sizes, tm, tn=None, *,
+                      transposed=False, interpret=False):
     """Entries 1 and 2: `rows [M, K]` against `table [G, K, N]` (`[G, N, K]`
-    with `transposed`) -> `[M, N]`."""
+    with `transposed`) -> `[M, N]`, `tn` columns of it at a time (None: all
+    N). With one part the grid is the visits alone; with several the parts
+    are the outer axis, so a tile's visits stay consecutive within a part."""
     from jax.experimental.pallas import tpu as pltpu
 
     (M, K), (_, a, b) = rows.shape, table.shape
     N = a if transposed else b
+    tn = tn or N
     *lists, count = _visits(group_sizes, M, tm, empty_groups=False)
+    grid = (count,) if tn == N else (N // tn, count)
 
-    def by_tile(width):
-        return pl.BlockSpec((tm, width), lambda i, offsets, group, tile:
-                            (tile[i], 0))
+    def spec(shape, index):
+        """`index(part, visit, group, tile)` as the grid's index map."""
+        def at(*ids):  # ([part,] visit, offsets, group, tile)
+            *part, i, _, group, tile = ids
+            return index(part[0] if part else 0, i, group, tile)
+        return pl.BlockSpec(shape, at)
 
+    if transposed:
+        table_spec = spec((None, tn, K), lambda j, i, group, tile:
+                          (group[i], j, 0))
+    else:
+        table_spec = spec((None, K, tn), lambda j, i, group, tile:
+                          (group[i], 0, j))
     return pl.pallas_call(
-        functools.partial(_rows_table_kernel, tm=tm, transposed=transposed),
+        functools.partial(_rows_table_kernel, tm=tm, transposed=transposed,
+                          axis=len(grid) - 1),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(count,),
-            in_specs=[by_tile(K), pl.BlockSpec(
-                (None, a, b), lambda i, offsets, group, tile:
-                (group[i], 0, 0))],
-            out_specs=by_tile(N)),
+            num_scalar_prefetch=3, grid=grid,
+            in_specs=[spec((tm, K), lambda j, i, group, tile: (tile[i], 0)),
+                      table_spec],
+            out_specs=spec((tm, tn), lambda j, i, group, tile:
+                           (tile[i], j))),
         out_shape=jax.ShapeDtypeStruct((M, N), rows.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",) * len(grid)),
         interpret=interpret,
         name=f"grouped_matmul_rows_table{'_t' if transposed else ''}",
     )(*lists, rows, table)
@@ -223,22 +243,26 @@ def _divisors(n):
 
 
 def tiling(entry, M, K, N, itemsize):
-    """The tiles for a signature, or None where none serves: `(tm,)` for
-    entries 1 and 2, `(tm, ta, tb)` for entry 3 (K, N its A, B). A row tile
-    divides M and leaves `_MIN_ROW_TILES` tiles; a step's blocks, each
-    twice (the pipeline's two buffers) with the float32 product beside
-    them, stay under `_VMEM_BUDGET`. Entry 3 takes the fewest parts of
-    `[A, B]` that fit, then the tallest row tile."""
+    """The tiles for a signature, or None where none serves: `(tm,)` or
+    `(tm, tn)` for entries 1 and 2, `(tm, ta, tb)` for entry 3 (K, N its A,
+    B). A row tile divides M and leaves `_MIN_ROW_TILES` tiles; a step's
+    blocks, each twice (the pipeline's two buffers) with the float32
+    product beside them, stay under `_VMEM_BUDGET`. Entries 1 and 2 take
+    the whole `[K, N]` matrix where a row tile fits beside it (`(tm,)`),
+    else the fewest `tn`-wide parts of N that fit, then the tallest row
+    tile. Entry 3 takes the fewest parts of `[A, B]` that fit, then the
+    tallest row tile."""
     def row_tiles(tallest):
         return [tm for tm in (512, 256, 128) if tm <= tallest
                 and M % tm == 0 and M >= _MIN_ROW_TILES * tm]
 
     if entry != "contracted":
         # 256 rows: within 3% of the best tile at every shape probed
-        for tm in row_tiles(256):
-            if (2 * tm * K + 2 * K * N + 2 * tm * N) * itemsize + (
-                    tm * N * 4) <= _VMEM_BUDGET:
-                return (tm,)
+        for tn in _divisors(N):
+            for tm in row_tiles(256):
+                if (2 * tm * K + 2 * K * tn + 2 * tm * tn) * itemsize + (
+                        tm * tn * 4) <= _VMEM_BUDGET:
+                    return (tm,) if tn == N else (tm, tn)
         return None
     parts = sorted(((ta, tb) for ta in _divisors(K) for tb in _divisors(N)),
                    key=lambda t: (-t[0] * t[1], t[0] + t[1]))
@@ -263,7 +287,7 @@ def _pallas_available(backend, shapes, dtypes, meta=(), forced=False):
     if shapes:
         M, K, N, G = shapes
         if K % 128 or N % 128:
-            return False, (f"K={K}, N={N}: the kernel holds whole [K, N] "
+            return False, (f"K={K}, N={N}: the kernel holds [K, N] "
                            "matrices and wants both on the 128-lane grid")
         if tiling(dict(meta).get("entry", "rows_table"), M, K, N,
                   2 if dtypes[:1] == ("bfloat16",) else 4) is None:
@@ -273,8 +297,8 @@ def _pallas_available(backend, shapes, dtypes, meta=(), forced=False):
                            "VMEM a kernel has")
     if backend == "tpu":
         return True, ("TPU grouped-product kernel (a visit list over row "
-                      "tiles and groups from the traced group sizes, whole "
-                      "[K, N] matrices in VMEM)")
+                      "tiles and groups from the traced group sizes, [K, N] "
+                      "matrices in VMEM whole or in parts of N)")
     return True, "interpret mode off-TPU (float-close parity tests only)"
 
 
@@ -304,17 +328,19 @@ def rows_table(rows, table, group_sizes):
     tiles = _resolve("rows_table", rows.shape[0], K, N, G, rows.dtype,
                      table.dtype)
     if tiles is not None:
-        return rows_table_pallas(rows, table, group_sizes, tm=tiles[0],
+        return rows_table_pallas(rows, table, group_sizes, *tiles,
                                  interpret=_registry.interpret_mode())
     return jax.lax.ragged_dot(rows, table, group_sizes)
 
 
 class Transposed(NamedTuple):
     """A table `[G, N, K]` as `rows_table_t` takes it: as stored with the
-    Pallas body's row tile, or the `[G, K, N]` copy XLA's lowering wants."""
+    Pallas body's row tile (and its part of N, where N is split), or the
+    `[G, K, N]` copy XLA's lowering wants."""
 
     table: jax.Array
     tm: int | None
+    tn: int | None = None
 
 
 def transposed(table, rows: int, dtype):
@@ -326,7 +352,7 @@ def transposed(table, rows: int, dtype):
     tiles = _resolve("rows_table_t", rows, K, N, G, dtype, table.dtype)
     if tiles is None:
         return Transposed(jnp.swapaxes(table, 1, 2), None)
-    return Transposed(table, tiles[0])
+    return Transposed(table, *tiles)
 
 
 def rows_table_t(rows, table_t: Transposed, group_sizes):
@@ -334,7 +360,7 @@ def rows_table_t(rows, table_t: Transposed, group_sizes):
     `[G, N, K]` that went through `transposed`."""
     if table_t.tm is not None:
         return rows_table_pallas(rows, table_t.table, group_sizes,
-                                 tm=table_t.tm, transposed=True,
+                                 table_t.tm, table_t.tn, transposed=True,
                                  interpret=_registry.interpret_mode())
     return jax.lax.ragged_dot(rows, table_t.table, group_sizes)
 

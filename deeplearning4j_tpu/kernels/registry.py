@@ -70,8 +70,11 @@ MODES = ("auto", "xla", "pallas")
 # grouped products resolve its Pallas body where they were
 # `jax.lax.ragged_dot` calls outside the registry (PR 35).
 # 7: `rotary` exists, and on a TPU `dsa.rope` resolves its Pallas body
-# where it ran the XLA expression inline.
-SELECTION_RULES = 7
+# where it ran the XLA expression inline. 8: on a TPU a grouped product
+# whose whole `[K, N]` table outgrows VMEM beside a row tile resolves
+# `grouped_matmul`'s Pallas body, over parts of N, where it ran
+# `jax.lax.ragged_dot`.
+SELECTION_RULES = 8
 
 # Meta key the registry itself adds to a signature traced under a mesh of
 # more than one device (and `--probe --meta mesh_devices=N` passes by hand).

@@ -767,14 +767,17 @@ def test_dropless_experts_at_published_widths(chip, N, D, F, E, Eh,
     assert not selects, selects
 
 
-@pytest.mark.parametrize("N,D,F,E,Eh,K,temporaries", [
-    (8192, 2048, 768, 128, 16, 8, 2.5e9),    # keye_vl2_30b_a3b.fit_seq8k
-    (16384, 2304, 896, 64, 8, 8, 3.0e9),     # mellum2_12b_a2_5b.fit_seq16k
-    (8192, 2048, 1408, 64, 8, 6, 2.5e9),     # kimi_vl_a3b.fit_seq8k
-], ids=["keye_vl2_30b_a3b", "mellum2_12b_a2_5b", "kimi_vl_a3b"])
+@pytest.mark.parametrize("N,D,F,E,Eh,K,temporaries,tiles", [
+    (8192, 2048, 768, 128, 16, 8, 2.5e9, (256,)),   # keye_vl2_30b_a3b
+    (16384, 2304, 896, 64, 8, 8, 3.0e9, (256,)),    # mellum2_12b_a2_5b
+    (8192, 2048, 1408, 64, 8, 6, 2.5e9, (256,)),    # kimi_vl_a3b
+    # zaya1_8b: a [2048, 2048] table outgrows VMEM beside a row tile, so
+    # entries 1 and 2 take N in two parts
+    (8192, 2048, 2048, 16, 8, 1, 1.0e9, (256, 1024)),
+], ids=["keye_vl2_30b_a3b", "mellum2_12b_a2_5b", "kimi_vl_a3b", "zaya1_8b"])
 def test_dropless_experts_run_the_grouped_kernel_at_published_widths(
-        chip, monkeypatch, N, D, F, E, Eh, K, temporaries):
-    """`expert.moe_ffn_dropless` at the three language models' points, traced
+        chip, monkeypatch, N, D, F, E, Eh, K, temporaries, tiles):
+    """`expert.moe_ffn_dropless` at the four language models' points, traced
     as a TPU process traces it: the registry's `grouped_matmul` resolves
     `pallas` for all twelve grouped products of the layer, the chip's
     compiler takes every body at the tiles the registry chose, inside the
@@ -830,7 +833,7 @@ def test_dropless_experts_run_the_grouped_kernel_at_published_widths(
         r'grouped_matmul[\w.]* = .*?"used_scoped_memory_configs":\[\{'
         r'"memory_space":"1","offset":"0","size":"(\d+)"', text)]
     assert len(used) == 12 and max(used) <= 16 << 20, used
-    assert gm.tiling("rows_table", N * K, D, F, 2) == (256,)
+    assert gm.tiling("rows_table", N * K, D, F, 2) == tiles
 
 
 # Every rotation of the three language-model cells: q and k of a layer, the

@@ -30,7 +30,7 @@ SIZES = {
 DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
 
 
-def _operands(sizes, dtype, seed=0):
+def _operands(sizes, dtype, seed=0, N=N):
     """rows [M, K], ct [M, N], tables [G, K, N] and [G, N, K]; the rows past
     the live prefix NaN in both `[M, ...]` operands."""
     rng = np.random.default_rng(seed)
@@ -60,15 +60,18 @@ def _close(got, want, dtype):
 @pytest.mark.parametrize("dtype", list(DTYPES))
 @pytest.mark.parametrize("case", list(SIZES))
 @pytest.mark.parametrize("transposed", [False, True], ids=["table", "table_t"])
-def test_rows_by_group_against_a_table(case, dtype, transposed):
-    o = _operands(SIZES[case], DTYPES[dtype])
+@pytest.mark.parametrize("tn", [None, 128], ids=["whole", "in_parts"])
+def test_rows_by_group_against_a_table(case, dtype, transposed, tn):
+    """Each group's `[K, N]` whole, or a `[K, tn]` part of N at a time (the
+    parts an outer grid axis: 2N // 128 = 2 parts here)."""
+    o = _operands(SIZES[case], DTYPES[dtype], N=2 * N)
     table = o["table_t"] if transposed else o["table"]
     want = jax.lax.ragged_dot(
         o["rows"], jnp.swapaxes(table, 1, 2) if transposed else table,
         o["sizes"])
     got = gm.rows_table_pallas(o["rows_nan"], table, o["sizes"], tm=TM,
-                               transposed=transposed, interpret=True)
-    assert got.shape == (M, N) and got.dtype == want.dtype
+                               tn=tn, transposed=transposed, interpret=True)
+    assert got.shape == (M, 2 * N) and got.dtype == want.dtype
     _close(got[:o["held"]], want[:o["held"]], dtype)
 
 
@@ -173,7 +176,10 @@ REFUSED = {
                              "row tiles"),
     "M_off_the_row_grid": ((1000, 256, 128, 4), ("bfloat16",) * 2, (),
                            "row tiles"),
-    "a_table_past_VMEM": ((131072, 4096, 4096, 8), ("bfloat16",) * 2,
+    # two [128, K] row tiles and a [K, 128] part of N outgrow 16 MiB: no
+    # split of N serves (a [4096, 4096] table is taken in parts since the
+    # split exists)
+    "a_table_past_VMEM": ((131072, 16384, 4096, 8), ("bfloat16",) * 2,
                           (("entry", "rows_table_t"),), "VMEM"),
     "partitioned_over_a_mesh": ((131072, 2304, 896, 8), ("bfloat16",) * 2,
                                 ((registry.MESH_DEVICES, 4),), "partitioned"),
@@ -199,6 +205,12 @@ def test_the_registry_refuses_the_pallas_body_with_a_reason(case):
     ((65536, 2048, 768, 16), "contracted", (128, 2048, 768)),   # keye_vl2
     ((131072, 4096, 4096, 8), "contracted", (512, 1024, 1024)),
     ((640, 256, 128, 4), "rows_table", (128,)),
+    # zaya1_8b: the whole [2048, 2048] table does not fit beside a row
+    # tile, so N goes in two parts
+    ((8192, 2048, 2048, 8), "rows_table", (256, 1024)),
+    ((8192, 2048, 2048, 8), "rows_table_t", (256, 1024)),
+    ((8192, 2048, 2048, 8), "contracted", (512, 1024, 1024)),
+    ((131072, 4096, 4096, 8), "rows_table_t", (256, 512)),
 ])
 def test_the_registry_takes_the_cells_signatures_on_a_tpu(shapes, entry,
                                                           tiles):
@@ -215,9 +227,52 @@ def test_the_registry_takes_the_cells_signatures_on_a_tpu(shapes, entry,
                           meta=meta)[0] == "xla"
 
 
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_the_split_body_stays_inside_vmem(dtype):
+    """Every `(tm, tn)` the split gives holds its blocks, twice, with the
+    float32 product beside them, inside the 16 MiB a kernel has by default;
+    and a split is taken only where the whole matrix fits beside no row
+    tile."""
+    size = jnp.dtype(dtype).itemsize
+    for K_ in (1024, 2048, 4096):
+        for N_ in (1024, 2048, 4096, 8192):
+            tiles = gm.tiling("rows_table", 131072, K_, N_, size)
+            assert tiles is not None, (K_, N_)
+            tm, tn = (*tiles, N_) if len(tiles) == 1 else tiles
+            assert N_ % tn == 0 and tn % 128 == 0
+            assert (2 * tm * K_ + 2 * K_ * tn + 2 * tm * tn) * size + (
+                tm * tn * 4) <= gm._VMEM_BUDGET
+            whole_fits = (2 * 128 * K_ + 2 * K_ * N_ + 2 * 128 * N_) * size + (
+                128 * N_ * 4) <= gm._VMEM_BUDGET
+            assert (len(tiles) == 1) == whole_fits, (K_, N_, tiles)
+
+
+def test_zayas_expert_products_resolve_the_kernel_on_a_tpu(monkeypatch):
+    """At `zaya1_8b.fit_seq8k`'s widths (8,192 pairs top-1 over 8 held
+    experts of K = N = 2,048, bf16), traced as a TPU process traces it:
+    all three entries resolve `pallas`, and `transposed` hands the table on
+    as stored (no `[G, K, N]` copy) with the split's tiles."""
+    monkeypatch.setattr(registry, "_default_backend", lambda: "tpu")
+    monkeypatch.delenv("DL4J_TPU_KERNELS", raising=False)
+    monkeypatch.delenv("DL4J_TPU_KERNEL_GROUPED_MATMUL", raising=False)
+    registry.clear_cache()
+    try:
+        for entry in ("rows_table", "rows_table_t", "contracted"):
+            res = registry.resolve(
+                "grouped_matmul", shapes=(8192, 2048, 2048, 8),
+                dtypes=("bfloat16", "bfloat16"), meta=(("entry", entry),))
+            assert res.impl == "pallas", (entry, res)
+        bf = jnp.dtype(jnp.bfloat16)
+        table = jax.ShapeDtypeStruct((8, 2048, 2048), bf)
+        t = gm.transposed(table, 8192, bf)
+        assert t.table is table and (t.tm, t.tn) == (256, 1024)
+    finally:
+        registry.clear_cache()
+
+
 def test_the_registry_lists_the_kernel_and_its_rules():
     assert "grouped_matmul" in registry.kernel_names()
-    assert registry.SELECTION_RULES >= 6
+    assert registry.SELECTION_RULES >= 8
     assert registry.config_fingerprint()["grouped_matmul"] == "auto"
     row = next(r for r in registry.describe(backend="cpu")
                if r["kernel"] == "grouped_matmul")

@@ -230,7 +230,7 @@ def test_the_registry_refuses_the_pallas_body_with_a_reason(case):
 
 def test_the_registry_lists_the_kernel_and_counts_its_dispatches():
     assert "rotary" in registry.kernel_names()
-    assert registry.SELECTION_RULES == 7
+    assert registry.SELECTION_RULES >= 7
     assert registry.config_fingerprint()["rotary"] == "auto"
     row = next(r for r in registry.describe(backend="cpu")
                if r["kernel"] == "rotary")
